@@ -15,6 +15,8 @@ def main() -> None:
 
     from benchmarks import (grad_compress_bytes, table1_matmul, table2_mlp,
                             table3_cnn)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     mods = [table1_matmul, table2_mlp, table3_cnn, grad_compress_bytes]
     all_rows = []

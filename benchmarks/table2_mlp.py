@@ -55,7 +55,7 @@ def rows() -> list[tuple]:
         h = L.apply_bn_sign_folded(p_packed["folded"][2], z)
         z = L.apply_binary_dense_packed(p_packed["layers"][3], h,
                                         backend="jnp")
-        return L.apply_batchnorm(p_packed["bn_out"], z)
+        return L.apply_bn_affine(p_packed["bn_out"], z)
 
     f_hybrid = jax.jit(lambda v: hybrid(packed, params, v))
     out.append(("table2/bmlp_first_layer_float_fwd_b1",

@@ -38,6 +38,7 @@ def _respawn_with_devices(argv: list[str]) -> int:
     env = dict(os.environ)
     env["REPRO_ANALYSIS_FORCE_DEVICES"] = str(R.SHARDED_DEVICES)
     env.pop("XLA_FLAGS", None)          # the child derives its own
+    env["JAX_PLATFORMS"] = "cpu"        # forced host devices; never the chip
     env["PYTHONPATH"] = (os.path.join(R.repo_root(), "src") + os.pathsep +
                          env.get("PYTHONPATH", ""))
     proc = subprocess.run(

@@ -24,10 +24,7 @@ from typing import Any, Iterator
 
 import jax
 
-try:                                   # jax >= 0.6 moved these aliases
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-except ImportError:                    # jax <= 0.5
-    from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # Higher-order call primitives whose operands map POSITIONALLY onto the
 # inner jaxpr's invars — the only ones the dataflow passes flow values
@@ -35,7 +32,7 @@ except ImportError:                    # jax <= 0.5
 # reduce_window, custom_* with consts) is treated as an opaque eqn by
 # the dataflow walk; the syntactic walk still descends so launch counts
 # never under-report.
-CALL_PRIMITIVES = frozenset({"pjit", "closed_call", "core_call"})
+CALL_PRIMITIVES = frozenset({"jit", "closed_call", "core_call"})
 
 
 def subjaxprs(param: Any) -> Iterator[Jaxpr]:
@@ -71,12 +68,10 @@ class PallasLaunch:
 
 
 def kernel_name(eqn: Any) -> str:
-    """The kernel function name a ``pallas_call`` eqn was traced from."""
-    info = eqn.params.get("name_and_src_info")
-    if info is not None and getattr(info, "name", None):
-        return str(info.name)
-    name = eqn.params.get("name")           # older jax spelling
-    return str(name) if name else "pallas_call"
+    """The ``name=`` a ``pallas_call`` eqn was launched under, else the
+    kernel function it was traced from."""
+    src = eqn.params["jaxpr"].debug_info.func_src_info   # "<name> at <file>"
+    return src.split(" at ", 1)[0]
 
 
 def call_subjaxpr(eqn: Any) -> ClosedJaxpr | None:

@@ -24,8 +24,8 @@ BlockSpec whose block covers its whole array is DMA'd once and held
 resident (1 buffer); a genuinely tiled block is double-buffered by the
 pipeline emitter (2 buffers).  Scratch is a single allocation.  The
 closed-form estimators additionally charge the kernel's compute
-transient (the (bm, bn, ws) popcount broadcast + the pre-pack int32
-tile), which the traced view cannot see.
+transient (one loop step's ``ws`` transposed operand words + the
+pre-pack int32 tile), which the traced view cannot see.
 
 Budget: 16 MiB/core by default; override with the environment knob
 ``REPRO_VMEM_BUDGET_BYTES`` (e.g. to model a smaller core or leave
@@ -185,7 +185,7 @@ def gemm_estimate(m: int, n: int, kw: int, *, block_m: int = 128,
         VmemTerm("a_block", bm * bkw * 4, 1 if gemv else 2),
         VmemTerm("b_block", block_n * bkw * 4, 2),
         VmemTerm("out_block", bm * out_w * 4, 2),
-        VmemTerm("mismatch_broadcast", bm * block_n * ws * 4),
+        *_contraction_terms(bkw, bm, block_n, ws),
         VmemTerm("y_tile", bm * block_n * 4),
     ]
     if fused:
@@ -201,6 +201,17 @@ def gemm_estimate(m: int, n: int, kw: int, *, block_m: int = 128,
                           grid=grid, terms=tuple(terms))
 
 
+def _contraction_terms(kw: int, bm: int, bn: int, ws: int
+                       ) -> list[VmemTerm]:
+    """The shared XNOR-popcount contraction (``binary_matmul.
+    _mismatch_counts``): both operands transposed into int32 scratch
+    (word axis on sublanes, lanes padded to 128), and one loop step's
+    ``ws`` words of each read back."""
+    return [VmemTerm("a_transposed", kw * _ceil_mult(bm, LANE) * 4),
+            VmemTerm("b_transposed", kw * _ceil_mult(bn, LANE) * 4),
+            VmemTerm("step_words", ws * (_ceil_mult(bm, LANE) + bn) * 4)]
+
+
 def dense_stack_estimate(weight_shapes: Sequence[tuple[int, int]], *,
                          block_m: int = SUBLANE,
                          words_per_step: int = 8) -> LaunchEstimate:
@@ -211,22 +222,24 @@ def dense_stack_estimate(weight_shapes: Sequence[tuple[int, int]], *,
     This IS the arithmetic ``dense_stack_vmem_bytes`` historically
     hand-rolled (that function now delegates here; the crossover is
     regression-pinned in tests): the x tile + every stage's lane-padded
-    resident weights and folded tau/flip rows, plus the single largest
-    stage transient — the (bm, n_pad, ws) popcount broadcast, the int32
-    pre-threshold tile, and the repacked words.
+    resident weights and folded tau/flip rows, the transposed-operand
+    scratch sized for the widest stage, plus the single largest stage
+    transient — the int32 pre-threshold tile and the repacked words.
     """
     prev_words = int(weight_shapes[0][1])
     terms = [VmemTerm("x_tile", block_m * prev_words * 4)]
-    peak = 0
+    peak = max_words = max_n = 0
     for s, (n_s, _) in enumerate(weight_shapes):
         n_pad = _ceil_mult(int(n_s), LANE)
         terms.append(VmemTerm(f"stage{s}_weights", n_pad * prev_words * 4))
         terms.append(VmemTerm(f"stage{s}_bn", 2 * n_pad * 4))
-        ws = min(words_per_step, prev_words)
-        stage = (block_m * n_pad * (ws + 1) * 4
+        stage = (block_m * n_pad * 4
                  + block_m * (n_pad // WORD_BITS) * 4)
         peak = max(peak, stage)
+        max_words, max_n = max(max_words, prev_words), max(max_n, n_pad)
         prev_words = n_pad // WORD_BITS
+    terms += _contraction_terms(max_words, block_m, max_n,
+                                min(words_per_step, max_words))
     terms.append(VmemTerm("stage_transient_peak", peak))
     return LaunchEstimate(kernel="dense_stack", grid=(1,),
                           terms=tuple(terms))
@@ -301,7 +314,7 @@ def bitpack_estimate(m: int, k: int, *, block_m: int = 256,
     """Estimate the sign-binarize + bitpack launch (``kernels.bitpack``)."""
     kw = -(-k // WORD_BITS)
     block_m = min(block_m, _ceil_mult(m, SUBLANE))
-    block_kw = min(block_kw, _ceil_mult(kw, LANE))
+    block_kw = min(block_kw, _ceil_mult(kw, LANE // WORD_BITS))
     block_k = block_kw * WORD_BITS
     mp = _ceil_mult(m, block_m)
     kp = _ceil_mult(k, block_k)
@@ -320,7 +333,7 @@ def bn_sign_pack_estimate(m: int, c: int, *, block_m: int = 256,
     (``kernels.fused_epilogue.bn_sign_pack``)."""
     cw = -(-c // WORD_BITS)
     block_m = min(block_m, _ceil_mult(m, SUBLANE))
-    block_cw = min(block_cw, _ceil_mult(cw, LANE))
+    block_cw = min(block_cw, _ceil_mult(cw, LANE // WORD_BITS))
     block_c = block_cw * WORD_BITS
     mp = _ceil_mult(m, block_m)
     cp = _ceil_mult(c, block_c)
@@ -340,8 +353,8 @@ def bn_sign_pack_estimate(m: int, c: int, *, block_m: int = 256,
 # ---------------------------------------------------------------------------
 
 def _block_dims(block_shape: Sequence[Any]) -> list[int]:
-    """Block dims as ints (squeezed / mapped dims count as 1)."""
-    return [int(d) if isinstance(d, int) else 1 for d in block_shape]
+    """Block dims as ints (``Blocked(n)`` counts n, squeezed dims 1)."""
+    return [int(getattr(d, "block_size", 1)) for d in block_shape]
 
 
 def estimate_eqn(eqn: Any) -> LaunchEstimate:
@@ -357,23 +370,18 @@ def estimate_eqn(eqn: Any) -> LaunchEstimate:
     terms: list[VmemTerm] = []
     n_in = gm.num_inputs
     for i, bm in enumerate(gm.block_mappings):
-        asd = bm.array_shape_dtype
+        asd = bm.array_aval
         dims = _block_dims(bm.block_shape)
         nbytes = _prod(dims) * asd.dtype.itemsize
         pinned = dims == [int(d) for d in asd.shape]
         role = "in" if i < n_in else "out"
         terms.append(VmemTerm(f"{role}{i if i < n_in else i - n_in}_block",
                               nbytes, 1 if pinned else 2))
-    ns = getattr(gm, "num_scratch_operands", 0)
-    if ns:
-        kjaxpr = eqn.params["jaxpr"]
-        for j, var in enumerate(kjaxpr.invars[-ns:]):
-            aval = var.aval
-            inner = getattr(aval, "inner_aval", aval)
-            if hasattr(inner, "size") and hasattr(inner, "dtype"):
-                terms.append(VmemTerm(
-                    f"scratch{j}",
-                    int(inner.size) * inner.dtype.itemsize))
+    for j, aval in enumerate(gm.scratch_avals):
+        inner = getattr(aval, "inner_aval", aval)
+        if hasattr(inner, "size") and hasattr(inner, "dtype"):
+            terms.append(VmemTerm(f"scratch{j}",
+                                  int(inner.size) * inner.dtype.itemsize))
     return LaunchEstimate(kernel=graph.kernel_name(eqn),
                           grid=tuple(int(g) for g in gm.grid),
                           terms=tuple(terms))

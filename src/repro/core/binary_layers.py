@@ -307,8 +307,26 @@ def init_batchnorm(c: int) -> Params:
 
 def apply_batchnorm(params: Params, x: jax.Array, eps: float = 1e-5
                     ) -> jax.Array:
-    inv = params["gamma"] * jax.lax.rsqrt(params["var"] + eps)
-    return (x.astype(jnp.float32) - params["mean"]) * inv + params["beta"]
+    return apply_bn_affine(fold_bn_affine(params, eps), x)
+
+
+def fold_bn_affine(params: Params, eps: float = 1e-5) -> Params:
+    """Inference BN as ``{"mean", "inv", "beta"}``, with the per-channel
+    ``inv = gamma / sqrt(var + eps)`` evaluated once, at pack time.
+
+    Left inside a jitted forward, that rsqrt of closed-over constants is
+    constant-folded by the compiler, which may round it differently from
+    the runtime op by one ulp, so the same logits would depend on whether
+    the forward was jitted.
+    """
+    return {"mean": params["mean"],
+            "inv": params["gamma"] * jax.lax.rsqrt(params["var"] + eps),
+            "beta": params["beta"]}
+
+
+def apply_bn_affine(folded: Params, x: jax.Array) -> jax.Array:
+    return (x.astype(jnp.float32) - folded["mean"]) * folded["inv"] + \
+        folded["beta"]
 
 
 def fold_bn_sign(params: Params, eps: float = 1e-5) -> Params:

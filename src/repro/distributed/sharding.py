@@ -533,8 +533,9 @@ def _partition_arrays(tree: Any):
 class ShardedForward:
     """Callable wrapper around the jitted shard_map'd packed forward.
 
-    Holds the device_put params so calls are ``fwd(x)``; exposes
-    ``.lower(x)`` for HLO inspection, ``.shard_plan`` for tests, and
+    Holds the device_put params (``.arrays``, the packed tree's array
+    leaves as placed) so calls are ``fwd(x)``; exposes ``.lower(x)`` for
+    HLO inspection, ``.shard_plan`` for tests, and
     the serving-facing seams ``.kind`` / ``.batch_multiple`` — the
     request queue (``train.serve.PackedInferenceServer``) sizes its
     flush buckets to multiples of ``batch_multiple`` so every flush
@@ -545,7 +546,7 @@ class ShardedForward:
                  kind: str, telemetry=None):
         from repro import telemetry as _telemetry
         self._jitted = jitted
-        self._arrays = arrays
+        self.arrays = arrays
         self.shard_plan = shard_plan
         self.mesh = mesh
         self.kind = kind
@@ -564,19 +565,19 @@ class ShardedForward:
     def __call__(self, x):
         tr = self.telemetry.tracer
         if not tr.enabled:
-            return self._jitted(self._arrays, x)
+            return self._jitted(self.arrays, x)
         # Traced path only: splitting dispatch from block costs a
         # block_until_ready the async-dispatch steady state must not
         # pay, so the untraced fast path above stays one call.
         with tr.span("sharded.dispatch", mesh=list(self.mesh.shape.values()),
                      kind=self.kind):
-            out = self._jitted(self._arrays, x)
+            out = self._jitted(self.arrays, x)
         with tr.span("sharded.block"):
             jax.block_until_ready(out)
         return out
 
     def lower(self, x):
-        return self._jitted.lower(self._arrays, x)
+        return self._jitted.lower(self.arrays, x)
 
 
 def make_sharded_forward(packed: Any, mesh: Mesh, *,

@@ -29,6 +29,7 @@ def run_verifier(timeout: int = 540) -> list[dict]:
     env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep +
                          env.get("PYTHONPATH", ""))
     env.pop("XLA_FLAGS", None)      # the verifier sets its own
+    env["JAX_PLATFORMS"] = "cpu"    # forced host devices; never the chip
     proc = subprocess.run(
         [sys.executable, "-m", "repro.distributed.verify_sharded",
          "--json"],

@@ -43,6 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import binarize as B
 from repro.kernels.binary_matmul import (_LANE, _SUBLANE, _ceil_mult,
                                          _mismatch_counts,
+                                         contraction_scratch,
                                          DEFAULT_WORDS_PER_STEP)
 from repro.kernels.fused_epilogue import (check_block_lanes,
                                           check_block_sublanes,
@@ -57,7 +58,8 @@ DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 128
 
 
-def _attention_kernel(qp_ref, kp_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _attention_kernel(qp_ref, kp_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                      qt_ref, kt_ref, *,
                       d_true: int, skv_true: int, causal: bool,
                       window: int | None, softcap: float | None,
                       q_offset: int, n_kv_blocks: int, block_q: int,
@@ -75,7 +77,7 @@ def _attention_kernel(qp_ref, kp_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     # Scores: XNOR-popcount identity, then scale (and optional softcap)
     # in f32.  Packed-word tails are zero on both operands, so they XOR
     # to no mismatches and d_true keeps the identity exact.
-    mism = _mismatch_counts(qp_ref[0], kp_ref[0],
+    mism = _mismatch_counts(qp_ref[0], kp_ref[0], qt_ref, kt_ref,
                             words_per_step=words_per_step)
     s = (jnp.int32(d_true) - 2 * mism).astype(jnp.float32)
     s = s * jnp.float32(d_true) ** -0.5
@@ -185,6 +187,7 @@ def binary_attention_packed(q_packed: jax.Array, k_packed: jax.Array,
             window=window, softcap=attn_softcap, q_offset=q_offset,
             n_kv_blocks=n_kv_blocks, block_q=bq, block_kv=bkv,
             words_per_step=words_per_step),
+        name="_attention_kernel",
         grid=(b * hq, sq_p // bq, n_kv_blocks),
         in_specs=[pl.BlockSpec((1, bq, dw_p), q_map),
                   pl.BlockSpec((1, bkv, dw_p), kv_map),
@@ -193,7 +196,8 @@ def binary_attention_packed(q_packed: jax.Array, k_packed: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, dv_p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, _LANE), jnp.float32),
                         pltpu.VMEM((bq, _LANE), jnp.float32),
-                        pltpu.VMEM((bq, dv_p), jnp.float32)],
+                        pltpu.VMEM((bq, dv_p), jnp.float32),
+                        *contraction_scratch(dw_p, bq, bkv)],
         interpret=interpret,
     )(qp, kp, vp)
     out = out.reshape(b, hq, sq_p, dv_p)[:, :, :sq, :dv]

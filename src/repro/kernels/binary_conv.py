@@ -51,7 +51,8 @@ from jax.experimental import pallas as pl
 
 from repro.core import binarize as B
 from repro.kernels.fused_epilogue import (bn_sign_bits_to_words,
-                                          check_block_lanes, pad_bn_params)
+                                          check_block_lanes, pad_bn_params,
+                                          unblock_packed)
 
 # Minimum tile granularity on TPU: (8 sublanes, 128 lanes).
 _LANE = 128
@@ -197,7 +198,7 @@ def resolve_block_oh(block_oh: int | None, oh: int, ow: int) -> int:
 # The kernels
 # ---------------------------------------------------------------------------
 
-def _tile_slab(x_ref, prefix: tuple, *, block_oh: int, stride: int,
+def _tile_slab(x_ref, prefix: tuple, tile, *, block_oh: int, stride: int,
                kh: int) -> jax.Array:
     """Read this M tile's input row band out of the VMEM-resident image.
 
@@ -205,11 +206,13 @@ def _tile_slab(x_ref, prefix: tuple, *, block_oh: int, stride: int,
     indexes the leading dims (batch slot / plane).  Tile ``m`` (grid dim
     1) covers output rows [m·block_oh, (m+1)·block_oh), which read input
     rows [m·block_oh·stride, m·block_oh·stride + (block_oh−1)·stride
-    + kh).  The ``pl.ds`` ref read loads ONLY the slab — the rest of the
+    + kh); ``tile`` is ``pl.program_id(1)``, read by the caller outside
+    any loop (interpret mode cannot resolve it inside one).  The
+    ``pl.ds`` ref read loads ONLY the slab — the rest of the
     image stays in VMEM untouched.  The host wrapper pads Hp so the last
     tile's slab stays in bounds.
     """
-    row0 = pl.program_id(1) * (block_oh * stride)
+    row0 = tile * (block_oh * stride)
     hblk = (block_oh - 1) * stride + kh
     return x_ref[(*prefix, pl.ds(row0, hblk))]
 
@@ -259,7 +262,7 @@ def _conv_bn_sign_kernel(x_ref, w_ref, corr_ref, tau_ref, flip_ref, o_ref, *,
     """Fused variant: conv -> BN-sign threshold -> re-bitpack (uint32)."""
     y = _conv_accumulate(x_ref, w_ref, corr_ref, kh=kh, kw=kw, stride=stride,
                          block_oh=block_oh, ow=ow, cw=cw, k_true=k_true)
-    o_ref[0] = bn_sign_bits_to_words(y, tau_ref[...], flip_ref[...])
+    o_ref[0, 0] = bn_sign_bits_to_words(y, tau_ref[...], flip_ref[...])
 
 
 def _conv_accumulate(x_ref, w_ref, corr_ref, *, kh, kw, stride, block_oh, ow,
@@ -268,7 +271,8 @@ def _conv_accumulate(x_ref, w_ref, corr_ref, *, kh, kw, stride, block_oh, ow,
 
     Returns the (block_oh·ow, bn) int32 pre-epilogue conv output tile.
     """
-    xs = _tile_slab(x_ref, (0,), block_oh=block_oh, stride=stride, kh=kh)
+    xs = _tile_slab(x_ref, (0,), pl.program_id(1), block_oh=block_oh,
+                    stride=stride, kh=kh)
     mism = _tap_mismatch(xs, w_ref[...], kh=kh, kw=kw, stride=stride,
                          n_rows=block_oh, ow=ow, cw=cw)
     return jnp.int32(k_true) - 2 * mism + corr_ref[...]
@@ -293,13 +297,16 @@ def _bitplane_conv_kernel(x_ref, w_ref, rowsum_ref, o_ref, *, kh, kw, stride,
     w = w_ref[...]
     m = block_oh * ow
     bn = w.shape[0]
-    wacc = jnp.zeros((m, bn), jnp.int32)
-    for p in range(nbits):
-        xs = _tile_slab(x_ref, (p, 0), block_oh=block_oh, stride=stride,
-                        kh=kh)
+    tile = pl.program_id(1)
+
+    def plane(p, wacc):
+        xs = _tile_slab(x_ref, (p, 0), tile, block_oh=block_oh,
+                        stride=stride, kh=kh)
         mism = _tap_mismatch(xs, w, kh=kh, kw=kw, stride=stride,
                              n_rows=block_oh, ow=ow, cw=cw)
-        wacc = wacc + (mism << p)
+        return wacc + (mism << p)
+
+    wacc = jax.lax.fori_loop(0, nbits, plane, jnp.zeros((m, bn), jnp.int32))
     full = jnp.int32((1 << nbits) - 1)
     o_ref[0] = (full * (jnp.int32(k_true) + rowsum_ref[...])
                 - 2 * wacc) >> 1
@@ -384,6 +391,7 @@ def binary_conv2d_packed(x_packed: jax.Array, w_packed: jax.Array,
                                k_true=k_true)
     out = pl.pallas_call(
         kernel,
+        name="_conv_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, hp, wp, cw), lambda b, m, j: (b, 0, 0, 0)),
@@ -431,7 +439,8 @@ def binary_conv2d_bn_sign_packed(x_packed: jax.Array, w_packed: jax.Array,
     tau_p, flip_p = pad_bn_params(tau, flip, block_n)
     hp, wp = xp.shape[1:3]
     block_m = block_oh * ow
-    grid = (bsz, m_tiles, c_out_p // block_n)
+    n_blocks = c_out_p // block_n
+    grid = (bsz, m_tiles, n_blocks)
     bnw = block_n // B.WORD_BITS
 
     kernel = functools.partial(_conv_bn_sign_kernel, kh=kh, kw=kw,
@@ -439,6 +448,7 @@ def binary_conv2d_bn_sign_packed(x_packed: jax.Array, w_packed: jax.Array,
                                cw=cw, k_true=k_true)
     out = pl.pallas_call(
         kernel,
+        name="_conv_bn_sign_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, hp, wp, cw), lambda b, m, j: (b, 0, 0, 0)),
@@ -447,12 +457,14 @@ def binary_conv2d_bn_sign_packed(x_packed: jax.Array, w_packed: jax.Array,
             pl.BlockSpec((1, block_n), lambda b, m, j: (0, j)),
             pl.BlockSpec((1, block_n), lambda b, m, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_m, bnw), lambda b, m, j: (b, m, j)),
-        out_shape=jax.ShapeDtypeStruct(
-            (bsz, oh_p * ow, c_out_p // B.WORD_BITS), jnp.uint32),
+        out_specs=pl.BlockSpec((1, 1, block_m, bnw),
+                               lambda b, m, j: (b, j, m, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, n_blocks, oh_p * ow, bnw),
+                                       jnp.uint32),
         interpret=interpret,
     )(xp, w_p, corr, tau_p, flip_p)
     cw_out = B.packed_width(c_out)
+    out = unblock_packed(out)
     return out[:, :oh * ow, :cw_out].reshape(bsz, oh, ow, cw_out)
 
 
@@ -500,6 +512,7 @@ def bitplane_conv2d_packed(x_planes: jax.Array, w_packed: jax.Array,
                                cw=cw, k_true=k_true, nbits=nbits)
     out = pl.pallas_call(
         kernel,
+        name="_bitplane_conv_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((nbits, 1, hp, wp, cw),

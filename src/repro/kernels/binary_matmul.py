@@ -5,13 +5,14 @@ The dense analogue of the conv subsystem (``binary_conv.py``), built
 around  out[m, n] = K − 2·popcount(XOR(a[m, :], b[n, :]))  over packed
 uint32 operands:
 
-* **Vectorized contraction** — each loop step contracts
-  ``words_per_step`` packed words at once: one (bm, bn, ws)
-  popcount-of-XOR broadcast and a word-axis reduce, instead of the old
-  one-(bm, bn)-tile-per-word scheme (128 sequential steps per lane-wide
-  K block -> 128/ws).  The knob is validated like ``block_oh``/``block_n``
-  (divisors of the 128-lane group; invalid values raise) and the output
-  is invariant to it.
+* **Contraction** — both operands are transposed into int32 VMEM
+  scratch (word axis on sublanes), and each loop step reads
+  ``words_per_step`` words of each as refs (``pl.ds``) and adds one
+  (bm, bn) popcount-of-XOR outer product per word: Mosaic slices
+  loaded values only statically and has no unsigned reductions.  The
+  knob is validated like ``block_oh``/``block_n`` (divisors of the
+  128-lane group; invalid values raise) and the output is invariant to
+  it.
 * **Fused BN-sign-repack epilogue** (:func:`binary_matmul_bn_sign_packed`)
   — the kernel flush thresholds the int32 accumulator against the folded
   BN (``fold_bn_sign``) and re-bitpacks along N, so hidden dense layers
@@ -48,7 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.analysis import vmem
 from repro.core import binarize as B
 from repro.kernels.fused_epilogue import (bn_sign_bits_to_words,
-                                          check_block_lanes,
+                                          check_block_lanes, unblock_packed,
                                           check_block_sublanes,
                                           check_words_per_step,
                                           pad_bn_params)
@@ -57,9 +58,8 @@ from repro.kernels.fused_epilogue import (bn_sign_bits_to_words,
 _SUBLANE = 8
 _LANE = 128
 
-# Packed words contracted per vectorized step (the (bm, bn, ws) popcount
-# broadcast).  8 words = 256 logical K per step keeps the broadcast under
-# ~512 KB at the default (128, 128) tile.
+# Packed words read per contraction loop step (each unrolled into ws
+# (bm, bn) popcount outer products).
 DEFAULT_WORDS_PER_STEP = 8
 
 # GEMV path bound: both operands hold their whole packed-K extent in one
@@ -79,46 +79,57 @@ STACK_VMEM_BUDGET = 8 * 2**20
 # Shared contraction body
 # ---------------------------------------------------------------------------
 
-def _mismatch_counts(a: jax.Array, b: jax.Array, *,
+def _mismatch_counts(a: jax.Array, b: jax.Array, at_ref, bt_ref, *,
                      words_per_step: int) -> jax.Array:
     """Vectorized XNOR-popcount contraction of two packed blocks.
 
-    ``a``: (bm, kw) uint32, ``b``: (bn, kw) uint32.  Returns the (bm, bn)
-    int32 total mismatch count.  Each loop step slices ``ws`` packed
-    words from both operands and reduces one (bm, bn, ws)
-    popcount-of-XOR broadcast over the word axis — ws lane-tiles of
-    popcount work per step instead of the old single (bm, 1)×(1, bn)
-    word op.  A static tail handles kw not divisible by ws (ragged stack
+    ``a``: (bm, kw) and ``b``: (bn, kw) packed words.  Returns the
+    (bm, bn) int32 total mismatch count.  Both operands are first
+    transposed into the int32 VMEM scratch refs ``at_ref`` / ``bt_ref``
+    (at least (kw, bm) / (kw, bn)), so the word axis is the sublane axis
+    and a loop step can slice ``ws`` words at a dynamic offset — Mosaic
+    only slices values statically and lanes at 128-aligned offsets.
+    Each step turns its ``ws`` activation rows back into (bm, ws)
+    columns and adds one (bm, bn) popcount-of-XOR outer product per
+    word.  A static tail handles kw not divisible by ws (ragged stack
     stages); the result is invariant to ``words_per_step``.
     """
     bm, kw = a.shape
     bn = b.shape[0]
+    # int32 view: XOR, popcount and the transpose ignore the sign, and
+    # Mosaic has no unsigned reductions.
+    at_ref[:kw, :bm] = jax.lax.bitcast_convert_type(a, jnp.int32).T
+    bt_ref[:kw, :bn] = jax.lax.bitcast_convert_type(b, jnp.int32).T
     ws = min(words_per_step, kw)
     steps, rem = divmod(kw, ws)
 
-    def chunk(a_c, b_c):
-        mism = jax.lax.population_count(a_c[:, None, :] ^ b_c[None, :, :])
-        return mism.sum(axis=-1).astype(jnp.int32)
+    def chunk(start, size: int, acc: jax.Array) -> jax.Array:
+        a_cols = at_ref[pl.ds(start, size), :bm].T          # (bm, size)
+        b_rows = bt_ref[pl.ds(start, size), :bn]            # (size, bn)
+        for j in range(size):
+            acc = acc + jax.lax.population_count(
+                a_cols[:, j:j + 1] ^ b_rows[j:j + 1, :])
+        return acc
 
-    def body(i, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, i * ws, ws, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, i * ws, ws, axis=1)
-        return acc + chunk(a_c, b_c)
-
-    acc = jax.lax.fori_loop(0, steps, body,
+    acc = jax.lax.fori_loop(0, steps, lambda i, acc: chunk(i * ws, ws, acc),
                             jnp.zeros((bm, bn), jnp.int32))
     if rem:
-        acc = acc + chunk(jax.lax.slice_in_dim(a, steps * ws, kw, axis=1),
-                          jax.lax.slice_in_dim(b, steps * ws, kw, axis=1))
+        acc = chunk(steps * ws, rem, acc)
     return acc
+
+
+def contraction_scratch(kw: int, bm: int, bn: int) -> list:
+    """The two transposed-operand scratch buffers of
+    :func:`_mismatch_counts` for a (bm, kw) x (bn, kw) contraction."""
+    return [pltpu.VMEM((kw, bm), jnp.int32), pltpu.VMEM((kw, bn), jnp.int32)]
 
 
 # ---------------------------------------------------------------------------
 # Kernels
 # ---------------------------------------------------------------------------
 
-def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_true: int,
-                 n_k_blocks: int, words_per_step: int):
+def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, at_ref, bt_ref, *,
+                 k_true: int, n_k_blocks: int, words_per_step: int):
     """One (bm, bn) output tile; grid dim 2 walks the packed-K blocks."""
     kb = pl.program_id(2)
 
@@ -126,7 +137,7 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_true: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _mismatch_counts(a_ref[...], b_ref[...],
+    acc_ref[...] += _mismatch_counts(a_ref[...], b_ref[...], at_ref, bt_ref,
                                      words_per_step=words_per_step)
 
     @pl.when(kb == n_k_blocks - 1)
@@ -134,8 +145,9 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_true: int,
         o_ref[...] = jnp.int32(k_true) - 2 * acc_ref[...]
 
 
-def _gemm_bn_sign_kernel(a_ref, b_ref, tau_ref, flip_ref, o_ref, acc_ref, *,
-                         k_true: int, n_k_blocks: int, words_per_step: int):
+def _gemm_bn_sign_kernel(a_ref, b_ref, tau_ref, flip_ref, o_ref, acc_ref,
+                         at_ref, bt_ref, *, k_true: int, n_k_blocks: int,
+                         words_per_step: int):
     """Fused variant: the flush thresholds + re-bitpacks along N, so the
     int32 activation never leaves the accumulator scratch."""
     kb = pl.program_id(2)
@@ -144,46 +156,51 @@ def _gemm_bn_sign_kernel(a_ref, b_ref, tau_ref, flip_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _mismatch_counts(a_ref[...], b_ref[...],
+    acc_ref[...] += _mismatch_counts(a_ref[...], b_ref[...], at_ref, bt_ref,
                                      words_per_step=words_per_step)
 
     @pl.when(kb == n_k_blocks - 1)
     def _flush():
         y = jnp.int32(k_true) - 2 * acc_ref[...]
-        o_ref[...] = bn_sign_bits_to_words(y, tau_ref[...], flip_ref[...])
+        o_ref[0] = bn_sign_bits_to_words(y, tau_ref[...], flip_ref[...])
 
 
-def _gemv_kernel(a_ref, b_ref, o_ref, *, k_true: int, words_per_step: int):
+def _gemv_kernel(a_ref, b_ref, o_ref, at_ref, bt_ref, *, k_true: int,
+                 words_per_step: int):
     """N-major serving path: full-K contraction per program, A resident."""
     o_ref[...] = jnp.int32(k_true) - 2 * _mismatch_counts(
-        a_ref[...], b_ref[...], words_per_step=words_per_step)
+        a_ref[...], b_ref[...], at_ref, bt_ref,
+        words_per_step=words_per_step)
 
 
-def _gemv_bn_sign_kernel(a_ref, b_ref, tau_ref, flip_ref, o_ref, *,
-                         k_true: int, words_per_step: int):
+def _gemv_bn_sign_kernel(a_ref, b_ref, tau_ref, flip_ref, o_ref, at_ref,
+                         bt_ref, *, k_true: int, words_per_step: int):
     y = jnp.int32(k_true) - 2 * _mismatch_counts(
-        a_ref[...], b_ref[...], words_per_step=words_per_step)
-    o_ref[...] = bn_sign_bits_to_words(y, tau_ref[...], flip_ref[...])
+        a_ref[...], b_ref[...], at_ref, bt_ref,
+        words_per_step=words_per_step)
+    o_ref[0] = bn_sign_bits_to_words(y, tau_ref[...], flip_ref[...])
 
 
 def _dense_stack_kernel(*refs, k_trues: tuple[int, ...],
                         words_per_step: int):
     """In-kernel stage loop over the VMEM-resident hidden-layer weights.
 
-    ``refs`` = (x, [w, tau, flip] per stage, out).  Each stage runs the
-    full contraction for this M tile (the stack grid has no N or K
-    blocking — residency is the point), thresholds against its folded
-    BN, and re-bitpacks; the packed words feed the next stage without
-    ever leaving VMEM.  Stage widths are lane-padded by the host wrapper
-    so every repack lands on 32-bit word seams; padded channels carry
-    tau=+inf / flip=+1 and pack as 0-bits, matching the zero-bit-tail
-    convention of the next stage's zero-padded weight words.
+    ``refs`` = (x, [w, tau, flip] per stage, out, at, bt) — the last two
+    are the contraction scratch, sized for the widest stage.  Each stage
+    runs the full contraction for this M tile (the stack grid has no N
+    or K blocking — residency is the point), thresholds against its
+    folded BN, and re-bitpacks; the packed words feed the next stage
+    without ever leaving VMEM.  Stage widths are lane-padded by the host
+    wrapper so every repack lands on 32-bit word seams; padded channels
+    carry tau=+inf / flip=+1 and pack as 0-bits, matching the
+    zero-bit-tail convention of the next stage's zero-padded weight
+    words.
     """
-    x_ref, o_ref = refs[0], refs[-1]
+    x_ref, o_ref, at_ref, bt_ref = refs[0], refs[-3], refs[-2], refs[-1]
     h = x_ref[...]
     for s in range(len(k_trues)):
         w_ref, tau_ref, flip_ref = refs[1 + 3 * s:4 + 3 * s]
-        mism = _mismatch_counts(h, w_ref[...],
+        mism = _mismatch_counts(h, w_ref[...], at_ref, bt_ref,
                                 words_per_step=words_per_step)
         y = jnp.int32(k_trues[s]) - 2 * mism
         h = bn_sign_bits_to_words(y, tau_ref[...], flip_ref[...])
@@ -269,6 +286,7 @@ def binary_matmul_packed(a_packed: jax.Array, b_packed: jax.Array, *,
                                    words_per_step=words_per_step)
         out = pl.pallas_call(
             kernel,
+            name="_gemv_kernel",
             grid=(np_ // block_n,),
             in_specs=[
                 pl.BlockSpec((mp, kwp), lambda j: (0, 0)),
@@ -276,6 +294,7 @@ def binary_matmul_packed(a_packed: jax.Array, b_packed: jax.Array, *,
             ],
             out_specs=pl.BlockSpec((mp, block_n), lambda j: (0, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
+            scratch_shapes=contraction_scratch(kwp, mp, block_n),
             interpret=interpret,
         )(a_p, b_p)
         return out[:m, :n]
@@ -286,6 +305,7 @@ def binary_matmul_packed(a_packed: jax.Array, b_packed: jax.Array, *,
                                words_per_step=words_per_step)
     out = pl.pallas_call(
         kernel,
+        name="_gemm_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_kw), lambda i, j, k: (i, k)),
@@ -293,7 +313,8 @@ def binary_matmul_packed(a_packed: jax.Array, b_packed: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32),
+                        *contraction_scratch(block_kw, block_m, block_n)],
         interpret=interpret,
     )(a_p, b_p)
     return out[:m, :n]
@@ -335,6 +356,7 @@ def binary_matmul_bn_sign_packed(a_packed: jax.Array, b_packed: jax.Array,
     mp, kwp = a_p.shape
     np_, _ = b_p.shape
     bnw = block_n // B.WORD_BITS
+    n_blocks = np_ // block_n
     cw_out = B.packed_width(n)
 
     if dispatch_batch(m, kwp) == "gemv":
@@ -342,26 +364,28 @@ def binary_matmul_bn_sign_packed(a_packed: jax.Array, b_packed: jax.Array,
                                    words_per_step=words_per_step)
         out = pl.pallas_call(
             kernel,
-            grid=(np_ // block_n,),
+            name="_gemv_bn_sign_kernel",
+            grid=(n_blocks,),
             in_specs=[
                 pl.BlockSpec((mp, kwp), lambda j: (0, 0)),
                 pl.BlockSpec((block_n, kwp), lambda j: (j, 0)),
                 pl.BlockSpec((1, block_n), lambda j: (0, j)),
                 pl.BlockSpec((1, block_n), lambda j: (0, j)),
             ],
-            out_specs=pl.BlockSpec((mp, bnw), lambda j: (0, j)),
-            out_shape=jax.ShapeDtypeStruct((mp, np_ // B.WORD_BITS),
-                                           jnp.uint32),
+            out_specs=pl.BlockSpec((1, mp, bnw), lambda j: (j, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((n_blocks, mp, bnw), jnp.uint32),
+            scratch_shapes=contraction_scratch(kwp, mp, block_n),
             interpret=interpret,
         )(a_p, b_p, tau_p, flip_p)
-        return out[:m, :cw_out]
+        return unblock_packed(out)[:m, :cw_out]
 
-    grid = (mp // block_m, np_ // block_n, kwp // block_kw)
+    grid = (mp // block_m, n_blocks, kwp // block_kw)
     kernel = functools.partial(_gemm_bn_sign_kernel, k_true=k_true,
                                n_k_blocks=grid[2],
                                words_per_step=words_per_step)
     out = pl.pallas_call(
         kernel,
+        name="_gemm_bn_sign_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_kw), lambda i, j, k: (i, k)),
@@ -369,12 +393,13 @@ def binary_matmul_bn_sign_packed(a_packed: jax.Array, b_packed: jax.Array,
             pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
             pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, bnw), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_ // B.WORD_BITS), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+        out_specs=pl.BlockSpec((1, block_m, bnw), lambda i, j, k: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, mp, bnw), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32),
+                        *contraction_scratch(block_kw, block_m, block_n)],
         interpret=interpret,
     )(a_p, b_p, tau_p, flip_p)
-    return out[:m, :cw_out]
+    return unblock_packed(out)[:m, :cw_out]
 
 
 # ---------------------------------------------------------------------------
@@ -388,9 +413,9 @@ def dense_stack_vmem_bytes(weights: list, *,
     """Upper-bound VMEM residency of :func:`binary_dense_stack_packed`.
 
     Resident terms: every stage's lane-padded weight block + folded
-    tau/flip rows + the activation M tile.  Transient terms (the largest
-    single stage): the (block_m, n_pad, ws) popcount broadcast, the
-    int32 pre-threshold tile, and the repacked words.
+    tau/flip rows + the activation M tile + the transposed-operand
+    scratch of the widest stage.  Transient terms (the largest single
+    stage): the int32 pre-threshold tile and the repacked words.
 
     The arithmetic lives in the shared static VMEM estimator
     (``analysis.vmem.dense_stack_estimate`` — the same cost model the
@@ -453,6 +478,7 @@ def binary_dense_stack_packed(x_packed: jax.Array, weights: list,
     operands = [x_p]
     in_specs = [pl.BlockSpec((block_m, kw0), lambda i: (i, 0))]
     prev_words = kw0
+    max_words = max_n = 0
     for s in range(n_stages):
         w = weights[s]
         n_s, kw_s = w.shape
@@ -462,6 +488,7 @@ def binary_dense_stack_packed(x_packed: jax.Array, weights: list,
         w_p = B.pad_to_multiple(w_p, n_pad, 0)
         tau_p, flip_p = pad_bn_params(taus[s], flips[s], n_pad)
         operands += [w_p, tau_p, flip_p]
+        max_words, max_n = max(max_words, prev_words), max(max_n, n_pad)
         in_specs += [
             pl.BlockSpec((n_pad, prev_words), lambda i: (0, 0)),
             pl.BlockSpec((1, n_pad), lambda i: (0, 0)),
@@ -473,10 +500,12 @@ def binary_dense_stack_packed(x_packed: jax.Array, weights: list,
                                words_per_step=words_per_step)
     out = pl.pallas_call(
         kernel,
+        name="_dense_stack_kernel",
         grid=(mp // block_m,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, prev_words), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, prev_words), jnp.uint32),
+        scratch_shapes=contraction_scratch(max_words, block_m, max_n),
         interpret=interpret,
     )(*operands)
     return out[:m, :B.packed_width(weights[-1].shape[0])]

@@ -19,17 +19,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import binarize as B
-from repro.kernels.fused_epilogue import (check_block_lanes,
-                                          check_block_sublanes)
+from repro.kernels.fused_epilogue import (_LANE, check_block_lanes,
+                                          check_block_sublanes, pack_lanes)
 
 
-def _bitpack_kernel(x_ref, o_ref, *, block_kw: int):
-    x = x_ref[...]                                     # (bm, block_kw * 32)
-    bm = x.shape[0]
-    bits = (x >= 0).astype(jnp.uint32)
-    bits = bits.reshape(bm, block_kw, B.WORD_BITS)
-    shifts = jnp.arange(B.WORD_BITS, dtype=jnp.uint32)
-    o_ref[...] = (bits << shifts).sum(axis=-1, dtype=jnp.uint32)
+def _bitpack_kernel(x_ref, o_ref):
+    o_ref[...] = pack_lanes((x_ref[...] >= 0).astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_kw",
@@ -48,7 +43,10 @@ def bitpack(x: jax.Array, *, block_m: int = 256, block_kw: int = 128,
     check_block_sublanes("block_m", block_m)
     block_m = min(block_m, _ceil_mult(m, 8))
     check_block_lanes("block_kw", block_kw)
-    block_kw = min(block_kw, _ceil_mult(kw, 128))
+    # Trim to the packed width rounded up to one 128-lane input group:
+    # the output block is then either the whole packed width or a
+    # multiple of 128 words, both legal on the chip.
+    block_kw = min(block_kw, _ceil_mult(kw, _LANE // B.WORD_BITS))
     block_k = block_kw * B.WORD_BITS
 
     # Pad K with -1.0 so padded positions encode to bit 0.
@@ -58,7 +56,8 @@ def bitpack(x: jax.Array, *, block_m: int = 256, block_kw: int = 128,
     grid = (mp // block_m, kp // block_k)
 
     out = pl.pallas_call(
-        functools.partial(_bitpack_kernel, block_kw=block_kw),
+        _bitpack_kernel,
+        name="_bitpack_kernel",
         grid=grid,
         in_specs=[pl.BlockSpec((block_m, block_k), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((block_m, block_kw), lambda i, j: (i, j)),

@@ -62,6 +62,35 @@ def check_words_per_step(name: str, value: int) -> None:
             f"granularity), got {value}")
 
 
+def pack_lanes(bits: jax.Array) -> jax.Array:
+    """Pack a (m, c) {0,1} tile LSB-first along the lane axis -> (m, c/32)
+    uint32, inside a kernel.
+
+    Splitting the lane axis into (c/32, 32) is a relayout Mosaic refuses,
+    so the pack is two matmuls against a constant 0/2^k selection
+    matrix: word j = Σ_k bit[32j+k]·2^k, low and high 16 bits apart so
+    every partial sum stays below 2^16 — exact in bf16 operands with
+    f32 accumulation.  ``c`` must be a multiple of 32.
+    """
+    c = bits.shape[1]
+    cw = c // B.WORD_BITS
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, cw), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, cw), 1)
+    bit = row % B.WORD_BITS
+    own = (row // B.WORD_BITS) == col
+    lhs = bits.astype(jnp.float32).astype(jnp.bfloat16)
+
+    def half(lo: int) -> jax.Array:
+        sel = own & (bit >= lo) & (bit < lo + 16)
+        weights = jnp.where(sel, jnp.left_shift(1, bit - lo), 0)
+        words = jnp.dot(lhs, weights.astype(jnp.float32).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+        return words.astype(jnp.int32)
+
+    words = half(0) | jnp.left_shift(half(16), 16)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
 def bn_sign_bits_to_words(y: jax.Array, tau: jax.Array,
                           flip: jax.Array) -> jax.Array:
     """The epilogue contract, shared by every kernel that inlines it.
@@ -72,11 +101,18 @@ def bn_sign_bits_to_words(y: jax.Array, tau: jax.Array,
     of 32; ``tau``/``flip``: broadcastable (1, c).
     """
     ge = y.astype(jnp.float32) >= tau
-    bits = (ge == (flip > 0)).astype(jnp.uint32)
-    m, c = bits.shape
-    bits = bits.reshape(m, c // B.WORD_BITS, B.WORD_BITS)
-    shifts = jnp.arange(B.WORD_BITS, dtype=jnp.uint32)
-    return (bits << shifts).sum(axis=-1, dtype=jnp.uint32)
+    return pack_lanes((ge == (flip > 0)).astype(jnp.int32))
+
+
+def unblock_packed(out: jax.Array) -> jax.Array:
+    """(..., n_blocks, M, bw) blocked packed output -> (..., M, n_blocks·bw).
+
+    Packed-output kernels write each C_out block's ``block_n/32`` words
+    into its own slab: a (M, bw) block with bw < 128 is only legal on
+    the chip when it spans the array's whole last dim.
+    """
+    out = jnp.moveaxis(out, -3, -2)
+    return out.reshape(*out.shape[:-2], -1)
 
 
 def pad_bn_params(tau: jax.Array, flip: jax.Array,
@@ -116,7 +152,10 @@ def bn_sign_pack(x: jax.Array, tau: jax.Array, flip: jax.Array, *,
     check_block_sublanes("block_m", block_m)
     block_m = min(block_m, _ceil_mult(m, 8))
     check_block_lanes("block_cw", block_cw)
-    block_cw = min(block_cw, _ceil_mult(cw, _LANE))
+    # Trim to the packed width rounded up to one 128-lane input group:
+    # the output block is then either the whole packed width or a
+    # multiple of 128 words, both legal on the chip.
+    block_cw = min(block_cw, _ceil_mult(cw, _LANE // B.WORD_BITS))
     block_c = block_cw * B.WORD_BITS
 
     x_p = B.pad_to_multiple(B.pad_to_multiple(x, block_c, 1), block_m, 0)
@@ -126,6 +165,7 @@ def bn_sign_pack(x: jax.Array, tau: jax.Array, flip: jax.Array, *,
 
     out = pl.pallas_call(
         _bn_sign_pack_kernel,
+        name="_bn_sign_pack_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_c), lambda i, j: (i, j)),

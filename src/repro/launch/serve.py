@@ -257,9 +257,10 @@ def main() -> None:
     ap.add_argument("--deadline-ms", type=float, default=5.0)
     ap.add_argument("--arrival-ms", type=float, default=0.0,
                     help="inter-arrival gap (0 = back-to-back)")
-    ap.add_argument("--backend", default="jnp",
-                    help="'jnp' | 'pallas' | 'ref' | 'auto' "
-                         "(pallas runs interpret-mode off-TPU)")
+    ap.add_argument("--backend", default="auto",
+                    help="'auto' (pallas on TPU, jnp elsewhere) | 'pallas' "
+                         "| 'jnp' | 'ref' (pallas runs interpret-mode "
+                         "off-TPU)")
     ap.add_argument("--mesh", default=None,
                     help="data,model mesh behind the queue, e.g. 2,2")
     ap.add_argument("--smoke", action="store_true",
@@ -279,6 +280,8 @@ def main() -> None:
                          "trace_event JSON (open in Perfetto / "
                          "chrome://tracing)")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 12)
     if args.chaos:
@@ -348,6 +351,11 @@ def main() -> None:
         srv.telemetry.tracer.export(args.trace_out)
         print(f"wrote {len(srv.telemetry.tracer.events)} trace events -> "
               f"{args.trace_out} (open in Perfetto / chrome://tracing)")
+    bad = [r for r in done if r.status != "ok"]
+    if bad:
+        raise SystemExit(f"{len(bad)} of {len(done)} requests did not end "
+                         f"ok, first: rid {bad[0].rid} {bad[0].status} "
+                         f"{bad[0].error!r}")
 
 
 if __name__ == "__main__":

@@ -75,7 +75,7 @@ def pack_bmlp(params: dict, spec: BMLPSpec) -> dict:
             packed_layers.append(L.pack_binary_dense(params["layers"][i]))
     folded = [L.fold_bn_sign(bn) for bn in params["bns"][:-1]]
     return {"layers": packed_layers, "folded": folded,
-            "bn_out": params["bns"][-1]}
+            "bn_out": L.fold_bn_affine(params["bns"][-1])}
 
 
 def _gather_packed(hp: jax.Array, axis_name: str) -> jax.Array:
@@ -182,7 +182,7 @@ def bmlp_forward_packed(packed: dict, x_uint8: jax.Array, *,
     with tel.span("model.bmlp.output"):
         z = L.apply_binary_dense_prepacked(packed["layers"][n - 1], hp,
                                            backend=backend)
-        return L.apply_batchnorm(packed["bn_out"], z)
+        return L.apply_bn_affine(packed["bn_out"], z)
 
 
 # ---------------------------------------------------------------------------
@@ -300,7 +300,8 @@ def pack_bcnn(params: dict, spec: BCNNSpec) -> dict:
     return {"convs": packed_convs, "folded_conv": folded_conv,
             "pool_masks": pool_masks,
             "denses": packed_dense, "folded_dense": folded_dense,
-            "bn_out": params["dense_bns"][-1], "spec": spec}
+            "bn_out": L.fold_bn_affine(params["dense_bns"][-1]),
+            "spec": spec}
 
 
 def _bitplane_conv_packed(pc: dict, x_uint8: jax.Array, nbits: int, *,
@@ -385,7 +386,7 @@ def bcnn_forward_packed(packed: dict, x_uint8: jax.Array, *,
     with tel.span("model.bcnn.output"):
         z = L.apply_binary_dense_prepacked(packed["denses"][n - 1], h,
                                            backend=backend)
-        return L.apply_batchnorm(packed["bn_out"], z)
+        return L.apply_bn_affine(packed["bn_out"], z)
 
 
 # ---------------------------------------------------------------------------

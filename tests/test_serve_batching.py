@@ -354,6 +354,29 @@ def test_serve_beyond_mailbox_cap():
     assert np.array_equal(got, _direct(params, spec, kind, xs, "jnp"))
 
 
+def test_serve_cli_exits_nonzero_when_a_request_fails(monkeypatch):
+    """``python -m repro.launch.serve`` must not report a run whose
+    requests failed (say, a kernel the chip refused) as a success."""
+    import sys
+
+    from repro.launch import serve as cli
+    from repro.utils import compile_cache
+
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", ["serve", "--model", "bmlp", "--smoke",
+                                      "--requests", "3"])
+    cli.main()                                  # healthy run: returns
+
+    def refuse(self, eng, buf, reqs):
+        raise RuntimeError("injected dispatch failure")
+
+    monkeypatch.setattr(SV.PackedInferenceServer, "_dispatch", refuse)
+    monkeypatch.setattr(SV.RetryPolicy, "backoff", lambda self, attempt: 0.0)
+    with pytest.raises(SystemExit) as exc:
+        cli.main()
+    assert "3 of 3 requests did not end ok" in str(exc.value.code)
+
+
 def test_history_is_bounded():
     """served/flushes are observability history, capped like the
     mailbox — a long-running server cannot leak request objects."""

@@ -66,7 +66,7 @@ def test_bcnn_shard_plan_and_specs():
     assert specs["folded_conv/1/tau"] == P()
     assert specs["denses/0/w_packed"] == P("model")
     assert specs["denses/1/w_packed"] == P()           # logits layer
-    assert specs["bn_out/gamma"] == P()
+    assert specs["bn_out/inv"] == P()
     # statics (plan ints, pads, the spec dataclass) get no spec at all
     assert "convs/0/k_true" not in specs
     assert "spec" not in specs
