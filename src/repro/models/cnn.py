@@ -94,11 +94,8 @@ def _gather_packed(hp: jax.Array, axis_name: str) -> jax.Array:
     probes' ``collective_kinds`` gate, not per-execution traffic (the
     compiled function re-runs without re-tracing).
     """
-    tel = telemetry.default()
-    tel.metrics.counter("sharding.gathers").inc()
-    with tel.span("sharding.gather", axis=axis_name):
-        return jax.lax.all_gather(hp, axis_name, axis=hp.ndim - 1,
-                                  tiled=True)
+    telemetry.default().metrics.counter("sharding.gathers").inc()
+    return jax.lax.all_gather(hp, axis_name, axis=hp.ndim - 1, tiled=True)
 
 
 def _check_dense_stack(dense_stack: str) -> None:
@@ -160,29 +157,21 @@ def bmlp_forward_packed(packed: dict, x_uint8: jax.Array, *,
     n = len(packed["layers"])
     shards = layer_shards or (1,) * n
     assert shards[-1] == 1, "output layer must stay replicated"
-    # Stage spans fire at TRACE time (this body runs under jit): they
-    # mark which model stage each kernel/gather was traced from, not
-    # per-execution wall time (docs/observability.md, "structural
-    # spans").  Disabled tracer -> one attribute check per stage.
-    tel = telemetry.default()
-    with tel.span("model.bmlp.bitplane_dense"):
-        z = L.apply_bitplane_dense_packed(packed["layers"][0], x_uint8,
-                                          backend=backend)
-        # Layer 0 accumulates over bit planes in int32, so its epilogue
-        # runs standalone; every later hidden layer fuses GEMM + epilogue.
-        hp = L.apply_bn_sign_folded_packed(packed["folded"][0], z,
-                                           backend=backend)
-        if shards[0] > 1:
-            hp = _gather_packed(hp, model_axis)
-    with tel.span("model.bmlp.dense_stack", layers=n - 2):
-        hp = _dense_hidden_stack(
-            packed["layers"][1:n - 1], packed["folded"][1:], hp,
-            backend=backend, model_axis=model_axis, shards=shards[1:n - 1],
-            dense_stack=dense_stack)
-    with tel.span("model.bmlp.output"):
-        z = L.apply_binary_dense_prepacked(packed["layers"][n - 1], hp,
-                                           backend=backend)
-        return L.apply_bn_affine(packed["bn_out"], z)
+    z = L.apply_bitplane_dense_packed(packed["layers"][0], x_uint8,
+                                      backend=backend)
+    # Layer 0 accumulates over bit planes in int32, so its epilogue
+    # runs standalone; every later hidden layer fuses GEMM + epilogue.
+    hp = L.apply_bn_sign_folded_packed(packed["folded"][0], z,
+                                       backend=backend)
+    if shards[0] > 1:
+        hp = _gather_packed(hp, model_axis)
+    hp = _dense_hidden_stack(
+        packed["layers"][1:n - 1], packed["folded"][1:], hp,
+        backend=backend, model_axis=model_axis, shards=shards[1:n - 1],
+        dense_stack=dense_stack)
+    z = L.apply_binary_dense_prepacked(packed["layers"][n - 1], hp,
+                                       backend=backend)
+    return L.apply_bn_affine(packed["bn_out"], z)
 
 
 # ---------------------------------------------------------------------------
@@ -349,44 +338,38 @@ def bcnn_forward_packed(packed: dict, x_uint8: jax.Array, *,
     conv_shards = conv_shards or (1,) * n_conv
     dense_shards = dense_shards or (1,) * len(packed["denses"])
     assert dense_shards[-1] == 1, "output layer must stay replicated"
-    # Stage spans fire at TRACE time (see bmlp_forward_packed).
-    tel = telemetry.default()
     # Stage 0 accumulates 8 bit-plane convs in int32, so its epilogue runs
     # standalone: pool on int32, then fused threshold + re-bitpack.
-    with tel.span("model.bcnn.bitplane_conv"):
-        z = _bitplane_conv_packed(
-            L.localize_conv_plan(packed["convs"][0], conv_shards[0]),
-            x_uint8, spec.nbits_input, backend=backend)
-        if spec.stages[0].pool:
-            z = L.maxpool2d(z)
-        hp = L.apply_bn_sign_folded_packed(packed["folded_conv"][0], z,
-                                           backend=backend)
-        if conv_shards[0] > 1:
-            hp = _gather_packed(hp, model_axis)
+    z = _bitplane_conv_packed(
+        L.localize_conv_plan(packed["convs"][0], conv_shards[0]),
+        x_uint8, spec.nbits_input, backend=backend)
+    if spec.stages[0].pool:
+        z = L.maxpool2d(z)
+    hp = L.apply_bn_sign_folded_packed(packed["folded_conv"][0], z,
+                                       backend=backend)
+    if conv_shards[0] > 1:
+        hp = _gather_packed(hp, model_axis)
     # Stages 1..n-1: packed in, packed out — zero un-packed activations.
     for i in range(1, n_conv):
-        with tel.span("model.bcnn.conv_stage", stage=i):
-            hp = L.apply_binary_conv2d_bn_packed(
-                L.localize_conv_plan(packed["convs"][i], conv_shards[i]),
-                packed["folded_conv"][i], hp, backend=backend)
-            if spec.stages[i].pool:
-                hp = L.maxpool2d_packed(hp, packed["pool_masks"][i])
-            if conv_shards[i] > 1:
-                hp = _gather_packed(hp, model_axis)
+        hp = L.apply_binary_conv2d_bn_packed(
+            L.localize_conv_plan(packed["convs"][i], conv_shards[i]),
+            packed["folded_conv"][i], hp, backend=backend)
+        if spec.stages[i].pool:
+            hp = L.maxpool2d_packed(hp, packed["pool_masks"][i])
+        if conv_shards[i] > 1:
+            hp = _gather_packed(hp, model_axis)
     h = hp.reshape(hp.shape[0], -1)         # packed (B, fh*fw*Cw) words
     # Classifier tail: hidden dense layers are fused GEMM + BN-sign +
     # re-bitpack (single-launch when VMEM-resident), the output layer
     # stays int32 for the fp batch-norm.
     n = len(packed["denses"])
-    with tel.span("model.bcnn.dense_stack", layers=n - 1):
-        h = _dense_hidden_stack(
-            packed["denses"][:n - 1], packed["folded_dense"], h,
-            backend=backend, model_axis=model_axis,
-            shards=dense_shards[:n - 1], dense_stack=dense_stack)
-    with tel.span("model.bcnn.output"):
-        z = L.apply_binary_dense_prepacked(packed["denses"][n - 1], h,
-                                           backend=backend)
-        return L.apply_bn_affine(packed["bn_out"], z)
+    h = _dense_hidden_stack(
+        packed["denses"][:n - 1], packed["folded_dense"], h,
+        backend=backend, model_axis=model_axis,
+        shards=dense_shards[:n - 1], dense_stack=dense_stack)
+    z = L.apply_binary_dense_prepacked(packed["denses"][n - 1], h,
+                                       backend=backend)
+    return L.apply_bn_affine(packed["bn_out"], z)
 
 
 # ---------------------------------------------------------------------------

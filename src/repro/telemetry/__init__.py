@@ -1,4 +1,4 @@
-"""Telemetry: the repo's one observability layer (dependency-free).
+"""Telemetry: the repo's one observability layer (numpy its only dependency).
 
 Three pieces (see ``docs/observability.md`` for the full taxonomy):
 
@@ -55,7 +55,7 @@ _default = Telemetry()
 
 def default() -> Telemetry:
     """The process-wide instance used by module-level seams (kernel
-    dispatch counters, sharded gather counters, trace-time stage spans)."""
+    dispatch counters, sharded gather counters)."""
     return _default
 
 

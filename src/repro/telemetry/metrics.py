@@ -1,8 +1,8 @@
 """Metrics registry: counters, gauges, and log-bucketed histograms.
 
-Dependency-free and thread-safe — the serving queue mutates counters
-from whatever thread drives ``step()`` while a reporter thread can
-``snapshot()`` concurrently.  Three instrument kinds:
+Thread-safe, with numpy its only dependency — the serving queue
+mutates counters from whatever thread drives ``step()`` while a
+reporter thread can ``snapshot()`` concurrently.  Three instrument kinds:
 
 * :class:`Counter` — monotone event count (``serve.cache.hits``,
   ``serve.route.gemv``).
@@ -23,6 +23,8 @@ Metric NAMES are dotted paths; the taxonomy the repo emits is listed in
 from __future__ import annotations
 
 import threading
+
+import numpy as np
 
 
 def log_spaced_buckets(lo: float = 1e-6, hi: float = 100.0,
@@ -139,6 +141,27 @@ class Histogram:
             self.sum += v
             self.min = v if self.min is None else min(self.min, v)
             self.max = v if self.max is None else max(self.max, v)
+
+    def observe_many(self, values) -> None:
+        """Observe every value of ``values`` under one lock: the same
+        counts, sum, min and max as calling :meth:`observe` on each in
+        turn, with one ``np.searchsorted`` for the buckets."""
+        vals = [float(v) for v in values]
+        if not vals:
+            return
+        idx = np.searchsorted(self.buckets, vals, side="left")
+        hits = np.bincount(idx, minlength=len(self._counts))
+        with self._lock:
+            for i in np.flatnonzero(hits):
+                self._counts[i] += int(hits[i])
+            self.count += len(vals)
+            total = self.sum
+            for v in vals:              # in order: the sum observe() makes
+                total += v
+            self.sum = total
+            lo, hi = min(vals), max(vals)
+            self.min = lo if self.min is None else min(self.min, lo)
+            self.max = hi if self.max is None else max(self.max, hi)
 
     def _bucket_index(self, v: float) -> int:
         lo, hi = 0, len(self.buckets)

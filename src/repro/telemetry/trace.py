@@ -22,8 +22,9 @@ Design points:
   long-running server cannot leak its trace buffer.
 * **Explicit-time spans** — ``add_complete(name, t0_ns, t1_ns)`` emits
   a span whose endpoints were captured earlier with ``now_ns()``; the
-  serving queue uses it for per-flush queue-wait spans (submit time →
-  flush start) without holding a context manager open across calls.
+  serving queue makes every ``serve.*`` span this way, from the phase
+  stamps it keeps on each flush record (and the per-request queue-wait
+  spans, submit → flush start), so spans and records cannot disagree.
 
 The wall clock is ``time.perf_counter_ns`` (injectable for tests) and
 is independent of any simulated serving clock.

@@ -130,17 +130,84 @@ class ServeRequest:
         return self.completed_at - self.submitted_at
 
 
-@dataclasses.dataclass(frozen=True)
+#: The phase stamps of a flush, in order, on the server tracer's clock
+#: (``Tracer.now_ns``, ns): flush start; queue popped (end of bucket
+#: pad); routed (the cohort's timeouts triaged and its bucket and route
+#: chosen: the start of packing); input packed; called (the start of the
+#: forward call that succeeded, after any retries); dispatched (that call
+#: returned); ready (the device's result is ready); on host (the logits
+#: copied to the host); done (every request of the flush completed).
+#: ``ready_ns`` is 0 unless the tracer is on or a JAX profiler trace is
+#: being taken: waiting for ready apart from the copy wakes the host
+#: twice per flush, which only a measured run pays for.
+STAMPS = ("start_ns", "popped_ns", "routed_ns", "packed_ns", "called_ns",
+          "dispatched_ns", "ready_ns", "on_host_ns", "done_ns")
+
+#: The span each pair of stamps becomes while the tracer is on
+#: (``serve.ready`` and ``serve.readback`` split ``serve.compute``).
+#: Triage and routing (popped -> routed) and failed attempts with their
+#: backoff (packed -> called) belong to no phase.
+PHASE_SPANS = (("serve.bucket_pad", "start_ns", "popped_ns"),
+               ("serve.pack", "routed_ns", "packed_ns"),
+               ("serve.dispatch", "called_ns", "dispatched_ns"),
+               ("serve.compute", "dispatched_ns", "on_host_ns"),
+               ("serve.ready", "dispatched_ns", "ready_ns"),
+               ("serve.readback", "ready_ns", "on_host_ns"),
+               ("serve.complete", "on_host_ns", "done_ns"))
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles_started = 0
+_compile_listener_registered = False
+
+try:        # private to JAX: read defensively, a miss reads "not profiling"
+    from jax._src.profiler import _profile_state as _jax_profile_state
+except ImportError:                                   # pragma: no cover
+    _jax_profile_state = None
+
+
+def _profiling() -> bool:
+    """True while a JAX profiler trace is being taken in this process."""
+    return getattr(_jax_profile_state, "profile_session", None) is not None
+
+
+def _on_compile_start(event: str, value: float, **_) -> None:
+    global _compiles_started
+    if event == BACKEND_COMPILE_EVENT:
+        _compiles_started += 1
+
+
+def _count_compiles() -> None:
+    """Count JAX backend compilations (or compile-cache loads) as they
+    start, process-wide; registered once, however many servers."""
+    global _compile_listener_registered
+    if not _compile_listener_registered:
+        jax.monitoring.register_scalar_listener(_on_compile_start)
+        _compile_listener_registered = True
+
+
+@dataclasses.dataclass(frozen=True, slots=True)
 class FlushRecord:
     """Per-flush bookkeeping: how many real requests rode which bucket
-    through which dense grid (``route`` ∈ {'gemv', 'gemm'}), and how
-    many retry attempts the dispatch needed (0 on the healthy path)."""
+    through which dense grid (``route`` ∈ {'gemv', 'gemm'}), how many
+    retry attempts the dispatch needed (0 on the healthy path), the
+    flush's phase stamps (:data:`STAMPS`), and how many JAX backend
+    compilations started during its dispatch."""
     batch: int
     bucket: int
     route: str
     at: float
     wall_s: float
     retries: int = 0
+    start_ns: int = 0
+    popped_ns: int = 0
+    routed_ns: int = 0
+    packed_ns: int = 0
+    called_ns: int = 0
+    dispatched_ns: int = 0
+    ready_ns: int = 0
+    on_host_ns: int = 0
+    done_ns: int = 0
+    compiles: int = 0
 
 
 class PackedModelCache:
@@ -365,6 +432,10 @@ class PackedInferenceServer:
         self._h_latency = m.histogram("serve.request_latency_s")
         self._h_wait = m.histogram("serve.queue_wait_s")
         self._h_flush = m.histogram("serve.flush_wall_s")
+        self._m_finished = {"ok": self._m_completed,
+                            "timeout": self._m_timeouts,
+                            "error": self._m_errors}
+        _count_compiles()
         self.cache = PackedModelCache(metrics=m)
         self.pool = ActivationPool(metrics=m)
         self._engines: dict[Any, _Engine] = {}
@@ -543,7 +614,6 @@ class PackedInferenceServer:
         tr = self.telemetry.tracer
         if tr.enabled:
             req.trace_submit_ns = tr.now_ns()
-            tr.instant("serve.submit", rid=req.rid)
         return req.rid
 
     def cancel(self, rid: int) -> bool:
@@ -664,27 +734,25 @@ class PackedInferenceServer:
             budget = self.default_deadline
         return now > r.submitted_at + self.timeout_grace * budget
 
-    def _finish(self, r: ServeRequest, status: str, now: float, *,
-                result=None, error: BaseException | None = None) -> None:
-        """Move one request to its terminal state — the ONLY writer of
+    def _finish(self, reqs: list[ServeRequest], status: str, now: float, *,
+                results=None, error: BaseException | None = None) -> None:
+        """Move requests to one terminal state — the ONLY writer of
         ``status``, so 'exactly one terminal state per rid' holds by
-        construction (re-finishing a finished request is a bug)."""
+        construction (re-finishing a finished request is a bug).
+        ``results[i]`` is the row of ``reqs[i]``.  Metrics move once
+        per call, not once per request."""
         assert status in TERMINAL_STATES, status
-        assert r.status == "pending", (r.rid, r.status, status)
-        r.status = status
-        r.result = result
-        r.error = error
-        r.completed_at = now
-        self._h_latency.observe(r.latency)
-        if status == "ok":
-            self._m_completed.inc()
-        elif status == "timeout":
-            self._m_timeouts.inc()
-        else:
-            self._m_errors.inc()
-        self.served.append(r)
+        for i, r in enumerate(reqs):
+            assert r.status == "pending", (r.rid, r.status, status)
+            r.status = status
+            r.result = None if results is None else results[i]
+            r.error = error
+            r.completed_at = now
+            self._completed[r.rid] = r
+        self._h_latency.observe_many([now - r.submitted_at for r in reqs])
+        self._m_finished[status].inc(len(reqs))
+        self.served += reqs
         del self.served[:-self._completed_cap]
-        self._completed[r.rid] = r
         while len(self._completed) > self._completed_cap:
             self._completed.popitem(last=False)
 
@@ -696,8 +764,9 @@ class PackedInferenceServer:
             return self.flush_hook(eng, buf, reqs, lambda: eng.fwd(buf))
         return eng.fwd(buf)
 
-    def _serve_cohort(self, reqs: list[ServeRequest],
-                      eng: _Engine) -> list[ServeRequest]:
+    def _serve_cohort(self, reqs: list[ServeRequest], eng: _Engine,
+                      bucket: int, route: str, start_ns: int,
+                      popped_ns: int) -> list[ServeRequest]:
         """Serve one cohort: pad to its bucket, dispatch with bounded
         retry/backoff, bisect on persistent failure, complete every
         request terminally.  Failure isolation contract:
@@ -720,31 +789,55 @@ class PackedInferenceServer:
         siblings (no terminal state, ``take()`` returns None forever).
         """
         try:
-            return self._dispatch_cohort(reqs, eng)
+            return self._dispatch_cohort(reqs, eng, bucket, route,
+                                         start_ns, popped_ns)
         except DeviceLossError:
             pending = [r for r in reqs if r.status == "pending"]
             self._queue.extendleft(reversed(pending))
             self._m_depth.set(len(self._queue))
             raise
 
-    def _dispatch_cohort(self, reqs: list[ServeRequest],
-                         eng: _Engine) -> list[ServeRequest]:
+    def _route(self, eng: _Engine, n: int) -> tuple[int, str]:
+        """The bucket and dense route of an ``n``-row flush."""
+        bucket = self._bucket_for(eng, n)
+        return bucket, kops.dispatch_batch(bucket, eng.kw_words)
+
+    def _dispatch_cohort(self, reqs: list[ServeRequest], eng: _Engine,
+                         bucket: int, route: str, start_ns: int,
+                         popped_ns: int) -> list[ServeRequest]:
+        """Pack, dispatch, wait, read back and complete one cohort routed
+        to ``bucket``/``route``, stamping each phase boundary
+        (:data:`STAMPS`) on the tracer's clock into its
+        :class:`FlushRecord`.  Each half of a bisected cohort is a flush
+        of its own, which starts and pops when the bisection routes it."""
         tr = self.telemetry.tracer
-        bucket = self._bucket_for(eng, len(reqs))
+        routed_ns = tr.now_ns()
         t0 = self._clock()
-        with tr.span("serve.pack", batch=len(reqs), bucket=bucket):
-            buf = self.pool.batch_buffer(bucket, eng.example_shape)
-            for i, r in enumerate(reqs):
-                buf[i] = np.asarray(r.x, buf.dtype)
-            buf[len(reqs):] = 0
-        route = kops.dispatch_batch(bucket, eng.kw_words)
+        buf = self.pool.batch_buffer(bucket, eng.example_shape)
+        for i, r in enumerate(reqs):
+            buf[i] = np.asarray(r.x, buf.dtype)
+        buf[len(reqs):] = 0
+        packed_ns = tr.now_ns()
+        split_ready = tr.enabled or _profiling()
+        ready_ns = 0
+        compiles = _compiles_started
         attempt = 0
         while True:
             try:
-                with tr.span("serve.dispatch", route=route):
-                    out_dev = self._dispatch(eng, buf, reqs)
-                with tr.span("serve.compute"):
-                    out = np.asarray(out_dev)   # blocks on device work
+                called_ns = tr.now_ns()
+                out_dev = self._dispatch(eng, buf, reqs)
+                dispatched_ns = tr.now_ns()
+                if split_ready:
+                    # ask for the copy to the host now, behind the forward
+                    # on the device, as np.asarray alone would: waiting for
+                    # ready first would start the copy only once the host
+                    # heard
+                    if hasattr(out_dev, "copy_to_host_async"):
+                        out_dev.copy_to_host_async()
+                    jax.block_until_ready(out_dev)
+                    ready_ns = tr.now_ns()
+                out = np.asarray(out_dev)       # blocks on device work
+                on_host_ns = tr.now_ns()
                 break
             except DeviceLossError:
                 raise        # not batch-local: _serve_cohort requeues
@@ -755,29 +848,44 @@ class PackedInferenceServer:
                     self._sleep(self.retry.backoff(attempt))
                     continue
                 if len(reqs) == 1:
-                    with tr.span("serve.complete"):
-                        self._finish(reqs[0], "error", self._clock(),
-                                     error=e)
-                        self._m_depth.set(len(self._queue))
+                    self._finish(reqs, "error", self._clock(), error=e)
+                    self._m_depth.set(len(self._queue))
                     return list(reqs)
                 self._m_bisections.inc()
                 mid = len(reqs) // 2
-                return (self._dispatch_cohort(reqs[:mid], eng) +
-                        self._dispatch_cohort(reqs[mid:], eng))
-        with tr.span("serve.complete"):
-            now = self._clock()
-            for i, r in enumerate(reqs):
-                self._h_wait.observe(max(0.0, t0 - r.submitted_at))
-                self._finish(r, "ok", now, result=out[i])
-            self.flushes.append(FlushRecord(
-                batch=len(reqs), bucket=bucket, route=route,
-                at=now, wall_s=now - t0, retries=attempt))
-            del self.flushes[:-self._completed_cap]
-            self._m_flushes.inc()
-            self._m_routes[route].inc()
-            self._m_padded.inc(bucket - len(reqs))
-            self._m_depth.set(len(self._queue))
-            self._h_flush.observe(now - t0)
+                done: list[ServeRequest] = []
+                for half in (reqs[:mid], reqs[mid:]):
+                    half_ns = tr.now_ns()
+                    done += self._dispatch_cohort(
+                        half, eng, *self._route(eng, len(half)),
+                        half_ns, half_ns)
+                return done
+        compiles = _compiles_started - compiles
+        now = self._clock()
+        self._h_wait.observe_many([max(0.0, t0 - r.submitted_at)
+                                   for r in reqs])
+        self._finish(reqs, "ok", now, results=out)
+        self._m_flushes.inc()
+        self._m_routes[route].inc()
+        self._m_padded.inc(bucket - len(reqs))
+        self._m_depth.set(len(self._queue))
+        self._h_flush.observe(now - t0)
+        done_ns = tr.now_ns()
+        rec = FlushRecord(
+            batch=len(reqs), bucket=bucket, route=route, at=now,
+            wall_s=now - t0, retries=attempt, start_ns=start_ns,
+            popped_ns=popped_ns, routed_ns=routed_ns, packed_ns=packed_ns,
+            called_ns=called_ns, dispatched_ns=dispatched_ns,
+            ready_ns=ready_ns, on_host_ns=on_host_ns, done_ns=done_ns,
+            compiles=compiles)
+        self.flushes.append(rec)
+        del self.flushes[:-self._completed_cap]
+        if tr.enabled:
+            args = {"serve.pack": {"batch": len(reqs), "bucket": bucket},
+                    "serve.dispatch": {"route": route}}
+            for name, a, b in PHASE_SPANS:
+                tr.add_complete(name, getattr(rec, a), getattr(rec, b),
+                                **args.get(name, {}))
         return list(reqs)
 
     def _flush_window(self, limit: int) -> list[ServeRequest]:
@@ -785,50 +893,47 @@ class PackedInferenceServer:
         ``timeout``, then serve the live cohort (`_serve_cohort` does
         pad → dispatch-with-retry → complete, bisecting on failure).
 
-        The serving lifecycle is traced per phase when the server's
-        tracer is enabled (span taxonomy in ``docs/observability.md``):
-        a ``serve.flush`` parent wrapping ``serve.bucket_pad`` →
-        ``serve.pack`` → ``serve.dispatch`` (the jitted call returns) →
-        ``serve.compute`` (host transfer blocks on device work) →
-        ``serve.complete``, plus one explicit-time ``serve.queue_wait``
+        Every flush is stamped per phase into its :class:`FlushRecord`
+        whether or not tracing is on.  With the server's tracer enabled
+        the same stamps become spans (taxonomy in
+        ``docs/observability.md``): a ``serve.flush`` parent over
+        :data:`PHASE_SPANS`, plus one explicit-time ``serve.queue_wait``
         span per request (submit → flush start).  Metrics (queue-wait /
         latency / flush-wall histograms, route + padded-row + lifecycle
-        counters) update unconditionally — they are a few dict ops per
-        flush.
+        counters) update unconditionally, once per flush.
         """
         tr = self.telemetry.tracer
-        flush_t0 = tr.now_ns() if tr.enabled else 0
-        with tr.span("serve.bucket_pad"):
-            reqs = [self._queue.popleft()
-                    for _ in range(min(limit, len(self._queue)))]
-            if not reqs:
-                return []
-            eng = self._active_engine()
-            now = self._clock()
+        start_ns = tr.now_ns()
+        reqs = [self._queue.popleft()
+                for _ in range(min(limit, len(self._queue)))]
+        if not reqs:
+            return []
+        eng = self._active_engine()
+        now = self._clock()
+        popped_ns = tr.now_ns()
+        live: list[ServeRequest] = []
+        done: list[ServeRequest] = []
+        for r in reqs:
+            (done if self._timed_out(r, now) else live).append(r)
+        if done:
+            self._finish(done, "timeout", now)
+        flush_args: dict = {"batch": len(reqs)}
+        if live:
+            bucket, route = self._route(eng, len(live))
+            flush_args.update(bucket=bucket, route=route)
+            done += self._serve_cohort(live, eng, bucket, route, start_ns,
+                                       popped_ns)
+        else:
+            self._m_depth.set(len(self._queue))
         if tr.enabled:
+            # read after the last stamp, so that every phase span ends
+            # strictly inside the flush's even after the ns -> us division
+            tr.add_complete("serve.flush", start_ns, tr.now_ns(),
+                            **flush_args)
             for r in reqs:
                 if r.trace_submit_ns is not None:
                     tr.add_complete("serve.queue_wait", r.trace_submit_ns,
-                                    flush_t0, rid=r.rid)
-        done: list[ServeRequest] = []
-        live: list[ServeRequest] = []
-        for r in reqs:
-            if self._timed_out(r, now):
-                self._finish(r, "timeout", now)
-                done.append(r)
-            else:
-                live.append(r)
-        flush_args: dict = {"batch": len(reqs)}
-        if not live:
-            self._m_depth.set(len(self._queue))
-        else:
-            bucket = self._bucket_for(eng, len(live))
-            flush_args["bucket"] = bucket
-            flush_args["route"] = kops.dispatch_batch(bucket, eng.kw_words)
-            done += self._serve_cohort(live, eng)
-        if tr.enabled:
-            tr.add_complete("serve.flush", flush_t0, tr.now_ns(),
-                            **flush_args)
+                                    start_ns, rid=r.rid)
         return done
 
 

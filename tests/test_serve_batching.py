@@ -392,6 +392,106 @@ def test_history_is_bounded():
     assert len(srv._completed) <= cap
 
 
+def _stamps(f):
+    return [getattr(f, k) for k in SV.STAMPS]
+
+
+def _poison_first_row(eng, buf, reqs, default):
+    if reqs[0].rid == 0:
+        raise RuntimeError("poison request")
+    return default()
+
+
+def _fail_twice():
+    calls = []
+
+    def hook(eng, buf, reqs, default):
+        calls.append(1)
+        if len(calls) <= 2:
+            raise RuntimeError("transient")
+        return default()
+    return hook
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("path", ["healthy", "retry", "bisect"])
+def test_flush_stamps_monotone(path, traced):
+    """Every FlushRecord's phase stamps run in order from flush start to
+    done, and one flush ends before the next starts — also when the
+    dispatch is retried or the cohort bisected around a poison row.
+    ``ready_ns`` is stamped only while tracing (0 otherwise)."""
+    from repro.telemetry import Telemetry, Tracer
+
+    params, spec, kind = _bmlp()
+    ticks = iter(range(10**6, 10**12, 1_000))
+    tel = Telemetry(tracer=Tracer(enabled=traced,
+                                  clock_ns=lambda: next(ticks)))
+    srv, clock = _server(max_batch=8, telemetry=tel)
+    srv.register("m", params, spec, kind=kind, backend="jnp")
+    srv.flush_hook = {"healthy": None, "retry": _fail_twice(),
+                      "bisect": _poison_first_row}[path]
+    xs = _inputs(11, srv.engine().example_shape)
+    for x in xs:
+        srv.submit(x)
+    done = srv.flush()
+    assert len(done) == 11
+    n_ok = sum(r.status == "ok" for r in done)
+    assert sum(f.batch for f in srv.flushes) == n_ok
+    assert n_ok == (10 if path == "bisect" else 11)
+    # bisect: [0..7] -> [0..3] -> [0, 1] -> [0] errors alone, [1] is
+    # served; then [2, 3], [4..7] and the second window [8..10]
+    assert len(srv.flushes) == {"healthy": 2, "retry": 2, "bisect": 4}[path]
+    if path == "retry":
+        assert srv.flushes[0].retries == 2
+        # the failed attempts and their backoff lie between packed and
+        # the call that succeeded
+        f = srv.flushes[0]
+        assert f.called_ns - f.packed_ns > f.dispatched_ns - f.called_ns
+    prev_done = 0
+    for f in srv.flushes:
+        st = _stamps(f)
+        if not traced:
+            assert f.ready_ns == 0
+            st.remove(0)
+        assert st == sorted(st), st       # a bisected half pops nothing
+        assert st[0] > prev_done
+        prev_done = st[-1]
+
+
+def test_flush_stamps_ready_while_profiling(tmp_path):
+    """A JAX profiler trace, with the server's tracer off, is enough for
+    the server to stamp ``ready_ns``: the profiled half of a benchmark
+    run splits the wait for the device from the copy to the host."""
+    params, spec, kind = _bmlp(sizes=(96, 128, 64, 12))
+    srv, _ = _server(max_batch=4)
+    srv.register("m", params, spec, kind=kind, backend="jnp")
+    x = _inputs(1, srv.engine().example_shape)[0]
+    srv.serve([x])
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.serve([x])
+    finally:
+        jax.profiler.stop_trace()
+    srv.serve([x])
+    assert [f.ready_ns > 0 for f in srv.flushes] == [False, True, False]
+    f = srv.flushes[1]
+    assert f.dispatched_ns <= f.ready_ns <= f.on_host_ns
+
+
+def test_flush_record_counts_compiles():
+    """``compiles`` counts the JAX backend compilations that started
+    during a flush's dispatch: a bucket's first flush compiles its
+    forward, the next flush through it compiles nothing."""
+    params, spec, kind = _bmlp(sizes=(96, 128, 64, 12))
+    srv, _ = _server(max_batch=4)
+    srv.register("m", params, spec, kind=kind, backend="jnp")
+    x = _inputs(1, srv.engine().example_shape)[0]
+    srv.serve([x] * 3)
+    srv.serve([x] * 3)
+    assert srv.flushes[0].compiles >= 1
+    assert srv.flushes[1].compiles == 0
+
+
 def test_register_validation():
     params, spec, _ = _bmlp()
     srv, _ = _server()

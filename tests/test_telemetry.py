@@ -106,6 +106,26 @@ def test_snapshot_reset_merge():
         c.merge(snap)
 
 
+@pytest.mark.parametrize("values", [
+    [2e-6, 2e-6, 2e-6, 0.5],
+    [3e-4, 1e-6, 12345.0, 0.0, 1e-6 * (1 + 1e-12)],   # edge, overflow
+    list(np.random.default_rng(0).lognormal(-7.0, 2.0, 513)),
+    [0.004],
+    [],
+], ids=["few", "edges_and_overflow", "many", "single", "empty"])
+def test_observe_many_equals_observe(values):
+    one, many = MetricsRegistry(), MetricsRegistry()
+    one.histogram("h")
+    for v in values:
+        one.histogram("h").observe(v)
+    many.histogram("h").observe_many(values)
+    assert many.snapshot() == one.snapshot()
+    many.histogram("h").observe_many(np.asarray(values[:2]))
+    for v in values[:2]:
+        one.histogram("h").observe(v)
+    assert many.snapshot() == one.snapshot()
+
+
 def test_single_sample_histogram_percentiles():
     h = MetricsRegistry().histogram("h")
     h.observe(0.004)
@@ -259,9 +279,10 @@ def test_serve_trace_spans_per_flush():
     x = np.zeros(srv.engine().example_shape, np.uint8)
     srv.serve([x, x])
     names = srv.telemetry.tracer.span_names()
-    for want in ("serve.submit", "serve.queue_wait", "serve.flush",
+    for want in ("serve.queue_wait", "serve.flush",
                  "serve.bucket_pad", "serve.pack", "serve.dispatch",
-                 "serve.compute", "serve.complete"):
+                 "serve.compute", "serve.ready", "serve.readback",
+                 "serve.complete"):
         assert want in names, names
     flushes = [e for e in srv.telemetry.tracer.events
                if e["name"] == "serve.flush"]
@@ -270,9 +291,38 @@ def test_serve_trace_spans_per_flush():
     # children nest inside the flush window
     f = flushes[0]
     for e in srv.telemetry.tracer.events:
-        if e["name"] in ("serve.pack", "serve.dispatch", "serve.compute"):
+        if e["name"] in ("serve.pack", "serve.dispatch", "serve.compute",
+                         "serve.ready", "serve.readback"):
             assert f["ts"] <= e["ts"]
             assert e["ts"] + e["dur"] <= f["ts"] + f["dur"] + 1e-6
+
+
+def test_serve_spans_are_the_flush_stamps():
+    """With the tracer on, every serve.* span of a flush is the interval
+    between two stamps of its FlushRecord: one system, not two."""
+    ticks = iter(range(10**6, 10**12, 1_000))
+    tel = Telemetry(tracer=Tracer(enabled=True,
+                                  clock_ns=lambda: next(ticks)))
+    srv = _smoke_server(max_batch=4, telemetry=tel)
+    x = np.zeros(srv.engine().example_shape, np.uint8)
+    for n in (3, 1, 4):
+        srv.serve([x] * n)
+    assert len(srv.flushes) == 3
+    spans = [e for e in tel.tracer.events if e["name"] != "serve.queue_wait"]
+    for name, a, b in SV.PHASE_SPANS:
+        got = [(e["ts"], e["dur"]) for e in spans if e["name"] == name]
+        want = [(getattr(f, a) / 1e3, (getattr(f, b) - getattr(f, a)) / 1e3)
+                for f in srv.flushes]
+        assert got == want, name
+    # the flush starts at its first stamp and ends after its last, so a
+    # reader that tests containment in microseconds keeps every phase
+    flushes = [e for e in spans if e["name"] == "serve.flush"]
+    for e, f in zip(flushes, srv.flushes, strict=True):
+        assert e["ts"] == f.start_ns / 1e3
+        assert e["ts"] + e["dur"] > f.done_ns / 1e3
+    waits = [e for e in tel.tracer.events if e["name"] == "serve.queue_wait"]
+    assert [e["ts"] + e["dur"] for e in waits] == \
+        [f.start_ns / 1e3 for f in srv.flushes for _ in range(f.batch)]
 
 
 def test_serve_tracing_disabled_records_nothing():
@@ -330,6 +380,17 @@ def test_pool_counters_buffer_reuse():
     buf1 = srv.pool.batch_buffer(1, eng.example_shape)
     buf2 = srv.pool.batch_buffer(1, eng.example_shape)
     assert buf1 is buf2                                  # same object reused
+
+
+def test_dispatch_batch_counted_once_per_flush():
+    g = telemetry.default().metrics
+    srv = _smoke_server(max_batch=4)
+    x = np.zeros(srv.engine().example_shape, np.uint8)
+    before = g.value("ops.dispatch.gemv") + g.value("ops.dispatch.gemm")
+    for n in (1, 3, 4, 2):
+        srv.serve([x] * n)
+    after = g.value("ops.dispatch.gemv") + g.value("ops.dispatch.gemm")
+    assert after - before == len(srv.flushes) == 4
 
 
 def test_dispatch_batch_counts_routes():
