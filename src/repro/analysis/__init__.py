@@ -25,6 +25,7 @@ from repro.analysis.graph import (CALL_PRIMITIVES, PallasLaunch,
                                   pallas_grids, pallas_launches, subjaxprs)
 from repro.analysis.vmem import (LaunchEstimate, VmemBudgetError, VmemTerm,
                                  attention_estimate, bitpack_estimate,
+                                 bitplane_dense_estimate,
                                  bn_sign_pack_estimate, conv_estimate,
                                  dense_stack_estimate, estimate_eqn,
                                  estimate_forward, gemm_estimate, preflight,
@@ -36,7 +37,8 @@ __all__ = [
     "max_intermediate_bytes", "pallas_eqns", "pallas_grids",
     "pallas_launches", "subjaxprs",
     "LaunchEstimate", "VmemBudgetError", "VmemTerm",
-    "attention_estimate", "bitpack_estimate", "bn_sign_pack_estimate",
+    "attention_estimate", "bitpack_estimate", "bitplane_dense_estimate",
+    "bn_sign_pack_estimate",
     "conv_estimate", "dense_stack_estimate", "estimate_eqn",
     "estimate_forward", "gemm_estimate", "preflight", "vmem_budget",
 ]

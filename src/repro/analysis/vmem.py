@@ -7,7 +7,8 @@ guard the repo had was ``dense_stack_fits_vmem``'s hand-rolled budget
 arithmetic for ONE kernel family.  This pass generalizes it:
 
 * **Closed-form estimators** (``gemm_estimate``, ``conv_estimate``,
-  ``attention_estimate``, ``dense_stack_estimate``, ...) mirror each
+  ``attention_estimate``, ``dense_stack_estimate``,
+  ``bitplane_dense_estimate``, ...) mirror each
   wrapper's own block-resolution math, so ``kernels/ops.py`` can
   :func:`preflight` a launch from shapes + knobs alone — at Python
   call time, before ``jax.jit`` ever traces.  An over-budget launch
@@ -243,6 +244,61 @@ def dense_stack_estimate(weight_shapes: Sequence[tuple[int, int]], *,
     terms.append(VmemTerm("stage_transient_peak", peak))
     return LaunchEstimate(kernel="dense_stack", grid=(1,),
                           terms=tuple(terms))
+
+
+# Accumulator budget of the bit-plane first layer, in int32 words: the
+# dense stack's (8, 4096) tile, 32 of the 64 vector registers.
+BITPLANE_ACC_WORDS = SUBLANE * 4096
+
+
+def bitplane_dense_blocks(m: int, n: int, nbits: int) -> tuple[int, int]:
+    """(images per row tile, N tile) of the single-launch bit-plane
+    first layer (``kernels.binary_matmul.bitplane_dense_packed``).
+
+    Up to 8 images ride in one row tile (at M = 1 the 8 planes are the
+    8 sublanes); larger batches tile 8 images at a time.  The N tile is
+    the widest multiple of 128 lanes that divides the lane-padded N and
+    keeps the (rows, N tile) int32 accumulator within
+    ``BITPLANE_ACC_WORDS``.
+    """
+    block_i = min(m, SUBLANE)
+    rows = _ceil_mult(block_i * nbits, SUBLANE)
+    groups = _ceil_mult(n, LANE) // LANE
+    fit = max(1, BITPLANE_ACC_WORDS // (rows * LANE))
+    block_n = max(d for d in range(1, min(fit, groups) + 1)
+                  if groups % d == 0) * LANE
+    return block_i, block_n
+
+
+def bitplane_dense_estimate(m: int, n: int, kw: int, *,
+                            nbits: int) -> LaunchEstimate:
+    """Estimate the single-launch bit-plane first layer for (M, K) input
+    of ``kw`` packed words against (Kw, N) word-major weights.
+
+    The staged blocks are exactly the wrapper's (:func:`estimate_eqn`
+    reads the same bytes and buffers off the traced launch); on top,
+    the (rows, N tile) accumulator and one loop step's 8 words of each
+    operand (the activation words transposed, lanes padded to 128).
+    """
+    block_i, block_n = bitplane_dense_blocks(m, n, nbits)
+    tiles = -(-m // block_i)
+    n_tiles = _ceil_mult(n, block_n) // block_n
+    rows = block_i * nbits
+    kw8 = _ceil_mult(kw, SUBLANE)
+    x_bufs = 1 if tiles == 1 else 2
+    w_bufs = 1 if n_tiles == 1 else 2
+    out_bufs = 1 if tiles == n_tiles == 1 else 2
+    terms = (
+        VmemTerm("x_block", kw8 * rows * 4, x_bufs),
+        VmemTerm("w_block", kw * block_n * 4, w_bufs),
+        VmemTerm("rowsum_block", block_n * 4, w_bufs),
+        VmemTerm("out_block", block_i * block_n * 4, out_bufs),
+        VmemTerm("acc_tile", rows * block_n * 4),
+        VmemTerm("step_words",
+                 SUBLANE * (_ceil_mult(rows, LANE) + block_n) * 4),
+    )
+    return LaunchEstimate(kernel="bitplane_dense", grid=(n_tiles, tiles),
+                          terms=terms)
 
 
 def conv_estimate(batch: int, padded_hw: tuple[int, int], cw: int,

@@ -143,10 +143,15 @@ def apply_binary_dense_stack_packed(packed_layers: list, foldeds: list,
 # ---------------------------------------------------------------------------
 
 def pack_bitplane_dense(params: Params, nbits: int = 8) -> Params:
+    """One-time packing for the fixed-precision first layer (paper C2):
+
+    the weights word-major, (Kw, N), so the single-launch bit-plane
+    kernel reads each packed word as a sublane row with no transpose,
+    and the eq.3 rowsum correction."""
     w = params["w"]
     wb = B.sign_pm1(w)
     return {
-        "w_packed": B.pack_bits(w),
+        "w_words": B.pack_bits(w).T,
         "k_true": w.shape[1],
         "w_rowsum": wb.sum(axis=1).astype(jnp.int32),   # the eq.3 correction
         "nbits": nbits,
@@ -157,27 +162,15 @@ def apply_bitplane_dense_packed(packed: Params, x_uint8: jax.Array, *,
                                 backend: str = "auto") -> jax.Array:
     """First layer on fixed-precision input, fully binary-optimized.
 
-    Splits x into bit-planes, runs one packed GEMM per plane against the
-    SAME packed weights, and recombines  y = 1/2 * sum_i 2^i (d_i + rowsum)
-    (exact integer identity; see ``core.binarize.bitplane_dot``).
+    Every bit plane is contracted against the SAME packed weights and
+    recombined  y = 1/2 * sum_i 2^i (d_i + rowsum)  (exact integer
+    identity; see ``core.binarize.bitplane_dot``) — on the pallas
+    backend in ONE kernel launch (``kops.bitplane_dense_packed``).
     Returns (..., N) int32 == x.astype(int32) @ sign(W)^T.
     """
-    nbits = packed["nbits"]
     lead = x_uint8.shape[:-1]
     x2 = x_uint8.reshape(-1, x_uint8.shape[-1])
-    planes = B.bitplanes_uint8(x2, nbits)                # (nbits, M, K) {0,1}
-    # Encode planes as ±1 by value>=?: bit 1 -> +1, bit 0 -> -1: pack_bits
-    # packs >=0 as 1, so shift to {-1,+1} first.
-    planes_pm1 = 2.0 * planes.astype(jnp.float32) - 1.0
-    acc = None
-    for i in range(nbits):
-        x_p = kops.bitpack(planes_pm1[i], backend=backend)
-        d = kops.binary_matmul_packed(x_p, packed["w_packed"],
-                                      k_true=packed["k_true"],
-                                      backend=backend)   # (M, N) int32
-        term = (d + packed["w_rowsum"][None, :]) << i
-        acc = term if acc is None else acc + term
-    out = acc >> 1                                        # exact: acc is even
+    out = kops.bitplane_dense_packed(packed, x2, backend=backend)
     return out.reshape(*lead, -1)
 
 

@@ -359,7 +359,8 @@ def bcnn_shard_plan(packed: Any, mesh: Mesh) -> dict:
 
 
 def bmlp_shard_plan(packed: Any, mesh: Mesh) -> dict:
-    douts = [p["w_packed"].shape[0] for p in packed["layers"]]
+    douts = [p["w_words"].shape[1] if "w_words" in p
+             else p["w_packed"].shape[0] for p in packed["layers"]]
     layer = tuple(packed_stage_shards(d, mesh) for d in douts[:-1]) + (1,)
     return {"layer": layer}
 
@@ -404,8 +405,10 @@ def _bmlp_spec_rule(shard_plan: dict):
     def rule(pstr: str, leaf) -> P | None:
         if not _is_array(leaf):
             return None
-        m = re.match(r"layers/(\d+)/(w_packed|w_rowsum)$", pstr)
+        m = re.match(r"layers/(\d+)/(w_packed|w_words|w_rowsum)$", pstr)
         if m and layer[int(m.group(1))] > 1:
+            if m.group(2) == "w_words":         # (Kw, N) word-major
+                return P(None, "model")
             return P("model") if leaf.ndim == 1 else P("model", None)
         m = re.match(r"folded/(\d+)/(tau|flip)$", pstr)
         if m and layer[int(m.group(1))] > 1:

@@ -32,6 +32,12 @@ uint32 operands:
   the grid becomes N-major 1-D: the packed activation block is pinned
   resident in VMEM, weight row blocks stream past it, and the
   contraction completes per program (no cross-step accumulator).
+* **Single-launch bit-plane first layer** (:func:`bitplane_dense_packed`)
+  — every (image, plane) pair of the fixed-precision input is one
+  contraction row against word-major weights packed once at load, so
+  the whole layer is one launch over only the real packed words, and
+  the epilogue folds the 2^p plane weights.  Its own contraction loop:
+  the shared one above transposes both operands on every call.
 
 HBM→VMEM staging via ``BlockSpec`` tiles is the TPU analogue of the
 paper's shared-memory tiling (C7); 32-bit packing words match the TPU
@@ -509,6 +515,113 @@ def binary_dense_stack_packed(x_packed: jax.Array, weights: list,
         interpret=interpret,
     )(*operands)
     return out[:m, :B.packed_width(weights[-1].shape[0])]
+
+
+# ---------------------------------------------------------------------------
+# Single-launch bit-plane first layer
+# ---------------------------------------------------------------------------
+
+def _bitplane_dense_kernel(x_ref, w_ref, rowsum_ref, o_ref, *, k_true: int,
+                           nbits: int):
+    """One (images, N) output tile of the bit-plane first layer.
+
+    ``x_ref``: (1, Kw₈, rows) — this tile's packed planes, word-major,
+    one lane per (image, plane) row in image-major order; ``w_ref``:
+    (Kw, bn) word-major packed weights, so each contraction step reads
+    its words as sublane rows with no transpose.  The loop walks only
+    the Kw real words: a step transposes 8 of them into (rows, 8)
+    columns and adds one (rows, bn) popcount-of-XOR per word, and a
+    static tail takes the last Kw mod 8.  The epilogue folds the 2^p
+    plane weights and the rowsum shift per image:
+
+        out = (2^n − 1)·(K + rowsum)/2 − Σ_p 2^p·mism_p
+
+    which is  1/2 Σ_p 2^p (K − 2·mism_p + rowsum)  — the exact identity
+    of ``core.binarize.bitplane_dot`` (K + rowsum is even: rowsum sums
+    K terms of ±1).
+    """
+    kw, bn = w_ref.shape
+    rows = x_ref.shape[2]
+    steps, rem = divmod(kw, _SUBLANE)
+
+    def chunk(start, size: int, acc: jax.Array) -> jax.Array:
+        a_cols = x_ref[0, pl.ds(start, _SUBLANE), :].T          # (rows, 8)
+        b_rows = jax.lax.bitcast_convert_type(
+            w_ref[pl.ds(start, size), :], jnp.int32)           # (size, bn)
+        for j in range(size):
+            acc = acc + jax.lax.population_count(
+                a_cols[:, j:j + 1] ^ b_rows[j:j + 1, :])
+        return acc
+
+    acc = jnp.zeros((rows, bn), jnp.int32)
+    if steps:
+        acc = jax.lax.fori_loop(
+            0, steps, lambda s, acc: chunk(s * _SUBLANE, _SUBLANE, acc), acc)
+    if rem:
+        acc = chunk(steps * _SUBLANE, rem, acc)
+    half = (((1 << nbits) - 1) * (jnp.int32(k_true) + rowsum_ref[...])) >> 1
+    plane = jax.lax.broadcasted_iota(jnp.int32, (nbits, bn), 0)
+    for i in range(o_ref.shape[0]):
+        mism = acc[i * nbits:(i + 1) * nbits] << plane
+        o_ref[i:i + 1, :] = half - jnp.sum(mism, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("k_true", "nbits",
+                                             "interpret"))
+def bitplane_dense_packed(x_planes: jax.Array, w_words: jax.Array,
+                          rowsum: jax.Array, *, k_true: int, nbits: int,
+                          interpret: bool = False) -> jax.Array:
+    """First-layer fixed-precision dense (paper C4) in ONE kernel launch.
+
+    ``x_planes``: (nbits, M, Kw) packed bit planes
+    (``core.binarize.pack_bitplanes_uint8``: plane bit == packed bit);
+    ``w_words``: (Kw, N) word-major packed weights and ``rowsum``: (N,)
+    int32 row sums of sign(W), both made once at pack time
+    (``core.binary_layers.pack_bitplane_dense``).  Returns (M, N) int32
+    == x.int32 @ sign(W)^T.
+
+    Each contraction row is one (image, plane) pair, so the 8 planes of
+    one image fill the 8 sublanes; the contraction runs over the Kw real
+    words (the row tile pads them only to the 8-word sublane multiple).
+    Grid: (N tiles, row tiles), N outer, so each weight tile is fetched
+    once per call and stays resident while the row tiles stream past;
+    at M = 1 and N = 4096 the grid is a single program.  Blocks come
+    from the shapes alone (``analysis.vmem.bitplane_dense_blocks``).
+    """
+    nb, m, kw = x_planes.shape
+    assert nb == nbits and w_words.shape[0] == kw, (x_planes.shape,
+                                                   w_words.shape, nbits)
+    n = w_words.shape[1]
+    block_i, block_n = vmem.bitplane_dense_blocks(m, n, nbits)
+    x_p = B.pad_to_multiple(x_planes, block_i, 1)
+    tiles = x_p.shape[1] // block_i
+    rows = block_i * nbits
+    # (nbits, M, Kw) -> (tiles, Kw₈, block_i·nbits): word-major, with
+    # the (image, plane) rows of a tile on lanes, image-major.
+    x_t = x_p.reshape(nbits, tiles, block_i, kw).transpose(1, 3, 2, 0)
+    x_t = B.pad_to_multiple(x_t.reshape(tiles, kw, rows), _SUBLANE, 1)
+    x_t = jax.lax.bitcast_convert_type(x_t, jnp.int32)
+    w_p = B.pad_to_multiple(w_words, block_n, 1)
+    rs = B.pad_to_multiple(rowsum.reshape(1, n).astype(jnp.int32),
+                           block_n, 1)
+    np_ = w_p.shape[1]
+
+    kernel = functools.partial(_bitplane_dense_kernel, k_true=k_true,
+                               nbits=nbits)
+    out = pl.pallas_call(
+        kernel,
+        name="_bitplane_dense_kernel",
+        grid=(np_ // block_n, tiles),
+        in_specs=[
+            pl.BlockSpec((1, x_t.shape[1], rows), lambda j, t: (t, 0, 0)),
+            pl.BlockSpec((kw, block_n), lambda j, t: (0, j)),
+            pl.BlockSpec((1, block_n), lambda j, t: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((block_i, block_n), lambda j, t: (t, j)),
+        out_shape=jax.ShapeDtypeStruct((tiles * block_i, np_), jnp.int32),
+        interpret=interpret,
+    )(x_t, w_p, rs)
+    return out[:m, :n]
 
 
 def _ceil_mult(x: int, m: int) -> int:

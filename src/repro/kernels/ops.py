@@ -450,6 +450,41 @@ def bitplane_conv2d_packed(plan: dict, x_uint8: jax.Array, *,
         c_out=plan["c_out"], k_true=plan["k_true"], nbits=nbits)
 
 
+def bitplane_dense_packed(packed: dict, x_uint8: jax.Array, *,
+                          backend: str = "auto") -> jax.Array:
+    """First-layer fixed-precision dense (paper C4) on a
+
+    ``core.binary_layers.pack_bitplane_dense`` layer.  ``x_uint8``:
+    (M, K) raw integer input.  Returns (M, N) int32 == x.int32 @
+    sign(W)^T.
+
+    backend: 'pallas' — plane extraction/packing is pure jnp bit ops
+    (``pack_bitplanes_uint8``) and the layer is ONE kernel launch
+    (every (image, plane) pair a contraction row, the 2^i weighting and
+    rowsum correction in the epilogue; ``binary_matmul.
+    bitplane_dense_packed``); 'jnp'/'ref' — the sequential per-plane
+    oracle; 'auto' as everywhere.  Unknown backends raise
+    ``ValueError``.  Each pallas dispatch bumps
+    ``ops.dispatch.bitplane_dense`` on the process-wide telemetry
+    registry, beside ``ops.dispatch.gemv``/``gemm``.
+    """
+    backend = _resolve(backend)
+    nbits = packed["nbits"]
+    if backend == "pallas":
+        x_planes = B.pack_bitplanes_uint8(x_uint8, nbits)
+        kw, n = packed["w_words"].shape
+        _vmem.preflight(_vmem.bitplane_dense_estimate(
+            x_uint8.shape[0], n, kw, nbits=nbits))
+        telemetry.default().metrics.counter(
+            "ops.dispatch.bitplane_dense").inc()
+        return _bmm.bitplane_dense_packed(
+            x_planes, packed["w_words"], packed["w_rowsum"],
+            k_true=packed["k_true"], nbits=nbits, interpret=not _on_tpu())
+    return _ref.bitplane_dense_packed_ref(
+        x_uint8, packed["w_words"], packed["w_rowsum"],
+        k_true=packed["k_true"], nbits=nbits)
+
+
 def bn_sign_pack(x: jax.Array, tau: jax.Array, flip: jax.Array, *,
                  backend: str = "auto") -> jax.Array:
     """Fused sign(BN(x)) + bit-pack along the last axis.

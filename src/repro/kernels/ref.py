@@ -105,6 +105,27 @@ def binary_conv2d_packed_ref(x_packed: jax.Array, w_packed: jax.Array,
     return out + correction[None]
 
 
+def bitplane_dense_packed_ref(x_uint8: jax.Array, w_words: jax.Array,
+                              rowsum: jax.Array, *, k_true: int,
+                              nbits: int) -> jax.Array:
+    """Reference first-layer dense (paper C4): the SEQUENTIAL plane loop.
+
+    One packed GEMM per bit plane (plane bit b -> ±1 via 2b−1) against
+    the word-major weights ``w_words`` (Kw, N), recombined with
+    x·w = 1/2 Σ_i 2^i (p̂_i ⊙ w + rowsum).  The single-launch Pallas
+    kernel (``binary_matmul.bitplane_dense_packed``) must match it
+    bit-for-bit, and both equal x.int32 @ sign(W)^T.
+    """
+    acc = None
+    for i in range(nbits):
+        plane = ((x_uint8.astype(jnp.uint32) >> i) & 1)
+        xp = B.pack_bits(2.0 * plane.astype(jnp.float32) - 1.0)
+        d = B.packed_matmul(xp, w_words.T, k_true)
+        term = (d + rowsum[None, :]) << i
+        acc = term if acc is None else acc + term
+    return acc >> 1
+
+
 def bitplane_conv2d_packed_ref(x_uint8: jax.Array, w_packed: jax.Array,
                                rowsum: jax.Array, *, kh: int, kw: int,
                                stride: int, pads, c_out: int, k_true: int,

@@ -60,6 +60,7 @@ import time
 
 import numpy as np
 
+from repro import telemetry
 from repro.models import cnn
 from repro.train import serve as SV
 
@@ -273,7 +274,8 @@ def main() -> None:
     ap.add_argument("--chaos-report", default=None, metavar="PATH",
                     help="write the chaos recovery report as JSON")
     ap.add_argument("--metrics", action="store_true",
-                    help="print the server's telemetry metrics snapshot "
+                    help="print the server's and the process-wide "
+                         "(kernel dispatch) telemetry metrics snapshot "
                          "as JSON after the run")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="enable span tracing and write a Chrome "
@@ -345,8 +347,11 @@ def main() -> None:
           f"hit(s); scratch pool: {srv.pool.allocations} buffer(s) for "
           f"{len(srv.flushes)} flushes")
     if args.metrics:
-        print(json.dumps(srv.telemetry.metrics.snapshot(), indent=1,
-                         sort_keys=True))
+        # The server's registry, plus the process-wide one the kernel
+        # dispatch seams write (ops.dispatch.*, sharding.gathers).
+        snap = {**telemetry.default().metrics.snapshot(),
+                **srv.telemetry.metrics.snapshot()}
+        print(json.dumps(snap, indent=1, sort_keys=True))
     if args.trace_out:
         srv.telemetry.tracer.export(args.trace_out)
         print(f"wrote {len(srv.telemetry.tracer.events)} trace events -> "

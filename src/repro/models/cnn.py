@@ -429,7 +429,10 @@ def packed_dense_kw_words(packed: dict) -> int:
         mats.append(packed["head"])
         return max(int(p["w_packed"].shape[1]) for p in mats)
     layers = packed["denses"] if kind == "bcnn" else packed["layers"]
-    return max(int(p["w_packed"].shape[1]) for p in layers)
+    # The BMLP's bit-plane first layer (word-major ``w_words``) is one
+    # launch at every batch and takes no part in the routing.
+    return max(int(p["w_packed"].shape[1]) for p in layers
+               if "w_packed" in p)
 
 
 def demo_model(kind: str, *, smoke: bool = False, seed: int = 0):

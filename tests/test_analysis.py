@@ -159,6 +159,26 @@ def test_ops_preflight_catches_seeded_over_budget(monkeypatch):
     assert out.shape == (256, 16)
 
 
+@pytest.mark.parametrize("m", [1, 4, 16, 256])
+def test_bitplane_dense_estimate_matches_traced_launch(m):
+    """The closed-form bit-plane estimate stages exactly the blocks the
+    traced launch does (bytes and buffers), on the same grid, and adds
+    the accumulator and loop-step transients on top."""
+    from repro.core import binary_layers as L
+    packed = L.pack_bitplane_dense(
+        {"w": np.ones((4096, 784), np.float32)}, nbits=8)
+    x = np.zeros((m, 784), np.uint8)
+    closed = VM.bitplane_dense_estimate(m, 4096, 25, nbits=8)
+    (traced,) = estimate_forward(
+        lambda v: kops.bitplane_dense_packed(packed, v, backend="pallas"),
+        x)
+    assert traced.kernel == "_bitplane_dense_kernel"
+    assert closed.grid == traced.grid
+    staged = [(t.bytes, t.buffers) for t in closed.terms[:4]]
+    assert staged == [(t.bytes, t.buffers) for t in traced.terms]
+    assert closed.total > traced.total and closed.fits()
+
+
 def test_gemm_estimate_tracks_dispatch_route():
     assert gemm_estimate(1, 1000, 64).kernel == "gemv"
     assert gemm_estimate(64, 1000, 64).kernel == "gemm"

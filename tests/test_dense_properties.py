@@ -18,7 +18,9 @@ Invariants, sampled over the awkward-shape grid in ``strategies.py``:
   (raise instead of silently clamping),
 * ``apply_bitplane_dense_packed`` (first-layer dense, paper C4) == the
   float oracle on both backends — previously only exercised indirectly
-  through ``bmlp_forward_packed``.
+  through ``bmlp_forward_packed`` — and its single-launch kernel == the
+  per-plane jnp oracle == x.int32 @ sign(W)^T across row tiles, N tiles,
+  ragged K and N, and plane counts.
 """
 from _hypothesis_compat import hypothesis, st
 import jax
@@ -187,15 +189,15 @@ def test_bmlp_hidden_stack_is_single_kernel_launch():
     """The acceptance criterion: bmlp_forward_packed's hidden stack
     traces to exactly ONE pallas_call on the VMEM-resident path.
 
-    Launch budget of the whole forward: 2·nbits for the bit-plane first
-    layer (per-plane pack + GEMM), 1 standalone epilogue, H launches for
+    Launch budget of the whole forward: 1 for the bit-plane first layer
+    (every plane in one launch), 1 standalone epilogue, H launches for
     the H-layer hidden stack (1 when resident), 1 output GEMM."""
     key = jax.random.PRNGKey(7)
     spec = cnn.BMLPSpec(sizes=(20, 64, 96, 64, 10), nbits_input=2)
     packed = cnn.pack_bmlp(cnn.init_bmlp(key, spec), spec)
     x = jax.random.randint(jax.random.fold_in(key, 1), (3, 20), 0,
                            4).astype(jnp.uint8)
-    base = 2 * spec.nbits_input + 1 + 1         # bit-plane + epi + output
+    base = 1 + 1 + 1                            # bit-plane + epi + output
     n_res = count_pallas_calls(
         lambda v: cnn.bmlp_forward_packed(packed, v, backend="pallas",
                                           dense_stack="auto"), x)
@@ -326,3 +328,47 @@ def test_bitplane_dense_uint8_edges_exact():
         for backend in ("jnp", "pallas"):
             got = L.apply_bitplane_dense_packed(packed, x, backend=backend)
             np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("m,k,n,nbits", [
+    (1, 784, 96, 8),      # BMLP K: 24 full words + a 16-bit tail word
+    (3, 50, 10, 8),       # K and N far below a word / a lane group
+    (8, 784, 130, 8),     # 8 images = 64 plane rows, N past 128
+    (9, 50, 33, 2),       # two row tiles, 2-bit input
+    (16, 100, 1100, 1),   # 1-bit input, several N tiles (1100 -> 3 x 384)
+    (3, 784, 200, 2),
+    (1, 50, 2048, 8),     # BMLP width over a 2-way model axis
+])
+def test_bitplane_dense_single_launch_matches_oracles(m, k, n, nbits):
+    """The single-launch kernel == the jnp per-plane oracle ==
+    x.int32 @ sign(W)^T, bit for bit, in exactly one launch."""
+    key = jax.random.PRNGKey(m * 7919 + k * 31 + n + nbits)
+    params = L.init_binary_dense(key, k, n)
+    x = jax.random.randint(jax.random.fold_in(key, 1), (m, k), 0,
+                           1 << nbits).astype(jnp.uint8)
+    packed = L.pack_bitplane_dense(params, nbits=nbits)
+    want = np.asarray(x, np.int64) @ np.where(
+        np.asarray(params["w"]) >= 0, 1, -1).T
+    oracle = np.asarray(ops.bitplane_dense_packed(packed, x, backend="jnp"))
+    got = np.asarray(ops.bitplane_dense_packed(packed, x, backend="pallas"))
+    assert got.dtype == np.int32 and got.shape == (m, n)
+    np.testing.assert_array_equal(oracle, want)
+    np.testing.assert_array_equal(got, want)
+    assert count_pallas_calls(
+        lambda v: ops.bitplane_dense_packed(packed, v, backend="pallas"),
+        x) == 1
+
+
+def test_bitplane_dense_dispatch_counted():
+    """Each pallas dispatch bumps ``ops.dispatch.bitplane_dense``; the
+    jnp oracle does not."""
+    from repro import telemetry
+    params = L.init_binary_dense(jax.random.PRNGKey(5), 40, 16)
+    packed = L.pack_bitplane_dense(params, nbits=2)
+    x = jnp.full((2, 40), 3, jnp.uint8)
+    counter = telemetry.default().metrics.counter(
+        "ops.dispatch.bitplane_dense")
+    before = counter.value
+    ops.bitplane_dense_packed(packed, x, backend="pallas")
+    ops.bitplane_dense_packed(packed, x, backend="jnp")
+    assert counter.value == before + 1

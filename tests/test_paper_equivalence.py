@@ -146,5 +146,7 @@ def test_paper_architectures_instantiate():
     params = cnn.init_bmlp(key, spec)
     packed = cnn.pack_bmlp(params, spec)
     fp_bytes = sum(p["w"].size * 4 for p in params["layers"])
-    bin_bytes = sum(p["w_packed"].size * 4 for p in packed["layers"])
+    # Layer 0 (bit-plane) keeps its words word-major, as ``w_words``.
+    bin_bytes = sum(p.get("w_packed", p.get("w_words")).size * 4
+                    for p in packed["layers"])
     assert fp_bytes / bin_bytes > 28     # ~32x less (padding overhead)

@@ -87,7 +87,7 @@ def test_bmlp_shard_plan_and_specs():
     plan = SH.bmlp_shard_plan(packed, mesh)
     assert plan["layer"] == (2, 1, 1)    # 96 falls back, 10 replicated
     specs = SH.packed_param_specs(packed, mesh)
-    assert specs["layers/0/w_packed"] == P("model")
+    assert specs["layers/0/w_words"] == P(None, "model")   # (Kw, N)
     assert specs["layers/0/w_rowsum"] == P("model")
     assert specs["layers/1/w_packed"] == P()
     assert specs["folded/0/tau"] == P("model")

@@ -80,8 +80,22 @@ def _stack_case(m, width, layers):
     return fn, [((m, kw), U32)] + stage * layers
 
 
+def _bitplane_dense_case(m, k, n, nbits=8):
+    kw = B.packed_width(k)
+    return (lambda x, w, rs: bmm.bitplane_dense_packed(
+        x, w, rs, k_true=k, nbits=nbits),
+            [((nbits, m, kw), U32), ((kw, n), U32), ((n,), I32)])
+
+
 CASES = {
-    # BMLP: the eight bit-plane GEMVs of layer 0, then the hidden layers.
+    # BMLP: the single-launch bit-plane layer 0 at every served bucket,
+    # then the hidden layers and the output GEMV.
+    "bitplane_dense_b1_784x4096": lambda: _bitplane_dense_case(1, 784, 4096),
+    "bitplane_dense_b4_784x4096": lambda: _bitplane_dense_case(4, 784, 4096),
+    "bitplane_dense_b16_784x4096": lambda: _bitplane_dense_case(
+        16, 784, 4096),
+    "bitplane_dense_b256_784x4096": lambda: _bitplane_dense_case(
+        256, 784, 4096),
     "gemv_b1_784x4096": lambda: _gemm_case(1, 784, 4096),
     "gemv_b1_4096x4096": lambda: _gemm_case(1, 4096, 4096),
     "gemm_b256_4096x4096": lambda: _gemm_case(256, 4096, 4096),
