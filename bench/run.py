@@ -392,6 +392,32 @@ def setup(cell: dict, cfg: dict, mix: dict, *,
                  phases=phases, watch=watch)
 
 
+def lateness_outside_flushes(w: Window) -> list[float]:
+    """Lateness of the requests due while no flush ran (from a flush's
+    ``start_ns`` stamp to its end, ``at``): how far the open loop fell
+    behind its schedule for a reason other than waiting for a flush."""
+    if not w.lateness_s:
+        return []
+    due, late = (np.array(x) for x in zip(*w.lateness_s))
+    if not w.flushes:
+        return late.tolist()
+    spans = np.array(sorted((f.start_ns * 1e-9, f.at) for f in w.flushes))
+    ends = np.maximum.accumulate(spans[:, 1])
+    t = w.t0 + due
+    k = np.searchsorted(spans[:, 0], t, side="right") - 1
+    inside = (k >= 0) & (t <= ends[np.maximum(k, 0)])
+    return late[~inside].tolist()
+
+
+def _outside(w: Window) -> str:
+    late = lateness_outside_flushes(w)
+    if not late:
+        return "none due outside flushes;"
+    return (f"due outside flushes {len(late)}: lateness p99 "
+            f"{percentile(late, 99) * 1e3:.4f}ms max "
+            f"{max(late) * 1e3:.4f}ms;")
+
+
 def _log_window(tag: str, w: Window) -> None:
     routes: dict = {}
     for f in w.flushes:
@@ -413,7 +439,8 @@ def _log_window(tag: str, w: Window) -> None:
             f"ms p99 {percentile(late, 99) * 1e3:.4f}ms over {len(late)} "
             f"requests; worst (due s, late ms) "
             f"{[(round(a, 3), round(b * 1e3, 3)) for a, b in worst]}; "
-            f"latency p50 / p95 / p99 over {len(w.latencies_s)} samples: "
+            + _outside(w) +
+            f" latency p50 / p95 / p99 over {len(w.latencies_s)} samples: "
             + " / ".join(f"{percentile(w.latencies_s, q) * 1e3:.4f}"
                          for q in (50, 95, 99)) + " ms")
 
@@ -532,11 +559,13 @@ def run(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
     del srv, w, windows, ready
     gc.unfreeze()
     gc.collect()
+    t = time.perf_counter()
     ref = checks.reference_logits(refmod, cfg, params, sample_x)
     gap = (checks.logit_gap(sample_y, ref) if len(sample_y) else
            float("inf"))
     correct, checked = checks.judge(gap, failed)
-    log(f"compared {len(ref)} served rows with the reference")
+    log(f"compared {len(ref)} served rows with the reference in "
+        f"{time.perf_counter() - t:.3f}s")
     for name, c in checked.items():
         log(f"check {name}: {c['value']!r} (limit {c['limit']!r})")
     return result_line(correct=correct, attempted=attempted, failed=failed,

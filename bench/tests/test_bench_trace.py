@@ -148,6 +148,21 @@ def test_readers(reduced):
                                                 / 393e12)
 
 
+@pytest.mark.parametrize("flushes,share", [
+    ([(64, 64), (16, 16), (1, 1)], 0.0),
+    ([(20, 64)], 68.75),
+    ([(20, 64), (64, 64)], 100 * 44 / 128),
+    ([], None)], ids=["full", "one_20_in_64", "mixed", "none"])
+def test_padded_share(flushes, share):
+    ctx = run.LayerContext(
+        trace=None, spans=[], cfg={}, reference=None, device_kind="",
+        flushes=[{"batch": b, "bucket": k, "route": "gemm"}
+                 for b, k in flushes],
+        images=sum(b for b, _ in flushes))
+    got = run.metric_reader("server.padded_share").read(ctx)
+    assert got == (None if share is None else pytest.approx(share))
+
+
 @pytest.fixture(scope="module")
 def recorded():
     """A 3 s traced window of ``bmlp.interactive`` recorded on one

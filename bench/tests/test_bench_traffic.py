@@ -1,4 +1,6 @@
 """The load generator: seeded, fixed work, full batches."""
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -46,8 +48,29 @@ def test_closed_batch_full_batches_in_turn():
         np.testing.assert_array_equal(batch, t.pool[k % 3])
 
 
-def test_make_is_seeded():
-    mix = {"arrivals": "open_poisson", "rate_per_s": 100, "pool_images": 8}
+def _digest(a: np.ndarray) -> str:
+    return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()[:16]
+
+
+def test_existing_kinds_draw_what_they_drew_before_open_onoff():
+    """The schedules and pools of ``open_poisson`` and ``closed_batch``
+    are pinned to their bytes (times rounded to the nanosecond), so that
+    no change to the generator moves a cell's traffic unseen."""
+    due = loadgen.open_poisson(100, 20, BIG_SEED)
+    assert _digest(np.round(due, 9)) == "1b7b6fa2346c523c"
+    t = loadgen.make({"arrivals": "open_poisson", "rate_per_s": 100,
+                      "pool_images": 8}, 20, BIG_SEED, (784,))
+    assert _digest(np.round(t.due, 9)) == "1b7b6fa2346c523c"
+    assert _digest(t.pool) == "9f6deec65181ca8b"
+    assert _digest(t.image_of) == "0ead4aef325ad6bb"
+    t = loadgen.make({"arrivals": "closed_batch", "batch": 256,
+                      "pool_batches": 3}, 10, BIG_SEED, (32, 32, 3))
+    assert _digest(t.pool) == "440b925ded3bdc23"
+
+
+@pytest.mark.parametrize("arrivals", ["open_poisson"])
+def test_make_is_seeded(arrivals):
+    mix = {"arrivals": arrivals, "rate_per_s": 100, "pool_images": 8}
     a = loadgen.make(mix, 2, BIG_SEED, (784,))
     b = loadgen.make(mix, 2, BIG_SEED, (784,))
     c = loadgen.make(mix, 2, BIG_SEED + 1, (784,))
