@@ -35,11 +35,13 @@ Two policies, matching the two workload families:
   kernel output other than packed words is tracked, and the taint
   survives float laundering (an int32 GEMM output that is sign()-ed to
   float and then re-packed is exactly the leak this pass exists for).
-* ``float-residual`` (``transformer``): the residual stream is float
-  by design (paper's LM serving path), so float kernel outputs are a
-  legal class and an int → float conversion ends the taint (the V / Q
-  / K projections are *meant* to step through float before
-  re-binarizing).
+* ``float-residual`` (``transformer``, ``reactnet``): the residual
+  stream is float by design (the paper's LM serving path; ReActNet's
+  float32 stream beside the packed sign), so float kernel outputs are a
+  legal class, counted under ``float`` in every report, and an int →
+  float conversion ends the taint (the V / Q / K projections are
+  *meant* to step through float before re-binarizing).  An int32
+  kernel output between binary layers is still an escape.
 
 The headline per-model number is ``max_live_unpacked_bytes``: a
 liveness sweep over the unpacked class — the peak HBM footprint of
@@ -344,4 +346,5 @@ def analyze_packedness(fn: Any, *args: Any,
 
 def model_policy(kind: str) -> str:
     """The packedness policy each workload family is verified under."""
-    return "float-residual" if kind == "transformer" else "strict"
+    return ("float-residual" if kind in ("transformer", "reactnet")
+            else "strict")

@@ -3,7 +3,7 @@ models, keyed like ``PROBES_baseline.json`` and drift-gated in CI.
 
 Cells (stable keys — they ARE the baseline diff surface):
 
-* ``packedness/{bmlp,bcnn,transformer}`` — the packedness dataflow
+* ``packedness/{bmlp,bcnn,transformer,reactnet}`` — the packedness dataflow
   verdict for each packed forward at the serving batch (8): HBM
   crossing classification, max live unpacked bytes, escapes (must stay
   empty);
@@ -41,9 +41,10 @@ def repo_root() -> str:
 
 
 def demo_packed(kind: str) -> Any:
-    """The shared demo configs every standing gate probes: the two
-    smoke-sized demo networks and the reduced gemma2 binary LM (the
-    same builders ``telemetry/probes.py`` records baselines for)."""
+    """The shared demo configs every standing gate probes: the three
+    smoke-sized demo image networks and the reduced gemma2 binary LM
+    (the same builders ``telemetry/probes.py`` records baselines
+    for)."""
     from repro.models import cnn
 
     if kind == "transformer":
@@ -56,7 +57,8 @@ def demo_packed(kind: str) -> Any:
         params = TF.init_binary_lm(jax.random.PRNGKey(0), cfg)
         return TF.pack_transformer(params, cfg, max_len=8)
     params, spec, kind = cnn.demo_model(kind, smoke=True)
-    pack = cnn.pack_bcnn if kind == "bcnn" else cnn.pack_bmlp
+    pack = {"bcnn": cnn.pack_bcnn, "bmlp": cnn.pack_bmlp,
+            "reactnet": cnn.R.pack_reactnet}[kind]
     return pack(params, spec)
 
 
@@ -119,7 +121,7 @@ def sharding_cell(kind: str, *,
 def merged_report(*, sharded: bool = True) -> dict:
     """All four passes over the canonical cells (see module docstring)."""
     cells: dict[str, Any] = {}
-    for kind in ("bmlp", "bcnn", "transformer"):
+    for kind in ("bmlp", "bcnn", "transformer", "reactnet"):
         cells[f"packedness/{kind}"] = packedness_cell(kind)
         cells[f"vmem/{kind}_b{REPORT_BATCH}"] = vmem_cell(kind)
     cells["lint"] = lint_cell()

@@ -8,7 +8,7 @@ arithmetic for ONE kernel family.  This pass generalizes it:
 
 * **Closed-form estimators** (``gemm_estimate``, ``conv_estimate``,
   ``attention_estimate``, ``dense_stack_estimate``,
-  ``bitplane_dense_estimate``, ...) mirror each
+  ``bitplane_dense_estimate``, ``residual_conv_estimate``, ...) mirror each
   wrapper's own block-resolution math, so ``kernels/ops.py`` can
   :func:`preflight` a launch from shapes + knobs alone — at Python
   call time, before ``jax.jit`` ever traces.  An over-budget launch
@@ -55,6 +55,10 @@ WORD_BITS = 32
 GEMV_MAX_KW = 4096
 
 DEFAULT_VMEM_BUDGET = 16 * 2**20
+
+# Rows of a residual epilogue's per-channel table
+# (kernels.fused_epilogue.EPI_ROWS).
+EPI_ROWS = 8
 
 
 def vmem_budget() -> int:
@@ -339,6 +343,115 @@ def conv_estimate(batch: int, padded_hw: tuple[int, int], cw: int,
         ("conv_bn_sign" if fused else "conv"),
         grid=(batch, m_tiles, c_out_p // block_n),
         terms=tuple(terms))
+
+
+# Residual kernels' tile budget: output pixels (rows) per tile, and
+# pixels x packed words a tile contracts, which bounds the unrolled word
+# loop's vector ops (and so the kernel's compile time).
+RESIDUAL_TILE_M = 1024
+RESIDUAL_TILE_WORDS = 4096
+
+
+def residual_block_n(c_out: int) -> int:
+    """C_out block of the residual kernels: the whole C_out up to one
+    lane group (a block as wide as its array, not padded to 128 lanes:
+    ReActNet's 32- and 64-channel blocks), else 128 lanes."""
+    if c_out % WORD_BITS or (c_out > LANE and c_out % LANE):
+        raise ValueError(f"residual kernels need C_out a multiple of 32, "
+                         f"and of 128 above 128; got {c_out}")
+    return min(c_out, LANE)
+
+
+def _tile_cap(cw: int) -> int:
+    return max(SUBLANE, min(RESIDUAL_TILE_M, RESIDUAL_TILE_WORDS // cw))
+
+
+def residual_conv_blocks(oh: int, ow: int, cw: int,
+                         c_out: int) -> tuple[int, int]:
+    """(output rows per M tile, C_out block) of
+    ``kernels.binary_conv.binary_conv2d_residual_packed``.  The row band
+    is the largest divisor of OH whose tile stays within the residual
+    tile budget (at least one row) and is a whole number of sublane
+    groups, or the whole image."""
+    cap = _tile_cap(cw)
+    fits = [d for d in range(1, oh + 1) if oh % d == 0 and
+            (d == oh or (d * ow) % SUBLANE == 0)]
+    within = [d for d in fits if d * ow <= cap]
+    return (max(within) if within else min(fits)), residual_block_n(c_out)
+
+
+def pointwise_residual_blocks(m: int, kw: int,
+                              n: int) -> tuple[int, int]:
+    """(row tile, N block) of ``kernels.binary_matmul.
+    pointwise_residual_packed``: the largest multiple of 8 rows within
+    the residual tile budget that divides M, or all of M when it fits;
+    else the budget itself (the wrapper pads M)."""
+    cap = _tile_cap(kw) // SUBLANE * SUBLANE
+    if m <= cap:
+        return m, residual_block_n(n)
+    for bm in range(cap, SUBLANE - 1, -SUBLANE):
+        if m % bm == 0:
+            return bm, residual_block_n(n)
+    return cap, residual_block_n(n)
+
+
+def _lanes(c: int) -> int:
+    return _ceil_mult(c, LANE)
+
+
+def residual_conv_estimate(batch: int, in_hw: tuple[int, int], cw: int,
+                           kh: int, kw: int, c_out: int,
+                           out_hw: tuple[int, int], *,
+                           stride: int) -> LaunchEstimate:
+    """Estimate the residual conv launch: the tile's padded packed input
+    row band, the tap-major weight block, the correction, epilogue rows,
+    shortcut and both output tiles (all double-buffered, lanes padded to
+    128), plus the accumulator and float epilogue transients."""
+    h, w = in_hw
+    oh, ow = out_hw
+    block_oh, bn = residual_conv_blocks(oh, ow, cw, c_out)
+    block_m = block_oh * ow
+    hblk, wp = (block_oh - 1) * stride + kh, w + kw - 1
+    sc_rows = block_m if stride == 1 else 2 * block_oh * w
+    terms = (
+        VmemTerm("row_band_block", hblk * _ceil_mult(wp, SUBLANE)
+                 * _lanes(cw) * 4, 2),
+        VmemTerm("weight_block", kh * kw * _ceil_mult(cw, SUBLANE)
+                 * _lanes(bn) * 4, 2),
+        VmemTerm("correction_block", block_m * _lanes(bn) * 4, 2),
+        VmemTerm("epilogue_block", EPI_ROWS * _lanes(bn) * 4, 2),
+        VmemTerm("shortcut_block", sc_rows * _lanes(bn) * 4, 2),
+        VmemTerm("stream_block", block_m * _lanes(bn) * 4, 2),
+        VmemTerm("packed_block", block_m * LANE * 4, 2),
+        VmemTerm("acc_tile", block_m * _lanes(bn) * 4),
+        VmemTerm("epilogue_tiles", 2 * block_m * _lanes(bn) * 4),
+    )
+    return LaunchEstimate(kernel="conv_residual",
+                          grid=(batch, oh // block_oh, c_out // bn),
+                          terms=terms)
+
+
+def pointwise_residual_estimate(m: int, kw: int, n: int,
+                                c_shortcut: int) -> LaunchEstimate:
+    """Estimate the residual pointwise launch: the packed row tile, the
+    word-major weight block, epilogue rows, shortcut and both output
+    tiles (double-buffered, lanes padded to 128), plus the accumulator
+    and float epilogue transients."""
+    bm, bn = pointwise_residual_blocks(m, kw, n)
+    terms = (
+        VmemTerm("row_block", bm * _lanes(kw) * 4, 2),
+        VmemTerm("weight_block", _ceil_mult(kw, SUBLANE) * _lanes(bn) * 4,
+                 2),
+        VmemTerm("epilogue_block", EPI_ROWS * _lanes(bn) * 4, 2),
+        VmemTerm("shortcut_block", bm * _lanes(min(bn, c_shortcut)) * 4, 2),
+        VmemTerm("stream_block", bm * _lanes(bn) * 4, 2),
+        VmemTerm("packed_block", bm * LANE * 4, 2),
+        VmemTerm("acc_tile", bm * _lanes(bn) * 4),
+        VmemTerm("epilogue_tiles", 2 * bm * _lanes(bn) * 4),
+    )
+    return LaunchEstimate(kernel="pointwise_residual",
+                          grid=(_ceil_mult(m, bm) // bm, n // bn),
+                          terms=terms)
 
 
 def attention_estimate(b: int, hq: int, sq: int, skv: int, dw: int,

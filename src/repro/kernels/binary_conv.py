@@ -37,9 +37,17 @@ planes, folding the ``2^i`` plane weighting and the rowsum form of the
 pad correction into the epilogue — one kernel launch where the model
 previously issued 8 sequential plane convs.
 
-Supported: arbitrary integer stride (paper evaluates 1 and 2), SAME and
-VALID padding; spatial padding is staged as all-zero words (bit 0 == −1,
-the paper's convention) and corrected exactly in the epilogue.
+ReActNet's residual conv is a fourth, :func:`binary_conv2d_residual_packed`:
+the same contraction and pad correction as a rolled loop over the taps,
+then an epilogue that adds a float32 shortcut and writes both the float
+stream and its packed sign (``fused_epilogue.residual_epilogue``).
+
+Supported: arbitrary integer stride (paper evaluates 1 and 2), SAME,
+VALID or explicit padding; spatial padding is staged as all-zero words
+(bit 0 == −1, the paper's convention) and corrected exactly in the
+epilogue.  The BCNN's kernels slice taps out of a loaded slab, which
+Mosaic allows at stride 1 only; the residual kernel reads strided taps
+from the ref.
 """
 from __future__ import annotations
 
@@ -49,9 +57,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.analysis import vmem
 from repro.core import binarize as B
-from repro.kernels.fused_epilogue import (bn_sign_bits_to_words,
-                                          check_block_lanes, pad_bn_params,
+from repro.kernels.fused_epilogue import (EPI_ROWS, avg_pool2x2,
+                                          bn_sign_bits_to_words,
+                                          check_block_lanes, pack_lanes,
+                                          pad_bn_params, residual_epilogue,
                                           unblock_packed)
 
 # Minimum tile granularity on TPU: (8 sublanes, 128 lanes).
@@ -68,15 +79,24 @@ _DEFAULT_TILE_M = 1024
 # ---------------------------------------------------------------------------
 
 def conv_geometry(input_hw: tuple[int, int], kh: int, kw: int, stride: int,
-                  padding: str) -> tuple[tuple[int, int], tuple]:
+                  padding) -> tuple[tuple[int, int], tuple]:
     """Output spatial size and ((top, bottom), (left, right)) pads.
 
-    Matches XLA's SAME/VALID conventions (extra pad goes low-index-last,
-    i.e. bottom/right), so the packed path lines up pixel-for-pixel with
-    ``jax.lax.conv_general_dilated``.
+    ``padding``: 'SAME' or 'VALID', matching XLA's conventions (extra
+    pad goes low-index-last, i.e. bottom/right), so the packed path lines
+    up pixel-for-pixel with ``jax.lax.conv_general_dilated``; or explicit
+    ((top, bottom), (left, right)) pads, as PyTorch's ``padding=1`` is
+    ((1, 1), (1, 1)) at any stride where 'SAME' pads (0, 1) at stride 2.
     """
     h, w = input_hw
-    if padding == "SAME":
+    if not isinstance(padding, str):
+        (pt, pb), (pl_, pr) = padding
+        pads = ((int(pt), int(pb)), (int(pl_), int(pr)))
+        if min(pt, pb, pl_, pr) < 0:
+            raise ValueError(f"pads must be >= 0, got {padding!r}")
+        out_h = (h + pt + pb - kh) // stride + 1
+        out_w = (w + pl_ + pr - kw) // stride + 1
+    elif padding == "SAME":
         out_h = -(-h // stride)
         out_w = -(-w // stride)
         pad_h = max((out_h - 1) * stride + kh - h, 0)
@@ -88,7 +108,8 @@ def conv_geometry(input_hw: tuple[int, int], kh: int, kw: int, stride: int,
         out_w = (w - kw) // stride + 1
         pads = ((0, 0), (0, 0))
     else:
-        raise ValueError(f"padding must be SAME or VALID, got {padding!r}")
+        raise ValueError(f"padding must be SAME, VALID or explicit "
+                         f"((top, bottom), (left, right)), got {padding!r}")
     if out_h <= 0 or out_w <= 0:
         raise ValueError(
             f"conv output would be empty: input {input_hw}, kernel "
@@ -97,7 +118,7 @@ def conv_geometry(input_hw: tuple[int, int], kh: int, kw: int, stride: int,
 
 
 def make_conv_plan(w: jax.Array, *, input_hw: tuple[int, int],
-                   stride: int = 1, padding: str = "SAME") -> dict:
+                   stride: int = 1, padding="SAME") -> dict:
     """Pack conv weights per-tap along channels (C3) and precompute the
 
     zero-padding correction matrix (C5) for the layer's input size.
@@ -159,6 +180,21 @@ def make_bitplane_conv_plan(w: jax.Array, *, input_hw: tuple[int, int],
     plan["rowsum"] = wsign.sum(axis=(1, 2, 3)).astype(jnp.int32)
     del plan["correction"]
     plan["nbits"] = nbits
+    return plan
+
+
+def make_residual_conv_plan(w: jax.Array, *, input_hw: tuple[int, int],
+                            stride: int) -> dict:
+    """Conv plan for :func:`binary_conv2d_residual_packed`, the residual
+    binary conv of ReActNet: PyTorch's symmetric ``padding=kh//2`` at
+    either stride, and the packed weights stored tap- and word-major,
+    ``w_taps`` (KH·KW, Cw, C_out), so the kernel's rolled tap loop reads
+    one tap's words as sublane rows of one block."""
+    c_out, kh, kw, _ = w.shape
+    plan = make_conv_plan(w, input_hw=input_hw, stride=stride,
+                          padding=((kh // 2, kh // 2), (kw // 2, kw // 2)))
+    plan["w_taps"] = plan.pop("w_packed").reshape(
+        c_out, kh * kw, plan["cw"]).transpose(1, 2, 0)
     return plan
 
 
@@ -310,6 +346,66 @@ def _bitplane_conv_kernel(x_ref, w_ref, rowsum_ref, o_ref, *, kh, kw, stride,
     full = jnp.int32((1 << nbits) - 1)
     o_ref[0] = (full * (jnp.int32(k_true) + rowsum_ref[...])
                 - 2 * wacc) >> 1
+
+
+def _rolled_tap_mismatch(x_ref, w_ref, *, kh, kw, stride, block_oh, ow,
+                         cw) -> jax.Array:
+    """XNOR-popcount mismatch count of one M tile, over a rolled loop of
+    the KH·KW taps.
+
+    Each step reads its tap straight from the tile's padded input row
+    band (``x_ref``: (1, (block_oh−1)·stride + KH, Wp, Cw)), strided at
+    stride 2, and adds one (block_oh·ow, bn) popcount-of-XOR per packed
+    word against the tap's weight rows (``w_ref``: (KH·KW, Cw, bn)).
+    Only the Cw words of one tap are unrolled, so compile time does not
+    grow with KH·KW, and no strided slice of a loaded value is needed
+    (Mosaic has none).
+    """
+    m = block_oh * ow
+    bn = w_ref.shape[-1]
+
+    def tap(t, acc):
+        di, dj = t // kw, t % kw
+        a = x_ref[0, pl.ds(di, block_oh, stride=stride),
+                  pl.ds(dj, ow, stride=stride), :].reshape(m, cw)
+        wt = w_ref[t]                                       # (Cw, bn)
+        for c in range(cw):
+            acc = acc + jax.lax.population_count(
+                a[:, c:c + 1] ^ wt[c:c + 1, :]).astype(jnp.int32)
+        return acc
+
+    return jax.lax.fori_loop(0, kh * kw, tap, jnp.zeros((m, bn), jnp.int32))
+
+
+def _shortcut_tile(sc_ref, *, stride, block_oh, ow) -> jax.Array:
+    """This M tile's float shortcut, (block_oh·ow, bn): the stream tile
+    itself at stride 1 (``sc_ref`` block (1, block_m, bn)), its 2x2
+    average at stride 2 (block (1, 2·block_oh, W, bn))."""
+    if stride == 1:
+        return sc_ref[0]
+
+    def part(r, c):
+        return sc_ref[0, pl.ds(r, block_oh, stride=2),
+                      pl.ds(c, ow, stride=2), :]
+
+    avg = avg_pool2x2(part(0, 0), part(0, 1), part(1, 0), part(1, 1))
+    return avg.reshape(block_oh * ow, avg.shape[-1])
+
+
+def _conv_residual_kernel(x_ref, w_ref, corr_ref, epi_ref, sc_ref, o_ref,
+                          p_ref, *, kh, kw, stride, block_oh, ow, cw,
+                          k_true):
+    """Residual binary conv: rolled-tap XNOR-popcount contraction, pad
+    correction, then :func:`fused_epilogue.residual_epilogue` against the
+    float shortcut.  Writes the float32 stream tile (``o_ref``) and the
+    packed sign of stream + the next conv's bias (``p_ref``)."""
+    mism = _rolled_tap_mismatch(x_ref, w_ref, kh=kh, kw=kw, stride=stride,
+                                block_oh=block_oh, ow=ow, cw=cw)
+    z = jnp.int32(k_true) - 2 * mism + corr_ref[...]
+    sc = _shortcut_tile(sc_ref, stride=stride, block_oh=block_oh, ow=ow)
+    y, u = residual_epilogue(z, epi_ref[...], sc)
+    o_ref[0] = y
+    p_ref[0, 0] = pack_lanes((u >= 0).astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -526,6 +622,87 @@ def bitplane_conv2d_packed(x_planes: jax.Array, w_packed: jax.Array,
         interpret=interpret,
     )(xp, w_p, rs)
     return out[:, :oh * ow, :c_out].reshape(bsz, oh, ow, c_out)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kh", "kw", "stride", "pads", "out_hw", "c_out", "k_true", "interpret"))
+def binary_conv2d_residual_packed(x_packed: jax.Array, w_taps: jax.Array,
+                                  correction: jax.Array, epi: jax.Array,
+                                  shortcut: jax.Array, *, kh: int, kw: int,
+                                  stride: int, pads, out_hw: tuple[int, int],
+                                  c_out: int, k_true: int,
+                                  interpret: bool = False
+                                  ) -> tuple[jax.Array, jax.Array]:
+    """Residual binary conv (ReActNet's 3x3 block half) in one launch.
+
+    ``x_packed``: (B, H, W, Cw) packed sign of the block input;
+    ``w_taps``: (KH·KW, Cw, C_out) (``make_residual_conv_plan``);
+    ``correction``: (OH, OW, C_out) int32 pad correction; ``epi``: (8,
+    C_out) float32 epilogue rows (``fused_epilogue.EPI_*``);
+    ``shortcut``: (B, H, W, C_out) float32 stream, 2x2-averaged in the
+    kernel at stride 2.  Returns the float32 stream (B, OH, OW, C_out)
+    and its packed sign plus the next bias, (B, OH, OW, ceil(C_out/32))
+    uint32.
+
+    Grid (B, M tiles of ``block_oh`` output rows, C_out blocks), blocks
+    from the shapes (``analysis.vmem.residual_conv_blocks``).  Each M
+    tile reads only its input row band, halo rows included (element
+    offsets): the packed words of a row sit on lanes, so a whole image
+    of 1-2 words a pixel would take 128 times its size in VMEM.  A C_out
+    of at most 128 lanes is one block as wide as the array, not padded
+    to 128.
+    """
+    bsz, h, w, cw = x_packed.shape
+    oh, ow = out_hw
+    block_oh, block_n = vmem.residual_conv_blocks(oh, ow, cw, c_out)
+    xp = jnp.pad(x_packed, ((0, 0), pads[0], pads[1], (0, 0)))
+    wp = xp.shape[2]
+    hblk = (block_oh - 1) * stride + kh
+    block_m = block_oh * ow
+    n_blocks = c_out // block_n
+    bnw = block_n // B.WORD_BITS
+    grid = (bsz, oh // block_oh, n_blocks)
+    if stride == 1:
+        sc = shortcut.reshape(bsz, oh * ow, c_out)
+        sc_spec = pl.BlockSpec((1, block_m, block_n),
+                               lambda b, m, j: (b, m, j))
+    else:
+        if stride != 2 or (h, w) != (2 * oh, 2 * ow):
+            raise ValueError(f"the residual conv takes stride 1, or stride 2 "
+                             f"on an even input; got stride {stride}, input "
+                             f"{(h, w)}, output {out_hw}")
+        sc = shortcut
+        sc_spec = pl.BlockSpec((1, 2 * block_oh, w, block_n),
+                               lambda b, m, j: (b, m, 0, j))
+
+    kernel = functools.partial(_conv_residual_kernel, kh=kh, kw=kw,
+                               stride=stride, block_oh=block_oh, ow=ow,
+                               cw=cw, k_true=k_true)
+    y, p = pl.pallas_call(
+        kernel,
+        name="_conv_residual_kernel",
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((pl.Element(1), pl.Element(hblk), pl.Element(wp),
+                          pl.Element(cw)),
+                         lambda b, m, j: (b, m * block_oh * stride, 0, 0)),
+            pl.BlockSpec((kh * kw, cw, block_n), lambda b, m, j: (0, 0, j)),
+            pl.BlockSpec((block_m, block_n), lambda b, m, j: (m, j)),
+            pl.BlockSpec((EPI_ROWS, block_n), lambda b, m, j: (0, j)),
+            sc_spec,
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_m, block_n), lambda b, m, j: (b, m, j)),
+            pl.BlockSpec((1, 1, block_m, bnw), lambda b, m, j: (b, j, m, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bsz, oh * ow, c_out), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, n_blocks, oh * ow, bnw), jnp.uint32),
+        ],
+        interpret=interpret,
+    )(xp, w_taps, correction.reshape(oh * ow, c_out), epi, sc)
+    return (y.reshape(bsz, oh, ow, c_out),
+            unblock_packed(p).reshape(bsz, oh, ow, n_blocks * bnw))
 
 
 def _ceil_mult(x: int, m: int) -> int:

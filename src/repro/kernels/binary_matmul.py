@@ -32,6 +32,10 @@ uint32 operands:
   the grid becomes N-major 1-D: the packed activation block is pinned
   resident in VMEM, weight row blocks stream past it, and the
   contraction completes per program (no cross-step accumulator).
+* **Residual pointwise conv** (:func:`pointwise_residual_packed`) — a
+  1x1 binary conv over the B·H·W pixel rows whose epilogue adds a float
+  shortcut and writes both the float stream and the packed sign
+  (ReActNet; ``fused_epilogue.residual_epilogue``).
 * **Single-launch bit-plane first layer** (:func:`bitplane_dense_packed`)
   — every (image, plane) pair of the fixed-precision input is one
   contraction row against word-major weights packed once at load, so
@@ -54,11 +58,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis import vmem
 from repro.core import binarize as B
-from repro.kernels.fused_epilogue import (bn_sign_bits_to_words,
+from repro.kernels.fused_epilogue import (EPI_ROWS, bn_sign_bits_to_words,
                                           check_block_lanes, unblock_packed,
                                           check_block_sublanes,
-                                          check_words_per_step,
-                                          pad_bn_params)
+                                          check_words_per_step, pack_lanes,
+                                          pad_bn_params, residual_epilogue)
 
 # Minimum int32 tile granularity on TPU: (8 sublanes, 128 lanes).
 _SUBLANE = 8
@@ -622,6 +626,99 @@ def bitplane_dense_packed(x_planes: jax.Array, w_words: jax.Array,
         interpret=interpret,
     )(x_t, w_p, rs)
     return out[:m, :n]
+
+
+# ---------------------------------------------------------------------------
+# Residual pointwise (1x1) binary conv
+# ---------------------------------------------------------------------------
+
+def _pointwise_residual_kernel(a_ref, w_ref, epi_ref, sc_ref, o_ref, p_ref,
+                               *, k_true: int, repeat: int):
+    """One (rows, bn) tile of a residual 1x1 binary conv.
+
+    ``a_ref``: (bm, Kw) packed rows (one per pixel); ``w_ref``: (Kw, bn)
+    word-major weights, so each word's weights are one sublane row; the
+    Kw ≤ 32 words are unrolled, each one (bm, bn) popcount-of-XOR.  The
+    float shortcut tile is ``repeat`` times narrower than the output
+    where the width doubles (two convs over one input, each adding the
+    same shortcut), and is tiled along lanes to match.  The epilogue is
+    :func:`fused_epilogue.residual_epilogue`; writes the float32 stream
+    (``o_ref``) and the packed sign of stream + the next bias
+    (``p_ref``).
+    """
+    a = a_ref[...]
+    w = w_ref[...]
+    acc = jnp.zeros((a.shape[0], w.shape[1]), jnp.int32)
+    for c in range(w.shape[0]):
+        acc = acc + jax.lax.population_count(
+            a[:, c:c + 1] ^ w[c:c + 1, :]).astype(jnp.int32)
+    sc = sc_ref[...]
+    if repeat > 1:
+        sc = jnp.concatenate([sc] * repeat, axis=1)
+    y, u = residual_epilogue(jnp.int32(k_true) - 2 * acc, epi_ref[...], sc)
+    o_ref[...] = y
+    p_ref[0] = pack_lanes((u >= 0).astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("k_true", "interpret"))
+def pointwise_residual_packed(x_packed: jax.Array, w_words: jax.Array,
+                              epi: jax.Array, shortcut: jax.Array, *,
+                              k_true: int, interpret: bool = False
+                              ) -> tuple[jax.Array, jax.Array]:
+    """Residual 1x1 binary conv (ReActNet's second block half) as one
+    GEMM launch over the B·H·W pixel rows.
+
+    ``x_packed``: (M, Kw) packed sign rows; ``w_words``: (Kw, N)
+    word-major packed weights, the two C -> C convs of a doubling block
+    stacked along N = 2C; ``epi``: (8, N) float32 epilogue rows
+    (``fused_epilogue.EPI_*``); ``shortcut``: (M, C) float32 stream,
+    added to every C-channel group of the output.  Returns the float32
+    stream (M, N) and its packed sign plus the next bias, (M,
+    ceil(N/32)) uint32.  Grid (row tiles, N blocks), blocks from the
+    shapes (``analysis.vmem.pointwise_residual_blocks``); no lane
+    padding of the K words or of N up to 128.
+    """
+    m, kw = x_packed.shape
+    kw_w, n = w_words.shape
+    c = shortcut.shape[1]
+    assert kw == kw_w and n % c == 0, (x_packed.shape, w_words.shape,
+                                       shortcut.shape)
+    bm, bn = vmem.pointwise_residual_blocks(m, kw, n)
+    a_p = B.pad_to_multiple(x_packed, bm, 0)
+    sc = B.pad_to_multiple(shortcut, bm, 0)
+    mp = a_p.shape[0]
+    n_blocks = n // bn
+    bnw = bn // B.WORD_BITS
+    if bn <= c:
+        sc_spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j % (c // bn)))
+        repeat = 1
+    else:
+        sc_spec = pl.BlockSpec((bm, c), lambda i, j: (i, 0))
+        repeat = bn // c
+
+    kernel = functools.partial(_pointwise_residual_kernel, k_true=k_true,
+                               repeat=repeat)
+    y, p = pl.pallas_call(
+        kernel,
+        name="_pointwise_residual_kernel",
+        grid=(mp // bm, n_blocks),
+        in_specs=[
+            pl.BlockSpec((bm, kw), lambda i, j: (i, 0)),
+            pl.BlockSpec((kw, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((EPI_ROWS, bn), lambda i, j: (0, j)),
+            sc_spec,
+        ],
+        out_specs=[
+            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+            pl.BlockSpec((1, bm, bnw), lambda i, j: (j, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((mp, n), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, mp, bnw), jnp.uint32),
+        ],
+        interpret=interpret,
+    )(a_p, w_words, epi, sc)
+    return y[:m], unblock_packed(p)[:m]
 
 
 def _ceil_mult(x: int, m: int) -> int:

@@ -12,6 +12,11 @@ Used standalone after layers whose producer can't fuse the epilogue
 itself (the bit-plane first layer, whose int32 output accumulates over
 8 plane convs, and the dense stack); the binary-conv kernel inlines the
 same epilogue directly (``binary_conv.binary_conv2d_bn_sign_packed``).
+
+Residual networks (ReActNet) keep a float32 stream beside the packed
+sign: :func:`residual_epilogue` is the float math both residual kernels
+(``binary_conv._conv_residual_kernel``,
+``binary_matmul._pointwise_residual_kernel``) and their oracles run.
 """
 from __future__ import annotations
 
@@ -102,6 +107,46 @@ def bn_sign_bits_to_words(y: jax.Array, tau: jax.Array,
     """
     ge = y.astype(jnp.float32) >= tau
     return pack_lanes((ge == (flip > 0)).astype(jnp.int32))
+
+
+# Rows of the per-channel float32 table a residual epilogue reads
+# ((8, C): one sublane group, the last two rows unused).
+EPI_SCALE, EPI_SHIFT, EPI_BIAS, EPI_SLOPE, EPI_BIAS2, EPI_NEXT = range(6)
+EPI_ROWS = 8
+
+
+def residual_epilogue(z: jax.Array, epi: jax.Array,
+                      shortcut: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The epilogue of a residual binary conv (ReActNet), shared by the
+    kernels and their oracles so both do the same float32 operations in
+    the same order, each rounding once:
+
+        y  = z·scale + shift          (BN at inference, α folded in scale)
+        y  = y + shortcut
+        y  = y + bias                 (RPReLU: bias, PReLU, bias)
+        y  = y if y > 0 else y·slope
+        y  = y + bias2                -> the float stream
+        u  = y + next                 -> its sign feeds the next binary conv
+
+    ``z``: (..., C) int32 exact conv output; ``epi``: (8, C) rows
+    ``EPI_*``; ``shortcut``: (..., C) float32.  Returns (y, u).
+    """
+    scale, shift, bias, slope, bias2, nxt = (
+        epi[i:i + 1] for i in (EPI_SCALE, EPI_SHIFT, EPI_BIAS, EPI_SLOPE,
+                               EPI_BIAS2, EPI_NEXT))
+    y = z.astype(jnp.float32) * scale + shift
+    y = y + shortcut
+    y = y + bias
+    y = jnp.where(y > 0, y, y * slope)
+    y = y + bias2
+    return y, y + nxt
+
+
+def avg_pool2x2(x00: jax.Array, x01: jax.Array, x10: jax.Array,
+                x11: jax.Array) -> jax.Array:
+    """The stride-2 shortcut's 2x2 average in one fixed order
+    (``xrc``: row r, column c of the window)."""
+    return ((x00 + x01) + (x10 + x11)) * jnp.float32(0.25)
 
 
 def unblock_packed(out: jax.Array) -> jax.Array:

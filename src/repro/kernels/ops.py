@@ -508,6 +508,65 @@ def bn_sign_pack(x: jax.Array, tau: jax.Array, flip: jax.Array, *,
     return _ref.bn_sign_pack_ref(x, tau, flip)
 
 
+def binary_conv2d_residual(plan: dict, epi: jax.Array, x_packed: jax.Array,
+                           shortcut: jax.Array, *, backend: str = "auto"
+                           ) -> tuple[jax.Array, jax.Array]:
+    """Residual binary conv (ReActNet) on a ``make_residual_conv_plan``
+    plan: the packed conv of ``x_packed`` (B, H, W, Cw), its pad
+    correction, and ``fused_epilogue.residual_epilogue`` against the
+    float ``shortcut`` (B, H, W, C_out), 2x2-averaged at stride 2, with
+    the (8, C_out) rows ``epi``.  Returns the float32 stream
+    (B, OH, OW, C_out) and its packed sign plus the next bias, uint32.
+
+    backend: 'pallas' (``binary_conv._conv_residual_kernel``, one launch)
+    | 'jnp'/'ref' (the oracle) | 'auto'; unknown strings raise
+    ``ValueError``.  Blocks come from the shapes.
+    """
+    backend = _resolve(backend)
+    statics = dict(kh=plan["kh"], kw=plan["kw"], stride=plan["stride"],
+                   pads=plan["pads"], c_out=plan["c_out"],
+                   k_true=plan["k_true"])
+    if backend == "pallas":
+        _vmem.preflight(_vmem.residual_conv_estimate(
+            x_packed.shape[0], plan["in_hw"], plan["cw"], plan["kh"],
+            plan["kw"], plan["c_out"], plan["out_hw"],
+            stride=plan["stride"]))
+        return _bconv.binary_conv2d_residual_packed(
+            x_packed, plan["w_taps"], plan["correction"], epi, shortcut,
+            out_hw=plan["out_hw"], interpret=not _on_tpu(), **statics)
+    return _ref.binary_conv2d_residual_ref(
+        x_packed, plan["w_taps"], plan["correction"], epi, shortcut,
+        **statics)
+
+
+def pointwise_residual(w_words: jax.Array, epi: jax.Array,
+                       x_packed: jax.Array, shortcut: jax.Array, *,
+                       k_true: int, backend: str = "auto"
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Residual 1x1 binary conv (ReActNet) over pixel rows:
+    ``x_packed`` (M, Kw) against word-major ``w_words`` (Kw, N), then
+    ``fused_epilogue.residual_epilogue`` with the (8, N) rows ``epi``
+    against the float ``shortcut`` (M, C), added to each C-channel group
+    of the N outputs (N = 2C where two convs share the input).  Returns
+    the float32 stream (M, N) and its packed sign plus the next bias,
+    uint32.
+
+    backend: 'pallas' (``binary_matmul._pointwise_residual_kernel``, one
+    launch) | 'jnp'/'ref' (the oracle) | 'auto'; unknown strings raise
+    ``ValueError``.  Blocks come from the shapes.
+    """
+    backend = _resolve(backend)
+    if backend == "pallas":
+        _vmem.preflight(_vmem.pointwise_residual_estimate(
+            x_packed.shape[0], x_packed.shape[1], w_words.shape[1],
+            shortcut.shape[1]))
+        return _bmm.pointwise_residual_packed(
+            x_packed, w_words, epi, shortcut, k_true=k_true,
+            interpret=not _on_tpu())
+    return _ref.pointwise_residual_ref(x_packed, w_words, epi, shortcut,
+                                       k_true=k_true)
+
+
 def binary_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1,
                   padding: str = "SAME", backend: str = "auto",
                   block_oh: int | None = None,

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import binarize as B
+from repro.kernels.fused_epilogue import avg_pool2x2, residual_epilogue
 
 
 def binary_matmul_ref(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -176,6 +177,39 @@ def binary_conv2d_bn_sign_packed_ref(x_packed: jax.Array,
                                  kw=kw, stride=stride, pads=pads,
                                  c_out=c_out, k_true=k_true)
     return bn_sign_pack_ref(y, tau, flip)
+
+
+def binary_conv2d_residual_ref(x_packed: jax.Array, w_taps: jax.Array,
+                               correction: jax.Array, epi: jax.Array,
+                               shortcut: jax.Array, *, kh: int, kw: int,
+                               stride: int, pads, c_out: int,
+                               k_true: int) -> tuple[jax.Array, jax.Array]:
+    """Reference residual conv: im2col packed conv + correction, the
+    stride-2 shortcut's 2x2 average, then the shared residual epilogue;
+    returns (float32 stream, packed sign of stream + next bias)."""
+    w_packed = w_taps.transpose(2, 0, 1).reshape(c_out, -1)
+    z = binary_conv2d_packed_ref(x_packed, w_packed, correction, kh=kh,
+                                 kw=kw, stride=stride, pads=pads,
+                                 c_out=c_out, k_true=k_true)
+    if stride == 2:
+        s = shortcut
+        shortcut = avg_pool2x2(s[:, 0::2, 0::2], s[:, 0::2, 1::2],
+                               s[:, 1::2, 0::2], s[:, 1::2, 1::2])
+    y, u = residual_epilogue(z, epi, shortcut)
+    return y, B.pack_bits(u)
+
+
+def pointwise_residual_ref(x_packed: jax.Array, w_words: jax.Array,
+                           epi: jax.Array, shortcut: jax.Array, *,
+                           k_true: int) -> tuple[jax.Array, jax.Array]:
+    """Reference residual 1x1 conv over pixel rows: packed GEMM against
+    the word-major weights, the shortcut added to every C-channel group
+    of the output, the shared residual epilogue; returns (float32
+    stream, packed sign of stream + next bias)."""
+    z = B.packed_matmul(x_packed, w_words.T, k_true)
+    sc = jnp.tile(shortcut, (1, w_words.shape[1] // shortcut.shape[1]))
+    y, u = residual_epilogue(z, epi, sc)
+    return y, B.pack_bits(u)
 
 
 def binary_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,

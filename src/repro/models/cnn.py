@@ -14,6 +14,10 @@ Each network has:
 
 forward_packed == forward_float exactly on the integer dots, and to fp
 round-off on the final BN logits (tests/test_paper_equivalence.py).
+
+ReActNet-A lives in ``models/reactnet.py``; its spec is re-exported here
+and the serving seams below cover it, so every image network the server
+takes is reached through this module.
 """
 from __future__ import annotations
 
@@ -26,6 +30,8 @@ from repro import telemetry
 from repro.core import binarize as B
 from repro.core import binary_layers as L
 from repro.kernels import ops as kops
+from repro.models import reactnet as R
+from repro.models.reactnet import ReActNetSpec  # noqa: F401  (spec by name)
 
 
 # ---------------------------------------------------------------------------
@@ -373,18 +379,22 @@ def bcnn_forward_packed(packed: dict, x_uint8: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
-# Serving seams (train/serve.py): one uniform view over both networks
+# Serving seams (train/serve.py): one uniform view over every network
 # ---------------------------------------------------------------------------
 
 def packed_kind(packed: dict) -> str:
-    """'bcnn' | 'bmlp' | 'transformer' from the shape of a ``pack_*`` tree.
+    """'reactnet' | 'bcnn' | 'transformer' | 'bmlp' from the shape of a
+    ``pack_*`` tree.
 
     The serving layer and the sharding rules both dispatch on this, so
     the check lives once, next to the pack functions whose layout it
-    reads ('transformer' trees come from
-    ``models.transformer.pack_transformer`` and carry a ``blocks`` list).
-    Raises ``ValueError`` for anything else.
+    reads ('reactnet' trees come from ``models.reactnet.pack_reactnet``
+    and carry ``res_blocks``; 'transformer' trees from
+    ``models.transformer.pack_transformer`` and carry a ``blocks``
+    list).  Raises ``ValueError`` for anything else.
     """
+    if "res_blocks" in packed:
+        return "reactnet"
     if "convs" in packed:
         return "bcnn"
     if "blocks" in packed:
@@ -392,22 +402,22 @@ def packed_kind(packed: dict) -> str:
     if "layers" in packed:
         return "bmlp"
     raise ValueError(
-        f"not a pack_bcnn/pack_bmlp/pack_transformer tree: "
+        f"not a pack_reactnet/pack_bcnn/pack_bmlp/pack_transformer tree: "
         f"keys {sorted(packed)}")
 
 
 def packed_input_shape(packed: dict) -> tuple[int, ...]:
     """Per-example input shape (no batch axis) a packed forward consumes.
 
-    bcnn: ``(H, W, C_in)`` raw uint8; bmlp: ``(K,)`` raw uint8;
+    bcnn, reactnet: ``(H, W, C_in)`` raw uint8; bmlp: ``(K,)`` raw uint8;
     transformer: ``(S,)`` uint8 token ids (reduced registry configs have
     vocab ≤ 256) — every workload takes fixed-precision input, so the
     serving scratch pool can stage requests without knowing which
     network is behind the queue.
     """
     kind = packed_kind(packed)
-    if kind == "bcnn":
-        spec: BCNNSpec = packed["spec"]
+    if kind in ("bcnn", "reactnet"):
+        spec = packed["spec"]
         return (*spec.input_hw, spec.c_in)
     if kind == "transformer":
         return (int(packed["meta"]["seq_len"]),)
@@ -423,6 +433,11 @@ def packed_dense_kw_words(packed: dict) -> int:
     the route for the whole forward.
     """
     kind = packed_kind(packed)
+    if kind == "reactnet":
+        # Its 1x1 convs (word-major).  They contract every pixel row of
+        # the bucket in one grid whatever the route says: the route
+        # label follows the bucket alone there.
+        return max(int(b["w_pw"].shape[0]) for b in packed["res_blocks"])
     if kind == "transformer":
         mats = [blk[w] for blk in packed["blocks"]
                 for w in ("wq", "wk", "wv", "wo", "w1", "w2")]
@@ -453,14 +468,22 @@ def demo_model(kind: str, *, smoke: bool = False, seed: int = 0):
         spec = BMLPSpec(sizes=(784, 256, 256, 10) if smoke
                         else (784, 1024, 1024, 10))
         return init_bmlp(key, spec), spec, "bmlp"
-    raise ValueError(f"kind must be 'bcnn' or 'bmlp', got {kind!r}")
+    if kind == "reactnet":
+        # A same-width block, the stride-1 32 -> 64 doubling block and a
+        # stride-2 doubling block.
+        spec = ReActNetSpec(input_hw=(16, 16) if smoke else (32, 32),
+                            stage_out_channel=(32, 32, 64, 128),
+                            num_classes=10)
+        return R.init_reactnet(key, spec), spec, "reactnet"
+    raise ValueError(
+        f"kind must be 'bcnn', 'bmlp' or 'reactnet', got {kind!r}")
 
 
 def make_packed_forward(packed: dict, *, backend: str = "auto",
                         dense_stack: str = "auto"):
     """Jitted single-device forward ``fwd(x_uint8) -> logits``.
 
-    Works for either packed network — the serving layer's default
+    Works for every packed network — the serving layer's default
     engine, and the same call signature as
     ``distributed.sharding.make_sharded_forward`` so a device mesh can
     sit behind the request queue as a drop-in.  ``backend`` /
@@ -468,7 +491,12 @@ def make_packed_forward(packed: dict, *, backend: str = "auto",
     values raise at first call).
     """
     kind = packed_kind(packed)
-    if kind == "bcnn":
+    if kind == "reactnet":              # no dense stack to route
+        _check_dense_stack(dense_stack)
+
+        def fwd(x):
+            return R.reactnet_forward_packed(packed, x, backend=backend)
+    elif kind == "bcnn":
         def fwd(x):
             return bcnn_forward_packed(packed, x, backend=backend,
                                        dense_stack=dense_stack)

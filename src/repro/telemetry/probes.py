@@ -127,8 +127,9 @@ def _demo_packed(kind: str):
 
 
 def standard_report(*, sharded: bool = True) -> dict:
-    """The committed probe cells: both demo networks and the reduced
-    gemma2 binary LM at the GEMV (≤ 8) and GEMM (> 8) serving batches,
+    """The committed probe cells: the BMLP and BCNN demo networks and the
+    reduced gemma2 binary LM at the GEMV (≤ 8) and GEMM (> 8) serving
+    batches, the ReActNet demo network at batch 8,
     plus the (4, 2)-mesh collective cells (bmlp/bcnn only — the
     sharding rules don't cover the transformer workload).  Keys are
     stable — they ARE the baseline diff surface."""
@@ -140,6 +141,8 @@ def standard_report(*, sharded: bool = True) -> dict:
         if sharded and kind != "transformer":
             cells[f"sharded/{kind}_{SHARDED_MESH[0]}x{SHARDED_MESH[1]}"] = \
                 probe_sharded(packed, batch=8)
+    # ReActNet has no GEMV/GEMM route to switch: one batch covers it.
+    cells["reactnet/b8"] = probe_forward(_demo_packed("reactnet"), 8)
     return {"schema": 1, "cells": cells}
 
 

@@ -463,7 +463,7 @@ class PackedInferenceServer:
         server is idle.
 
         Either pass float ``params`` + ``spec`` (+ ``kind`` 'bcnn' |
-        'bmlp' | 'transformer'; for 'transformer' ``spec`` is the
+        'bmlp' | 'reactnet' | 'transformer'; for 'transformer' ``spec`` is the
         ``ArchConfig`` and ``params`` come from
         ``models.transformer.init_binary_lm``) — the weight cache packs
         + folds ONCE per key — or a pre-``pack_*`` tree via ``packed=``.  Re-registering a known key
@@ -488,22 +488,23 @@ class PackedInferenceServer:
         if packed is not None:
             packed_tree = self.cache.get_or_pack(key, lambda: packed)
         else:
-            if kind not in ("bcnn", "bmlp", "transformer"):
+            if kind not in ("bcnn", "bmlp", "reactnet", "transformer"):
                 raise ValueError(
-                    f"kind must be 'bcnn', 'bmlp', or 'transformer', "
-                    f"got {kind!r}")
+                    f"kind must be 'bcnn', 'bmlp', 'reactnet' or "
+                    f"'transformer', got {kind!r}")
             if kind == "transformer":
                 from repro.models import transformer as TF
                 pack = TF.pack_transformer
             else:
-                pack = C.pack_bcnn if kind == "bcnn" else C.pack_bmlp
+                pack = {"bcnn": C.pack_bcnn, "bmlp": C.pack_bmlp,
+                        "reactnet": C.R.pack_reactnet}[kind]
             packed_tree = self.cache.get_or_pack(
                 key, lambda: pack(params, spec))
         kind = C.packed_kind(packed_tree)
-        if kind == "transformer" and mesh is not None:
+        if kind in ("transformer", "reactnet") and mesh is not None:
             raise ValueError(
-                "mesh serving is not supported for the transformer "
-                "workload (the sharding rules cover bcnn/bmlp)")
+                f"mesh serving is not supported for the {kind} "
+                f"workload (the sharding rules cover bcnn/bmlp)")
         if mesh is not None:
             from repro.distributed.sharding import make_sharded_forward
             fwd = make_sharded_forward(packed_tree, mesh, backend=backend,

@@ -5,8 +5,9 @@ CPU host, so a kernel the chip would refuse fails this file instead of a
 chip run.  Nothing executes; results are covered by the interpret-mode
 tests.
 
-Widths are the paper's networks: the BMLP 784-4096x3-10 and the
-CIFAR-10 BCNN (32x32x3 input, 128/256/512-channel conv stages, FC 1024).
+Widths are the published networks: the BMLP 784-4096x3-10, the
+CIFAR-10 BCNN (32x32x3 input, 128/256/512-channel conv stages, FC 1024)
+and the blocks of ReActNet-A (112x112 to 7x7, 32 to 1024 channels).
 The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and every test worker imports
 this file.
@@ -160,4 +161,48 @@ def test_binary_attention_compiles(one_chip, request):
     compiled, seconds = _compile(
         fn, one_chip, ((b, s, h, dw), U32), ((b, s, h, dw), U32),
         ((b, s, h, d), F32))
+    _assert_kernel(compiled, seconds, request)
+
+
+# ReActNet-A blocks at published widths: (input size, C_in, stride) of
+# the 3x3 residual conv; (rows, C, N) of the residual 1x1 conv.
+REACTNET_CONVS = {
+    "b1_112_c32_s1": (112, 32, 1),
+    "b2_112_c64_s2": (112, 64, 2),
+    "b7_14_c512_s1": (14, 512, 1),
+    "b12_14_c512_s2": (14, 512, 2),
+    "b13_7_c1024_s1": (7, 1024, 1),
+}
+REACTNET_POINTWISE = {
+    "b1_112_32x64": (2 * 112 * 112, 32, 64),
+    "b12_7_512x1024": (2 * 7 * 7, 512, 1024),
+    "b13_7_1024x1024": (2 * 7 * 7, 1024, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REACTNET_CONVS))
+def test_reactnet_conv_residual_compiles(case, one_chip, request):
+    hw, c, stride = REACTNET_CONVS[case]
+    oh, cw = hw // stride, B.packed_width(c)
+
+    def fn(x, w, corr, epi, sc):
+        return bconv.binary_conv2d_residual_packed(
+            x, w, corr, epi, sc, kh=3, kw=3, stride=stride,
+            pads=((1, 1), (1, 1)), out_hw=(oh, oh), c_out=c, k_true=9 * c)
+    compiled, seconds = _compile(
+        fn, one_chip, ((2, hw, hw, cw), U32), ((9, cw, c), U32),
+        ((oh, oh, c), I32), ((8, c), F32), ((2, hw, hw, c), F32))
+    _assert_kernel(compiled, seconds, request)
+
+
+@pytest.mark.parametrize("case", sorted(REACTNET_POINTWISE))
+def test_reactnet_pointwise_residual_compiles(case, one_chip, request):
+    m, c, n = REACTNET_POINTWISE[case]
+    kw = B.packed_width(c)
+
+    def fn(x, w, epi, sc):
+        return bmm.pointwise_residual_packed(x, w, epi, sc, k_true=c)
+    compiled, seconds = _compile(
+        fn, one_chip, ((m, kw), U32), ((kw, n), U32), ((8, n), F32),
+        ((m, c), F32))
     _assert_kernel(compiled, seconds, request)
