@@ -347,9 +347,24 @@ def conv_estimate(batch: int, padded_hw: tuple[int, int], cw: int,
 
 # Residual kernels' tile budget: output pixels (rows) per tile, and
 # pixels x packed words a tile contracts, which bounds the unrolled word
-# loop's vector ops (and so the kernel's compile time).
+# loop of the pointwise kernel and of the conv's VPU route (and so their
+# compile time).
 RESIDUAL_TILE_M = 1024
 RESIDUAL_TILE_WORDS = 4096
+
+# Pixels of one MXU-route residual conv tile: a few hundred rows a dot
+# (several whole images where one image is fewer), halved where the
+# launch would overrun VMEM (``residual_conv_blocks``).
+RESIDUAL_CONV_TILE_M = 512
+
+
+def residual_conv_route(cw: int, c_out: int) -> str:
+    """The residual conv's contraction: 'mxu' (±1 bf16 operands unpacked
+    in VMEM, one dot a tap) where C_in and C_out each fill a 128-lane
+    group, else 'vpu' (XOR-popcount).  On a v5e ReActNet-A's 112² blocks
+    (C 32 and 64) ran faster on the VPU and every wider block on the MXU
+    (the route table in PERF.md, section 6)."""
+    return "mxu" if min(cw * WORD_BITS, c_out) >= LANE else "vpu"
 
 
 def residual_block_n(c_out: int) -> int:
@@ -366,18 +381,41 @@ def _tile_cap(cw: int) -> int:
     return max(SUBLANE, min(RESIDUAL_TILE_M, RESIDUAL_TILE_WORDS // cw))
 
 
-def residual_conv_blocks(oh: int, ow: int, cw: int,
-                         c_out: int) -> tuple[int, int]:
-    """(output rows per M tile, C_out block) of
-    ``kernels.binary_conv.binary_conv2d_residual_packed``.  The row band
-    is the largest divisor of OH whose tile stays within the residual
-    tile budget (at least one row) and is a whole number of sublane
-    groups, or the whole image."""
-    cap = _tile_cap(cw)
+def _conv_tile(batch: int, oh: int, ow: int, cap: int) -> tuple[int, int]:
+    """(images, output rows) of a conv M tile of at most ``cap`` pixels:
+    whole images, as many as divide the batch, where one image fits;
+    else the largest divisor of OH whose band is a whole number of
+    sublane groups (at least one row), or the whole image."""
+    if oh * ow <= cap:
+        return max(d for d in range(1, batch + 1)
+                   if batch % d == 0 and d * oh * ow <= cap), oh
     fits = [d for d in range(1, oh + 1) if oh % d == 0 and
             (d == oh or (d * ow) % SUBLANE == 0)]
     within = [d for d in fits if d * ow <= cap]
-    return (max(within) if within else min(fits)), residual_block_n(c_out)
+    return 1, (max(within) if within else min(fits))
+
+
+def residual_conv_blocks(batch: int, oh: int, ow: int, cw: int, c_out: int,
+                         *, stride: int = 1, kh: int = 3,
+                         kw: int = 3) -> tuple[int, int, int]:
+    """(images per M tile, output rows per M tile, C_out block) of
+    ``kernels.binary_conv.binary_conv2d_residual_packed``: on the MXU
+    route the largest tile within ``RESIDUAL_CONV_TILE_M`` pixels,
+    halved while the launch estimate overruns the VMEM budget (7x7x1024
+    at 256 images: 4 images, not 8); on the VPU route within the
+    residual tile budget."""
+    bn = residual_block_n(c_out)
+    if residual_conv_route(cw, c_out) == "vpu":
+        return (*_conv_tile(batch, oh, ow, _tile_cap(cw)), bn)
+    cap = RESIDUAL_CONV_TILE_M
+    while True:
+        nb, block_oh = _conv_tile(batch, oh, ow, cap)
+        terms = _residual_conv_terms(nb, block_oh, ow, cw, bn, route="mxu",
+                                     stride=stride, kh=kh, kw=kw,
+                                     in_w=ow * stride)
+        if cap <= SUBLANE or sum(t.total for t in terms) <= vmem_budget():
+            return nb, block_oh, bn
+        cap //= 2
 
 
 def pointwise_residual_blocks(m: int, kw: int,
@@ -399,22 +437,17 @@ def _lanes(c: int) -> int:
     return _ceil_mult(c, LANE)
 
 
-def residual_conv_estimate(batch: int, in_hw: tuple[int, int], cw: int,
-                           kh: int, kw: int, c_out: int,
-                           out_hw: tuple[int, int], *,
-                           stride: int) -> LaunchEstimate:
-    """Estimate the residual conv launch: the tile's padded packed input
-    row band, the tap-major weight block, the correction, epilogue rows,
-    shortcut and both output tiles (all double-buffered, lanes padded to
-    128), plus the accumulator and float epilogue transients."""
-    h, w = in_hw
-    oh, ow = out_hw
-    block_oh, bn = residual_conv_blocks(oh, ow, cw, c_out)
-    block_m = block_oh * ow
-    hblk, wp = (block_oh - 1) * stride + kh, w + kw - 1
-    sc_rows = block_m if stride == 1 else 2 * block_oh * w
-    terms = (
-        VmemTerm("row_band_block", hblk * _ceil_mult(wp, SUBLANE)
+def _residual_conv_terms(nb: int, block_oh: int, ow: int, cw: int, bn: int,
+                         *, route: str, stride: int, kh: int, kw: int,
+                         in_w: int) -> tuple[VmemTerm, ...]:
+    block_m = nb * block_oh * ow
+    c_in = cw * WORD_BITS
+    hblk, wp = (block_oh - 1) * stride + kh, in_w + kw - 1
+    hh, wh = -(-hblk // stride), -(-wp // stride)
+    sc_rows = block_m if stride == 1 else nb * 2 * block_oh * in_w
+    bf16_rows = 16      # sublanes of a bf16 tile
+    terms = [
+        VmemTerm("row_band_block", nb * hblk * _ceil_mult(wp, SUBLANE)
                  * _lanes(cw) * 4, 2),
         VmemTerm("weight_block", kh * kw * _ceil_mult(cw, SUBLANE)
                  * _lanes(bn) * 4, 2),
@@ -425,10 +458,48 @@ def residual_conv_estimate(batch: int, in_hw: tuple[int, int], cw: int,
         VmemTerm("packed_block", block_m * LANE * 4, 2),
         VmemTerm("acc_tile", block_m * _lanes(bn) * 4),
         VmemTerm("epilogue_tiles", 2 * block_m * _lanes(bn) * 4),
-    )
-    return LaunchEstimate(kernel="conv_residual",
-                          grid=(batch, oh // block_oh, c_out // bn),
-                          terms=terms)
+    ]
+    if route == "mxu":
+        terms += [
+            VmemTerm("byte_spread_block", _ceil_mult(4 * cw, bf16_rows)
+                     * _lanes(c_in) * 2),
+            # ±1 bf16 operands, unpacked in VMEM: the row band by phase,
+            # and every tap of the C_out block's weights.
+            VmemTerm("row_band_scratch", stride * stride * nb * hh
+                     * _ceil_mult(wh, bf16_rows) * _lanes(c_in) * 2),
+            VmemTerm("weight_scratch", kh * kw * _ceil_mult(c_in, bf16_rows)
+                     * _lanes(bn) * 2),
+            # The row band's unpack (spread f32, bits int32, ±1 f32 and
+            # bf16) a phase at a time, one tap window, one dot's sums.
+            VmemTerm("unpack_tiles", _ceil_mult(nb * hh * wh, SUBLANE)
+                     * _lanes(c_in) * 14),
+            VmemTerm("tap_window", block_m * _lanes(c_in) * 2),
+            VmemTerm("dot_tile", block_m * _lanes(bn) * 4),
+        ]
+    return tuple(terms)
+
+
+def residual_conv_estimate(batch: int, in_hw: tuple[int, int], cw: int,
+                           kh: int, kw: int, c_out: int,
+                           out_hw: tuple[int, int], *,
+                           stride: int) -> LaunchEstimate:
+    """Estimate the residual conv launch: the tile's padded packed input
+    row band, the tap-major weight block, the correction, epilogue rows,
+    shortcut and both output tiles (all double-buffered, lanes padded to
+    128), the accumulator and float epilogue transients; on the MXU
+    route also the byte-spread matrix, the ±1 bf16 scratch of the
+    unpacked row band and weights, and the unpack and tap window
+    transients."""
+    oh, ow = out_hw
+    nb, block_oh, bn = residual_conv_blocks(batch, oh, ow, cw, c_out,
+                                            stride=stride, kh=kh, kw=kw)
+    return LaunchEstimate(
+        kernel="conv_residual",
+        grid=(c_out // bn, batch // nb, oh // block_oh),
+        terms=_residual_conv_terms(nb, block_oh, ow, cw, bn,
+                                   route=residual_conv_route(cw, c_out),
+                                   stride=stride, kh=kh, kw=kw,
+                                   in_w=in_hw[1]))
 
 
 def pointwise_residual_estimate(m: int, kw: int, n: int,

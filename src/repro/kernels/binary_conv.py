@@ -38,16 +38,17 @@ pad correction into the epilogue — one kernel launch where the model
 previously issued 8 sequential plane convs.
 
 ReActNet's residual conv is a fourth, :func:`binary_conv2d_residual_packed`:
-the same contraction and pad correction as a rolled loop over the taps,
-then an epilogue that adds a float32 shortcut and writes both the float
-stream and its packed sign (``fused_epilogue.residual_epilogue``).
+the same pad correction, but the contraction on the MXU — the packed
+words unpacked to ±1 bf16 in VMEM and one dot a tap — then an epilogue
+that adds a float32 shortcut and writes both the float stream and its
+packed sign (``fused_epilogue.residual_epilogue``).
 
 Supported: arbitrary integer stride (paper evaluates 1 and 2), SAME,
 VALID or explicit padding; spatial padding is staged as all-zero words
 (bit 0 == −1, the paper's convention) and corrected exactly in the
 epilogue.  The BCNN's kernels slice taps out of a loaded slab, which
-Mosaic allows at stride 1 only; the residual kernel reads strided taps
-from the ref.
+Mosaic allows at stride 1 only; the residual kernel splits its unpacked
+row band by stride phase, so each tap is a contiguous read.
 """
 from __future__ import annotations
 
@@ -56,6 +57,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis import vmem
 from repro.core import binarize as B
@@ -348,25 +350,126 @@ def _bitplane_conv_kernel(x_ref, w_ref, rowsum_ref, o_ref, *, kh, kw, stride,
                 - 2 * wacc) >> 1
 
 
+def _unpack_lanes(words: jax.Array, sel: jax.Array) -> jax.Array:
+    """(r, Cw) packed words -> (r, 32·Cw) ±1 float32, channel 32j + i (bit i
+    of word j) on lane 32j + i; bit 1 -> +1, bit 0 -> −1.
+
+    Splitting the lane axis into (Cw, 32) is a relayout Mosaic refuses,
+    so the words go through the MXU: their 4 bytes side by side,
+    (r, 4·Cw), times the 0/1 selection ``sel`` (4·Cw, 32·Cw)
+    (:func:`_byte_spread`) puts each byte on its 8 lanes (bytes are
+    below 256, exact in bf16), and each lane keeps bit ``lane % 8``.
+    """
+    v = jax.lax.bitcast_convert_type(words, jnp.int32)
+    b = jnp.concatenate([(v >> (8 * k)) & 0xFF for k in range(4)], axis=1)
+    spread = jnp.dot(b.astype(jnp.float32).astype(jnp.bfloat16), sel,
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    shift = jax.lax.broadcasted_iota(jnp.int32, spread.shape, 1) & 7
+    return (2 * ((spread >> shift) & 1) - 1).astype(jnp.float32)
+
+
+def _byte_spread(cw: int) -> jax.Array:
+    """The (4·Cw, 32·Cw) bf16 0/1 matrix of :func:`_unpack_lanes`: row
+    k·Cw + j (byte k of word j) feeds lanes 32j + 8k ... 32j + 8k + 7."""
+    shape = (4 * cw, B.WORD_BITS * cw)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    sel = ((jax.lax.rem(row, cw) == lane >> 5)
+           & (jax.lax.div(row, cw) == (lane >> 3) & 3))
+    return sel.astype(jnp.bfloat16)
+
+
+def _unpack_sublanes(words: jax.Array) -> jax.Array:
+    """(Cw, n) packed weight words -> (32·Cw, n) ±1 bf16, channel 32j + i
+    on row 32j + i: :func:`_unpack_lanes` mirrored, the selection on the
+    left."""
+    cw = words.shape[0]
+    v = jax.lax.bitcast_convert_type(words, jnp.int32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (B.WORD_BITS * cw, cw), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (B.WORD_BITS * cw, cw), 1)
+    spread = None
+    for k in range(4):
+        sel = ((row >> 5) == col) & (((row >> 3) & 3) == k)
+        b = ((v >> (8 * k)) & 0xFF).astype(jnp.float32).astype(jnp.bfloat16)
+        part = jnp.dot(sel.astype(jnp.float32).astype(jnp.bfloat16), b,
+                       preferred_element_type=jnp.float32)
+        spread = part if spread is None else spread + part
+    spread = spread.astype(jnp.int32)
+    shift = jax.lax.broadcasted_iota(jnp.int32, spread.shape, 0) & 7
+    return (2 * ((spread >> shift) & 1) - 1).astype(jnp.bfloat16)
+
+
+def _unpack_row_band(x_ref, sel_ref, xs_ref, *, stride) -> None:
+    """Unpack the tile's packed row band (``x_ref``: (nb, hblk, Wp, Cw))
+    into ``xs_ref`` (stride², nb, ⌈hblk/s⌉, ⌈Wp/s⌉, C_in) ±1 bf16, split
+    by (row, column) phase at stride 2 so that every tap window is a
+    contiguous read (Mosaic has strided loads of 32-bit data only)."""
+    nb, hblk, wp, cw = x_ref.shape
+    for pr in range(stride):
+        for pc in range(stride):
+            h, w = -(-(hblk - pr) // stride), -(-(wp - pc) // stride)
+            if stride == 1:
+                words = x_ref[...]
+            else:
+                words = x_ref[:, pl.ds(pr, h, stride=stride),
+                              pl.ds(pc, w, stride=stride), :]
+            pm1 = _unpack_lanes(words.reshape(nb * h * w, cw), sel_ref[...])
+            # Reshaped while 32-bit: Mosaic splits bf16 rows only in pairs.
+            xs_ref[pr * stride + pc, :, :h, :w] = pm1.reshape(
+                nb, h, w, pm1.shape[-1]).astype(jnp.bfloat16)
+
+
+def _tap_dots(xs_ref, ws_ref, *, kh, kw, stride, block_oh, ow) -> jax.Array:
+    """Σ over the KH·KW taps of window (m, C_in) @ weights (C_in, bn), one
+    MXU dot a tap, f32: K_tap − 2·mismatch per tap, exact (|sum| ≤ K)."""
+    _, nb, _, _, c_in = xs_ref.shape
+    m = nb * block_oh * ow
+    acc = None
+    for di in range(kh):
+        for dj in range(kw):
+            win = xs_ref[(di % stride) * stride + dj % stride, :,
+                         pl.ds(di // stride, block_oh),
+                         pl.ds(dj // stride, ow), :]
+            part = jnp.dot(win.reshape(m, c_in), ws_ref[di * kw + dj],
+                           preferred_element_type=jnp.float32)
+            acc = part if acc is None else acc + part
+    return acc
+
+
+def _shortcut_tile(sc_ref, *, stride, nb, block_oh, ow) -> jax.Array:
+    """This M tile's float shortcut, (nb·block_oh·ow, bn): the stream tile
+    itself at stride 1 (``sc_ref`` block (1, nb·block_m, bn)), its 2x2
+    average at stride 2 (block (nb, 2·block_oh, W, bn))."""
+    if stride == 1:
+        return sc_ref[0]
+
+    def part(r, c):
+        return sc_ref[:, pl.ds(r, block_oh, stride=2),
+                      pl.ds(c, ow, stride=2), :]
+
+    avg = avg_pool2x2(part(0, 0), part(0, 1), part(1, 0), part(1, 1))
+    return avg.reshape(nb * block_oh * ow, avg.shape[-1])
+
+
 def _rolled_tap_mismatch(x_ref, w_ref, *, kh, kw, stride, block_oh, ow,
                          cw) -> jax.Array:
-    """XNOR-popcount mismatch count of one M tile, over a rolled loop of
-    the KH·KW taps.
+    """XNOR-popcount mismatch count of one M tile on the VPU, over a
+    rolled loop of the KH·KW taps.
 
     Each step reads its tap straight from the tile's padded input row
-    band (``x_ref``: (1, (block_oh−1)·stride + KH, Wp, Cw)), strided at
-    stride 2, and adds one (block_oh·ow, bn) popcount-of-XOR per packed
-    word against the tap's weight rows (``w_ref``: (KH·KW, Cw, bn)).
-    Only the Cw words of one tap are unrolled, so compile time does not
-    grow with KH·KW, and no strided slice of a loaded value is needed
-    (Mosaic has none).
+    band (``x_ref``: (nb, (block_oh−1)·stride + KH, Wp, Cw)), strided at
+    stride 2, and adds one (nb·block_oh·ow, bn) popcount-of-XOR per
+    packed word against the tap's weight rows (``w_ref``: (KH·KW, Cw,
+    bn)).  Only the Cw words of one tap are unrolled, so compile time
+    does not grow with KH·KW.
     """
-    m = block_oh * ow
+    nb = x_ref.shape[0]
+    m = nb * block_oh * ow
     bn = w_ref.shape[-1]
 
     def tap(t, acc):
         di, dj = t // kw, t % kw
-        a = x_ref[0, pl.ds(di, block_oh, stride=stride),
+        a = x_ref[:, pl.ds(di, block_oh, stride=stride),
                   pl.ds(dj, ow, stride=stride), :].reshape(m, cw)
         wt = w_ref[t]                                       # (Cw, bn)
         for c in range(cw):
@@ -377,35 +480,51 @@ def _rolled_tap_mismatch(x_ref, w_ref, *, kh, kw, stride, block_oh, ow,
     return jax.lax.fori_loop(0, kh * kw, tap, jnp.zeros((m, bn), jnp.int32))
 
 
-def _shortcut_tile(sc_ref, *, stride, block_oh, ow) -> jax.Array:
-    """This M tile's float shortcut, (block_oh·ow, bn): the stream tile
-    itself at stride 1 (``sc_ref`` block (1, block_m, bn)), its 2x2
-    average at stride 2 (block (1, 2·block_oh, W, bn))."""
-    if stride == 1:
-        return sc_ref[0]
-
-    def part(r, c):
-        return sc_ref[0, pl.ds(r, block_oh, stride=2),
-                      pl.ds(c, ow, stride=2), :]
-
-    avg = avg_pool2x2(part(0, 0), part(0, 1), part(1, 0), part(1, 1))
-    return avg.reshape(block_oh * ow, avg.shape[-1])
-
-
-def _conv_residual_kernel(x_ref, w_ref, corr_ref, epi_ref, sc_ref, o_ref,
-                          p_ref, *, kh, kw, stride, block_oh, ow, cw,
-                          k_true):
-    """Residual binary conv: rolled-tap XNOR-popcount contraction, pad
-    correction, then :func:`fused_epilogue.residual_epilogue` against the
-    float shortcut.  Writes the float32 stream tile (``o_ref``) and the
+def _residual_out(z, corr_ref, epi_ref, sc_ref, o_ref, p_ref, *, stride, nb,
+                  block_oh, ow) -> None:
+    """Pad correction, :func:`fused_epilogue.residual_epilogue` against the
+    float shortcut; writes the float32 stream tile (``o_ref``) and the
     packed sign of stream + the next conv's bias (``p_ref``)."""
-    mism = _rolled_tap_mismatch(x_ref, w_ref, kh=kh, kw=kw, stride=stride,
-                                block_oh=block_oh, ow=ow, cw=cw)
-    z = jnp.int32(k_true) - 2 * mism + corr_ref[...]
-    sc = _shortcut_tile(sc_ref, stride=stride, block_oh=block_oh, ow=ow)
-    y, u = residual_epilogue(z, epi_ref[...], sc)
+    sc = _shortcut_tile(sc_ref, stride=stride, nb=nb, block_oh=block_oh,
+                        ow=ow)
+    y, u = residual_epilogue(z + corr_ref[...], epi_ref[...], sc)
     o_ref[0] = y
     p_ref[0, 0] = pack_lanes((u >= 0).astype(jnp.int32))
+
+
+def _conv_residual_mxu_kernel(x_ref, w_ref, sel_ref, corr_ref, epi_ref,
+                              sc_ref, o_ref, p_ref, xs_ref, ws_ref, *, kh,
+                              kw, stride, block_oh, ow):
+    """Residual binary conv on the MXU: ±1 operands unpacked in VMEM, one
+    bf16 dot a tap.  Grid (C_out blocks, image groups, M tiles): the
+    C_out block's weights are unpacked into ``ws_ref`` at its first step
+    and reused by every later one; each step unpacks its own row band
+    into ``xs_ref``."""
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _():
+        def tap(t, carry):
+            ws_ref[t] = _unpack_sublanes(w_ref[t])
+            return carry
+        jax.lax.fori_loop(0, kh * kw, tap, 0)
+
+    _unpack_row_band(x_ref, sel_ref, xs_ref, stride=stride)
+    dots = _tap_dots(xs_ref, ws_ref, kh=kh, kw=kw, stride=stride,
+                     block_oh=block_oh, ow=ow)
+    _residual_out(dots.astype(jnp.int32), corr_ref, epi_ref, sc_ref, o_ref,
+                  p_ref, stride=stride, nb=x_ref.shape[0], block_oh=block_oh,
+                  ow=ow)
+
+
+def _conv_residual_vpu_kernel(x_ref, w_ref, corr_ref, epi_ref, sc_ref, o_ref,
+                              p_ref, *, kh, kw, stride, block_oh, ow, k_true):
+    """Residual binary conv with XNOR-popcount on the VPU, for C_out blocks
+    narrower than the MXU (``analysis.vmem.residual_conv_route``)."""
+    mism = _rolled_tap_mismatch(x_ref, w_ref, kh=kh, kw=kw, stride=stride,
+                                block_oh=block_oh, ow=ow,
+                                cw=x_ref.shape[-1])
+    _residual_out(jnp.int32(k_true) - 2 * mism, corr_ref, epi_ref, sc_ref,
+                  o_ref, p_ref, stride=stride, nb=x_ref.shape[0],
+                  block_oh=block_oh, ow=ow)
 
 
 # ---------------------------------------------------------------------------
@@ -644,63 +763,92 @@ def binary_conv2d_residual_packed(x_packed: jax.Array, w_taps: jax.Array,
     and its packed sign plus the next bias, (B, OH, OW, ceil(C_out/32))
     uint32.
 
-    Grid (B, M tiles of ``block_oh`` output rows, C_out blocks), blocks
-    from the shapes (``analysis.vmem.residual_conv_blocks``).  Each M
-    tile reads only its input row band, halo rows included (element
-    offsets): the packed words of a row sit on lanes, so a whole image
-    of 1-2 words a pixel would take 128 times its size in VMEM.  A C_out
-    of at most 128 lanes is one block as wide as the array, not padded
-    to 128.
+    The contraction's route comes from the shapes
+    (``analysis.vmem.residual_conv_route``): ±1 bf16 operands unpacked in
+    VMEM and one MXU dot a tap where C_in and C_out each fill a 128-lane
+    group, XOR-popcount on the VPU below that.  Grid (C_out blocks,
+    image groups, M tiles), blocks from the shapes
+    (``analysis.vmem.residual_conv_blocks``): an M tile is ``block_oh``
+    output rows of one image, or ``nb`` whole images where one image is
+    fewer rows than the tile.  Each M tile reads only its input row
+    band, halo rows included (element offsets): the packed words of a
+    row sit on lanes, so a whole image of 1-2 words a pixel would take
+    128 times its size in VMEM.  A C_out of at most 128 lanes is one
+    block as wide as the array, not padded to 128.
     """
     bsz, h, w, cw = x_packed.shape
     oh, ow = out_hw
-    block_oh, block_n = vmem.residual_conv_blocks(oh, ow, cw, c_out)
+    route = vmem.residual_conv_route(cw, c_out)
+    nb, block_oh, block_n = vmem.residual_conv_blocks(bsz, oh, ow, cw, c_out,
+                                                      stride=stride, kh=kh,
+                                                      kw=kw)
     xp = jnp.pad(x_packed, ((0, 0), pads[0], pads[1], (0, 0)))
     wp = xp.shape[2]
     hblk = (block_oh - 1) * stride + kh
-    block_m = block_oh * ow
+    block_m = nb * block_oh * ow
+    m_tiles = oh // block_oh
     n_blocks = c_out // block_n
     bnw = block_n // B.WORD_BITS
-    grid = (bsz, oh // block_oh, n_blocks)
+    groups = bsz // nb
+    grid = (n_blocks, groups, m_tiles)
     if stride == 1:
-        sc = shortcut.reshape(bsz, oh * ow, c_out)
+        sc = shortcut.reshape(groups, nb * oh * ow, c_out)
         sc_spec = pl.BlockSpec((1, block_m, block_n),
-                               lambda b, m, j: (b, m, j))
+                               lambda j, g, m: (g, m, j))
     else:
         if stride != 2 or (h, w) != (2 * oh, 2 * ow):
             raise ValueError(f"the residual conv takes stride 1, or stride 2 "
                              f"on an even input; got stride {stride}, input "
                              f"{(h, w)}, output {out_hw}")
         sc = shortcut
-        sc_spec = pl.BlockSpec((1, 2 * block_oh, w, block_n),
-                               lambda b, m, j: (b, m, 0, j))
+        sc_spec = pl.BlockSpec((nb, 2 * block_oh, w, block_n),
+                               lambda j, g, m: (g, m, 0, j))
+    corr = jnp.tile(correction.reshape(oh * ow, c_out), (nb, 1))
+    x_spec = pl.BlockSpec((pl.Element(nb), pl.Element(hblk), pl.Element(wp),
+                           pl.Element(cw)),
+                          lambda j, g, m: (g * nb, m * block_oh * stride, 0, 0))
+    w_spec = pl.BlockSpec((kh * kw, cw, block_n), lambda j, g, m: (0, 0, j))
+    tile_specs = [
+        pl.BlockSpec((block_m, block_n), lambda j, g, m: (m, j)),
+        pl.BlockSpec((EPI_ROWS, block_n), lambda j, g, m: (0, j)),
+        sc_spec,
+    ]
+    statics = dict(kh=kh, kw=kw, stride=stride, block_oh=block_oh, ow=ow)
+    if route == "mxu":
+        c_in = cw * B.WORD_BITS
+        kernel = functools.partial(_conv_residual_mxu_kernel, **statics)
+        in_specs = [x_spec, w_spec,
+                    pl.BlockSpec((4 * cw, c_in), lambda j, g, m: (0, 0)),
+                    *tile_specs]
+        operands = (xp, w_taps, _byte_spread(cw), corr, epi, sc)
+        scratch = [
+            pltpu.VMEM((stride * stride, nb, -(-hblk // stride),
+                        -(-wp // stride), c_in), jnp.bfloat16),
+            pltpu.VMEM((kh * kw, c_in, block_n), jnp.bfloat16)]
+    else:
+        kernel = functools.partial(_conv_residual_vpu_kernel, k_true=k_true,
+                                   **statics)
+        in_specs = [x_spec, w_spec, *tile_specs]
+        operands = (xp, w_taps, corr, epi, sc)
+        scratch = []
 
-    kernel = functools.partial(_conv_residual_kernel, kh=kh, kw=kw,
-                               stride=stride, block_oh=block_oh, ow=ow,
-                               cw=cw, k_true=k_true)
     y, p = pl.pallas_call(
         kernel,
         name="_conv_residual_kernel",
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((pl.Element(1), pl.Element(hblk), pl.Element(wp),
-                          pl.Element(cw)),
-                         lambda b, m, j: (b, m * block_oh * stride, 0, 0)),
-            pl.BlockSpec((kh * kw, cw, block_n), lambda b, m, j: (0, 0, j)),
-            pl.BlockSpec((block_m, block_n), lambda b, m, j: (m, j)),
-            pl.BlockSpec((EPI_ROWS, block_n), lambda b, m, j: (0, j)),
-            sc_spec,
-        ],
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_m, block_n), lambda b, m, j: (b, m, j)),
-            pl.BlockSpec((1, 1, block_m, bnw), lambda b, m, j: (b, j, m, 0)),
+            pl.BlockSpec((1, block_m, block_n), lambda j, g, m: (g, m, j)),
+            pl.BlockSpec((1, 1, block_m, bnw), lambda j, g, m: (g, j, m, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, oh * ow, c_out), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, n_blocks, oh * ow, bnw), jnp.uint32),
+            jax.ShapeDtypeStruct((groups, nb * oh * ow, c_out), jnp.float32),
+            jax.ShapeDtypeStruct((groups, n_blocks, nb * oh * ow, bnw),
+                                 jnp.uint32),
         ],
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(xp, w_taps, correction.reshape(oh * ow, c_out), epi, sc)
+    )(*operands)
     return (y.reshape(bsz, oh, ow, c_out),
             unblock_packed(p).reshape(bsz, oh, ow, n_blocks * bnw))
 

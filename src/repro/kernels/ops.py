@@ -520,7 +520,12 @@ def binary_conv2d_residual(plan: dict, epi: jax.Array, x_packed: jax.Array,
 
     backend: 'pallas' (``binary_conv._conv_residual_kernel``, one launch)
     | 'jnp'/'ref' (the oracle) | 'auto'; unknown strings raise
-    ``ValueError``.  Blocks come from the shapes.
+    ``ValueError``.  Route and blocks come from the shapes: the ±1
+    operands unpacked in VMEM and contracted on the MXU, or XOR-popcount
+    on the VPU below a 128-lane C_in or C_out
+    (``analysis.vmem.residual_conv_route``).  Each pallas dispatch bumps
+    ``ops.dispatch.residual_conv.<route>`` on the process-wide telemetry
+    registry.
     """
     backend = _resolve(backend)
     statics = dict(kh=plan["kh"], kw=plan["kw"], stride=plan["stride"],
@@ -531,6 +536,9 @@ def binary_conv2d_residual(plan: dict, epi: jax.Array, x_packed: jax.Array,
             x_packed.shape[0], plan["in_hw"], plan["cw"], plan["kh"],
             plan["kw"], plan["c_out"], plan["out_hw"],
             stride=plan["stride"]))
+        route = _vmem.residual_conv_route(plan["cw"], plan["c_out"])
+        telemetry.default().metrics.counter(
+            f"ops.dispatch.residual_conv.{route}").inc()
         return _bconv.binary_conv2d_residual_packed(
             x_packed, plan["w_taps"], plan["correction"], epi, shortcut,
             out_hw=plan["out_hw"], interpret=not _on_tpu(), **statics)

@@ -88,19 +88,32 @@ def _epi(key, c):
                            ).astype(jnp.float32)
 
 
-@pytest.mark.parametrize("h,c,stride,batch", [
-    (8, 32, 1, 2), (8, 64, 2, 2), (4, 256, 1, 1), (6, 32, 2, 1),
+# (images a tile, M tiles an image, C_out blocks) each case reaches; C
+# below 128 takes the VPU route, 128 and up the MXU route.
+CONV_CASES = [
+    # whole images folded into one M tile
+    (8, 32, 1, 2, (2, 1, 1)), (8, 64, 2, 2, (2, 1, 1)),
+    (16, 128, 2, 3, (3, 1, 1)),
+    (4, 256, 1, 1, (1, 1, 2)), (6, 32, 2, 1, (1, 1, 1)),
     # several M tiles, each reading its own input row band
-    (40, 32, 1, 1), (40, 512, 2, 1)])
-def test_conv_residual_kernel_matches_oracle(h, c, stride, batch):
+    (40, 32, 1, 1, (1, 2, 1)), (48, 512, 2, 1, (1, 2, 4)),
+    (40, 512, 2, 1, (1, 1, 4)),
+    # C of 128 at stride 2; C of 1,024 over several C_out blocks
+    (8, 128, 2, 2, (2, 1, 1)), (4, 1024, 1, 2, (2, 1, 8))]
+
+
+@pytest.mark.parametrize("h,c,stride,batch,tiles", CONV_CASES,
+                         ids=["-".join(map(str, k[:4])) for k in CONV_CASES])
+def test_conv_residual_kernel_matches_oracle(h, c, stride, batch, tiles):
     key = jax.random.PRNGKey(h * c + stride)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     w = jax.random.normal(k1, (c, 3, 3, c))
     plan = BC.make_residual_conv_plan(w, input_hw=(h, h), stride=stride)
     assert plan["pads"] == ((1, 1), (1, 1))
     oh = h // stride
-    block_oh, _ = vmem.residual_conv_blocks(oh, oh, c // 32, c)
-    assert (oh // block_oh > 1) == (h == 40)
+    nb, block_oh, bn = vmem.residual_conv_blocks(batch, oh, oh, c // 32, c,
+                                                 stride=stride)
+    assert (nb, oh // block_oh, c // bn) == tiles
     x = jax.random.normal(k2, (batch, h, h, c))
     sc = jax.random.normal(k3, (batch, h, h, c))
     epi = _epi(k4, c)
@@ -122,6 +135,76 @@ def test_conv_residual_kernel_matches_oracle(h, c, stride, batch):
     y_f, u_f = FE.residual_epilogue(z, epi, sc)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y_f))
     np.testing.assert_array_equal(np.asarray(p), np.asarray(B.pack_bits(u_f)))
+
+
+@pytest.mark.parametrize("sx,sw", [(1.0, 1.0), (1.0, -1.0)])
+def test_conv_residual_largest_sums_are_exact(sx, sw):
+    """All-+1 or all-−1 inputs and weights at C = 1,024: the interior
+    sums reach ±K = ±9,216 and the border ones stop at the taps inside.
+    With an identity epilogue and a zero shortcut the stream is z
+    itself, so it shows that no sum rounds on the MXU."""
+    c = 1024
+    w = jnp.full((c, 3, 3, c), sw)
+    plan = BC.make_residual_conv_plan(w, input_hw=(4, 4), stride=1)
+    x = jnp.full((2, 4, 4, c), sx)
+    epi = jnp.zeros((FE.EPI_ROWS, c)).at[FE.EPI_SCALE].set(1.0).at[
+        FE.EPI_SLOPE].set(1.0)
+    sc = jnp.zeros((2, 4, 4, c))
+    y, p = kops.binary_conv2d_residual(plan, epi, B.pack_bits(x), sc,
+                                       backend="pallas")
+    taps = np.array([2, 3, 3, 2])          # 3x3 taps inside a 4-wide row
+    want = sx * sw * c * np.multiply.outer(taps, taps)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.broadcast_to(want[None, :, :, None],
+                                                  y.shape))
+    assert float(jnp.abs(y).max()) == 9 * c
+    y_ref, p_ref = kops.binary_conv2d_residual(plan, epi, B.pack_bits(x),
+                                               sc, backend="jnp")
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(p_ref))
+
+
+def test_conv_residual_route_follows_the_shapes():
+    """The MXU where C_in and C_out each fill a 128-lane group, the VPU
+    below: at ReActNet-A's widths, its two 112² blocks on the VPU."""
+    routes = [vmem.residual_conv_route(bs.c_in // 32, bs.c_in)
+              for bs in R.block_shapes(R.ReActNetSpec())]
+    assert routes == ["vpu", "vpu"] + ["mxu"] * 11
+    assert vmem.residual_conv_route(2, 256) == "vpu"
+    assert vmem.residual_conv_route(8, 64) == "vpu"
+
+
+def test_conv_residual_tiles_fit_vmem_at_256_images():
+    """Every 3x3 block of ReActNet-A at 256 images takes a tile whose
+    estimate, ±1 scratch included, fits the VMEM budget: 7x7x1024 halves
+    its tile from 8 images to 4 to get there."""
+    for bs in R.block_shapes(R.ReActNetSpec()):
+        oh = bs.in_hw[0] // bs.stride
+        est = vmem.residual_conv_estimate(256, bs.in_hw, bs.c_in // 32, 3, 3,
+                                          bs.c_in, (oh, oh), stride=bs.stride)
+        assert est.fits(), est.breakdown()
+    assert vmem.residual_conv_blocks(256, 7, 7, 32, 1024) == (4, 7, 128)
+
+
+@pytest.mark.parametrize("c,route", [(32, "vpu"), (128, "mxu")])
+def test_conv_residual_dispatch_counted(c, route):
+    """Each pallas dispatch bumps ``ops.dispatch.residual_conv.<route>``
+    once, and the other route's counter not at all; the jnp oracle bumps
+    neither."""
+    from repro import telemetry
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    plan = BC.make_residual_conv_plan(jax.random.normal(k1, (c, 3, 3, c)),
+                                      input_hw=(4, 4), stride=1)
+    x = B.pack_bits(jax.random.normal(k2, (1, 4, 4, c)))
+    sc = jnp.zeros((1, 4, 4, c))
+    epi = _epi(k2, c)
+    counters = {r: telemetry.default().metrics.counter(
+        f"ops.dispatch.residual_conv.{r}") for r in ("mxu", "vpu")}
+    before = {r: k.value for r, k in counters.items()}
+    kops.binary_conv2d_residual(plan, epi, x, sc, backend="pallas")
+    kops.binary_conv2d_residual(plan, epi, x, sc, backend="jnp")
+    assert {r: k.value - before[r] for r, k in counters.items()} == {
+        r: int(r == route) for r in counters}
 
 
 @pytest.mark.parametrize("m,c,n", [(40, 32, 64), (64, 64, 128),

@@ -169,6 +169,10 @@ def test_binary_attention_compiles(one_chip, request):
 REACTNET_CONVS = {
     "b1_112_c32_s1": (112, 32, 1),
     "b2_112_c64_s2": (112, 64, 2),
+    "b3_56_c128_s1": (56, 128, 1),
+    "b4_56_c128_s2": (56, 128, 2),
+    "b5_28_c256_s1": (28, 256, 1),
+    "b6_28_c256_s2": (28, 256, 2),
     "b7_14_c512_s1": (14, 512, 1),
     "b12_14_c512_s2": (14, 512, 2),
     "b13_7_c1024_s1": (7, 1024, 1),
