@@ -14,9 +14,9 @@ Four passes over traced programs and source (see ``docs/analysis.md``):
   (``python -m repro.analysis.lint src/``).
 
 :mod:`repro.analysis.graph` is the shared jaxpr traversal under the
-traced passes (``utils/jaxpr.py`` re-exports it), and
-:mod:`repro.analysis.report` merges every pass into the CI-gated
-baseline (``python -m repro.analysis --check``).
+traced passes, and :mod:`repro.analysis.report` merges every pass,
+with each forward's launch list, route and largest HBM intermediate,
+into the CI-gated baseline (``python -m repro.analysis --check``).
 """
 from repro.analysis.graph import (CALL_PRIMITIVES, PallasLaunch,
                                   call_subjaxpr, count_pallas_calls,

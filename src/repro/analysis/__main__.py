@@ -5,12 +5,13 @@
 
 ``--check`` (the CI analysis job) fails on hard invariant violations
 (packedness escapes, over-budget launches, lint/sharding violations)
-AND on any drift against ``experiments/ANALYSIS_baseline.json``;
-``--write`` regenerates the baseline after an intentional change.
+AND on any drift against ``experiments/ANALYSIS_baseline.json`` —
+launch lists, routes and HBM intermediates included; ``--write``
+regenerates the baseline after an intentional change.
 
-The sharding cells need 8 devices: like ``telemetry/probes.py``, the
-CLI re-execs itself with ``REPRO_ANALYSIS_FORCE_DEVICES`` set so the
-XLA host-device override below lands before jax's first import.
+The sharding cells need 8 devices: the CLI re-execs itself with
+``REPRO_ANALYSIS_FORCE_DEVICES`` set so the XLA host-device override
+below lands before jax's first import.
 """
 from __future__ import annotations
 
@@ -108,7 +109,12 @@ def main(argv: list[str] | None = None) -> int:
               f"({len(report['cells'])} cells)")
     if not (args.json or args.write or args.check):
         for name, cell in report["cells"].items():
-            if name.startswith("packedness/"):
+            if name.startswith("forward/"):
+                print(f"{name}: {cell['launch_count']} launches "
+                      f"route={cell['route']} "
+                      f"max_intermediate={cell['max_intermediate_bytes']}B "
+                      f"{cell['max_intermediate_shape']}")
+            elif name.startswith("packedness/"):
                 print(f"{name}: {cell['launch_count']} launches, "
                       f"max_live_unpacked={cell['max_live_unpacked_bytes']}B"
                       f" escapes={len(cell['escapes'])}")
@@ -122,6 +128,7 @@ def main(argv: list[str] | None = None) -> int:
                 print(f"lint: {len(cell['violations'])} violation(s)")
             else:
                 print(f"{name}: kinds={cell['kinds']} "
+                      f"{cell['total_bytes']:.0f}B plan={cell['shard_plan']} "
                       f"violations={len(cell['violations'])}")
     return 0
 

@@ -13,9 +13,9 @@ The sharded packed forward has exactly two legal collective shapes
 
 ``utils/hlo.py`` stays the low-level text parser (regex + wire-byte
 model); this module turns its output into reusable verdicts with a
-violation list, so the verifier, the telemetry probes, and the merged
-analysis report all apply the SAME rule instead of three hand-rolled
-copies of ``set(kinds) <= {...}``.
+violation list, so the verifier and the merged analysis report both
+apply the SAME rule instead of hand-rolled copies of
+``set(kinds) <= {...}``.
 """
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
 """Traced-program graph core: the ONE jaxpr traversal every static
-pass and every legacy helper is built on.
+pass is built on.
 
 The kernel subsystem's evidence ("the bit-plane conv is ONE launch",
 "the patch matrix never hits HBM") is op-count-level: it comes from
@@ -13,9 +13,6 @@ the fused-epilogue evidence), and the dataflow passes in
 cannot drift between them.  ``pallas_call`` bodies are never descended
 into: everything inside one is a single launch's VMEM-resident work,
 not an HBM intermediate or a separate launch.
-
-``utils/jaxpr.py`` re-exports this module's names for older call
-sites; new code should import from ``repro.analysis``.
 """
 from __future__ import annotations
 
@@ -102,8 +99,8 @@ def pallas_eqns(fn: Any, *args: Any) -> list[Any]:
 
 def pallas_launches(fn: Any, *args: Any) -> list[PallasLaunch]:
     """Every pallas_call in ``fn``'s jaxpr, in trace order, with its
-    kernel name and launch grid — the unit the telemetry cost probes
-    (``telemetry/probes.py``) record and regression-gate."""
+    kernel name and launch grid — the unit the analysis report's
+    ``forward/`` cells record and regression-gate."""
     return [PallasLaunch(kernel=kernel_name(eqn),
                          grid=tuple(eqn.params["grid_mapping"].grid))
             for eqn in pallas_eqns(fn, *args)]

@@ -1,28 +1,32 @@
 """Merged static-analysis report: every pass over the canonical demo
-models, keyed like ``PROBES_baseline.json`` and drift-gated in CI.
+models, drift-gated against one committed baseline.
 
 Cells (stable keys — they ARE the baseline diff surface):
 
-* ``packedness/{bmlp,bcnn,transformer,reactnet}`` — the packedness dataflow
-  verdict for each packed forward at the serving batch (8): HBM
-  crossing classification, max live unpacked bytes, escapes (must stay
-  empty);
+* ``forward/{kind}_b{batch}`` — what each packed forward lowers to:
+  per-launch kernel name and grid, the launch count, the GEMV/GEMM
+  route ``kernels.ops.dispatch_batch`` picks for the batch, and the
+  largest HBM intermediate (bytes + shape).  bmlp, bcnn and the
+  transformer at the GEMV (1, 8) and GEMM (32) serving batches;
+  reactnet, which has no route to switch, at batch 8;
+* ``packedness/{bmlp,bcnn,transformer,reactnet}`` — the packedness
+  dataflow verdict for each packed forward at the serving batch (8):
+  HBM crossing classification, max live unpacked bytes, escapes (must
+  stay empty);
 * ``vmem/{kind}_b8`` — the traced per-launch VMEM estimates (kernel,
   grid, bytes, fits) for the same forwards;
 * ``lint`` — the repo lint pass over ``src/`` (violations must stay
   empty);
-* ``sharding/{bmlp,bcnn}_4x2`` — the collective-rule verdict for the
-  model-parallel mesh the probes exercise (all-gather-only).
+* ``sharding/{bmlp,bcnn}_4x2`` — one compile of the sharded forward on
+  the (4, 2) model-parallel mesh: the collective-rule verdict
+  (all-gather-only), collective kinds and wire bytes, and the per-stage
+  shard plan.
 
 CI runs ``PYTHONPATH=src python -m repro.analysis --check`` and fails
 on ANY drift against ``experiments/ANALYSIS_baseline.json``; after an
 intentional kernel/model change, regenerate with ``--write`` and
 commit the diff (see ``docs/analysis.md``).  The sharding cells need 8
-devices — the CLI re-execs itself with forced host devices, same
-pattern as ``telemetry/probes.py``.
-
-``diff_reports`` lives here (moved from ``telemetry/probes.py``, which
-now re-exports it): one structural differ serves both baselines.
+devices — the CLI re-execs itself with forced host devices.
 """
 from __future__ import annotations
 
@@ -33,6 +37,7 @@ BASELINE_PATH = os.path.join("experiments", "ANALYSIS_baseline.json")
 SHARDED_MESH = (4, 2)
 SHARDED_DEVICES = SHARDED_MESH[0] * SHARDED_MESH[1]
 REPORT_BATCH = 8
+FORWARD_BATCHES = (1, 8, 32)
 
 
 def repo_root() -> str:
@@ -41,10 +46,8 @@ def repo_root() -> str:
 
 
 def demo_packed(kind: str) -> Any:
-    """The shared demo configs every standing gate probes: the three
-    smoke-sized demo image networks and the reduced gemma2 binary LM
-    (the same builders ``telemetry/probes.py`` records baselines
-    for)."""
+    """The shared demo configs every cell traces: the three smoke-sized
+    demo image networks and the reduced gemma2 binary LM."""
     from repro.models import cnn
 
     if kind == "transformer":
@@ -72,19 +75,44 @@ def _forward_and_input(packed: Any, batch: int):
     return (lambda a: fwd(a)), x
 
 
-def packedness_cell(kind: str, *, batch: int = REPORT_BATCH) -> dict:
+def forward_cell(packed: Any, batch: int) -> dict:
+    """Launch inventory, route and largest HBM intermediate of one
+    packed forward at one batch.  Pure tracing (``jax.make_jaxpr``):
+    no kernel executes."""
+    from repro.analysis.graph import max_intermediate_bytes, pallas_launches
+    from repro.kernels import ops as kops
+    from repro.models import cnn
+
+    fn, x = _forward_and_input(packed, batch)
+    launches = pallas_launches(fn, x)
+    nbytes, shape = max_intermediate_bytes(fn, x)
+    return {
+        "kind": cnn.packed_kind(packed), "batch": batch, "backend": "pallas",
+        "launch_count": len(launches),
+        "launches": [{"kernel": ln.kernel, "grid": list(ln.grid)}
+                     for ln in launches],
+        "route": kops.dispatch_batch(batch,
+                                     cnn.packed_dense_kw_words(packed)),
+        "max_intermediate_bytes": int(nbytes),
+        "max_intermediate_shape": list(shape),
+    }
+
+
+def packedness_cell(packed: Any, *, batch: int = REPORT_BATCH) -> dict:
     """Packedness verdict for one demo forward (pallas backend)."""
     from repro.analysis.packedness import analyze_packedness, model_policy
+    from repro.models import cnn
 
-    fn, x = _forward_and_input(demo_packed(kind), batch)
-    return analyze_packedness(fn, x, policy=model_policy(kind)).to_json()
+    fn, x = _forward_and_input(packed, batch)
+    return analyze_packedness(
+        fn, x, policy=model_policy(cnn.packed_kind(packed))).to_json()
 
 
-def vmem_cell(kind: str, *, batch: int = REPORT_BATCH) -> list[dict]:
+def vmem_cell(packed: Any, *, batch: int = REPORT_BATCH) -> list[dict]:
     """Traced per-launch VMEM estimates for one demo forward."""
     from repro.analysis.vmem import estimate_forward
 
-    fn, x = _forward_and_input(demo_packed(kind), batch)
+    fn, x = _forward_and_input(packed, batch)
     return [est.to_json() for est in estimate_forward(fn, x)]
 
 
@@ -97,11 +125,11 @@ def lint_cell(root: str | None = None) -> dict:
                            for v in lint_paths([root])]}
 
 
-def sharding_cell(kind: str, *,
+def sharding_cell(packed: Any, *,
                   mesh_shape: tuple[int, int] = SHARDED_MESH) -> dict:
-    """Collective-rule verdict for one demo forward on a (data, model)
-    mesh — the compiled-HLO path ``probe_sharded`` records bytes for,
-    run through ``analysis.collectives.check_mesh``.  Requires
+    """One compile of a demo forward on a (data, model) mesh: the
+    ``analysis.collectives.check_mesh`` verdict with its collective
+    kinds and wire bytes, plus the per-stage shard plan.  Requires
     ``prod(mesh_shape)`` devices."""
     import numpy as np
 
@@ -110,26 +138,31 @@ def sharding_cell(kind: str, *,
     from repro.launch.mesh import make_mesh
     from repro.models import cnn
 
-    packed = demo_packed(kind)
     mesh = make_mesh(mesh_shape, ("data", "model"))
     fwd = SH.make_sharded_forward(packed, mesh, backend="jnp")
     x = np.zeros((REPORT_BATCH, *cnn.packed_input_shape(packed)), np.uint8)
     hlo = fwd.lower(x).compile().as_text()
-    return check_mesh(hlo, mesh_shape).to_json()
+    return {**check_mesh(hlo, mesh_shape).to_json(),
+            "kind": fwd.kind, "mesh": list(mesh_shape), "batch": REPORT_BATCH,
+            "shard_plan": {k: list(v) for k, v in fwd.shard_plan.items()}}
 
 
 def merged_report(*, sharded: bool = True) -> dict:
-    """All four passes over the canonical cells (see module docstring)."""
+    """All passes over the canonical cells (see module docstring)."""
     cells: dict[str, Any] = {}
     for kind in ("bmlp", "bcnn", "transformer", "reactnet"):
-        cells[f"packedness/{kind}"] = packedness_cell(kind)
-        cells[f"vmem/{kind}_b{REPORT_BATCH}"] = vmem_cell(kind)
-    cells["lint"] = lint_cell()
-    if sharded:
-        for kind in ("bmlp", "bcnn"):
+        packed = demo_packed(kind)
+        # ReActNet has no GEMV/GEMM route to switch: one batch covers it.
+        batches = (REPORT_BATCH,) if kind == "reactnet" else FORWARD_BATCHES
+        for batch in batches:
+            cells[f"forward/{kind}_b{batch}"] = forward_cell(packed, batch)
+        cells[f"packedness/{kind}"] = packedness_cell(packed)
+        cells[f"vmem/{kind}_b{REPORT_BATCH}"] = vmem_cell(packed)
+        if sharded and kind in ("bmlp", "bcnn"):
             cells[f"sharding/{kind}_"
                   f"{SHARDED_MESH[0]}x{SHARDED_MESH[1]}"] = \
-                sharding_cell(kind)
+                sharding_cell(packed)
+    cells["lint"] = lint_cell()
     return {"schema": 1, "cells": cells}
 
 
@@ -155,11 +188,7 @@ def report_ok(report: dict) -> list[str]:
 
 
 def diff_reports(baseline: Any, current: Any, path: str = "") -> list[str]:
-    """Recursive structural diff, one human-readable line per drift.
-
-    Shared by this module's ``--check`` gate and the telemetry probes'
-    (``telemetry/probes.py`` re-exports it).
-    """
+    """Recursive structural diff, one human-readable line per drift."""
     out: list[str] = []
     if isinstance(baseline, dict) and isinstance(current, dict):
         for k in sorted(set(baseline) | set(current)):

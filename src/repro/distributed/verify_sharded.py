@@ -14,16 +14,14 @@ Proves, without hardware, that ``make_sharded_forward`` is
   a partial sum or an un-packed int32 activation).
 
 Both rules come from ``analysis.collectives`` (``check_mesh``), the
-shared analyzers the telemetry probes and the merged analysis report
-also consume.
+shared analyzers the merged analysis report also consumes.
 
 Usage (the CI sharding job and tests/test_sharded_forward.py run this):
 
     PYTHONPATH=src python -m repro.distributed.verify_sharded [--json]
 
 NOTE: the XLA_FLAGS line below must execute before ANY other import
-touches jax — keep it immediately after the docstring (same pattern as
-launch/dryrun.py).
+touches jax — keep it immediately after the docstring.
 """
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
@@ -115,7 +113,7 @@ def run_cells(backends=("jnp",), pallas_cell: bool = True) -> list[dict]:
         # Data-parallel meshes must be collective-free; model meshes may
         # emit packed-word all-gathers only (a partial sum crossing
         # chips would surface as an all-reduce).  The rules live in
-        # analysis.collectives so the probes/report apply the same ones.
+        # analysis.collectives so the analysis report applies the same ones.
         coll = check_mesh(fwd.lower(x).compile().as_text(), shape)
         rec = {
             "kind": kind, "mesh": list(shape), "backend": backend,

@@ -4,8 +4,8 @@
         --reduced --steps 50 [--quant binary] [--data 2 --model 1] \
         [--microbatches 2] [--ckpt-dir /tmp/ckpt] [--compress-grads]
 
-Full-size configs target the production mesh (launch/dryrun.py proves
-lowering); on this CPU container use --reduced for a real end-to-end run.
+Full-size configs target the production mesh; on a CPU host use
+--reduced for a real end-to-end run.
 """
 from __future__ import annotations
 

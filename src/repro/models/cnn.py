@@ -97,8 +97,8 @@ def _gather_packed(hp: jax.Array, axis_name: str) -> jax.Array:
     Every gather site bumps ``sharding.gathers`` on the process-wide
     telemetry registry at TRACE time — i.e. it counts the all-gather
     eqns a sharded forward lowers to, the same structural fact the
-    probes' ``collective_kinds`` gate, not per-execution traffic (the
-    compiled function re-runs without re-tracing).
+    analysis report's ``sharding/`` cells gate, not per-execution
+    traffic (the compiled function re-runs without re-tracing).
     """
     telemetry.default().metrics.counter("sharding.gathers").inc()
     return jax.lax.all_gather(hp, axis_name, axis=hp.ndim - 1, tiled=True)
@@ -452,8 +452,8 @@ def packed_dense_kw_words(packed: dict) -> int:
 
 def demo_model(kind: str, *, smoke: bool = False, seed: int = 0):
     """Reduced evaluation-network preset + random params for demo
-    drivers — the serving CLI (``launch/serve.py``) and the serving
-    benchmark (``benchmarks/serve_latency.py``) both build from this
+    drivers — the serving CLI (``launch/serve.py``) and the static
+    analysis report (``analysis/report.py``) both build from this
     one place so their shapes cannot drift.  Returns
     ``(params, spec, kind)``.  ``smoke`` picks CI-sized shapes.
     """

@@ -10,8 +10,7 @@ In a jit/GSPMD program the all-reduce is implicit, so this is implemented
 as a gradient transform whose *numerics* match 1-bit-compressed
 communication; the 32x collective-byte reduction it would buy on the wire
 is accounted analytically in EXPERIMENTS.md §Perf.  ``pack_bits`` from
-the core library is reused for the on-the-wire layout in the benchmark
-(`benchmarks/grad_compress_bytes.py`).
+the core library is reused for the on-the-wire layout.
 """
 from __future__ import annotations
 

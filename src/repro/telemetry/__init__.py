@@ -1,15 +1,14 @@
 """Telemetry: the repo's one observability layer (numpy its only dependency).
 
-Three pieces (see ``docs/observability.md`` for the full taxonomy):
+Two pieces (see ``docs/observability.md`` for the full taxonomy):
 
 * :mod:`repro.telemetry.metrics` — counters / gauges / histograms with
   snapshot, reset, and merge (``MetricsRegistry``).
 * :mod:`repro.telemetry.trace` — nestable spans with Chrome
   ``trace_event`` export, near-zero cost when disabled (``Tracer``).
-* :mod:`repro.telemetry.probes` — STATIC cost probes (kernel-launch
-  counts, grids, collective bytes) gated against
-  ``experiments/PROBES_baseline.json``.  Imported explicitly (it pulls
-  in jax + the models); never imported from here.
+
+What a forward lowers to (launches, grids, collective bytes) is the
+static-analysis report's job (``python -m repro.analysis``).
 
 :class:`Telemetry` bundles a registry + tracer; the serving layer owns
 one per ``PackedInferenceServer`` (isolated, testable), while

@@ -12,8 +12,7 @@ reporter thread can ``snapshot()`` concurrently.  Three instrument kinds:
   decade).  Bucket edges are FIXED at construction so snapshots from
   different processes/runs merge exactly (``MetricsRegistry.merge``).
 
-``snapshot()`` returns plain dicts (JSON-able — the ``telemetry``
-section of ``experiments/BENCH_serve.json`` is one), ``reset()`` zeroes
+``snapshot()`` returns plain dicts (JSON-able), ``reset()`` zeroes
 every instrument in place, and ``merge()`` folds another snapshot in:
 counters/histograms add, gauges take the merged-in value.
 

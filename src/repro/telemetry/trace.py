@@ -11,8 +11,7 @@ Design points:
 * **Near-zero cost when disabled** — ``span()`` returns one shared
   no-op context manager without allocating; the only work on the
   disabled path is an attribute check.  The serving layer leaves its
-  tracer disabled by default (``BENCH_serve.json`` carries the measured
-  enabled-vs-disabled overhead).
+  tracer disabled by default.
 * **Nestable** — spans are emitted as Chrome ``"ph": "X"`` (complete)
   events with microsecond ``ts``/``dur``; Perfetto reconstructs nesting
   per thread from the timestamps, so plain ``with`` nesting renders as

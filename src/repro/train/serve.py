@@ -268,7 +268,7 @@ class ActivationPool:
 
     Steady-state serving writes every flush into the same preallocated
     buffer — ``allocations`` stops growing once all buckets are warm
-    (asserted by ``benchmarks/serve_latency.py``), so the request path
+    (asserted by ``tests/test_serve_batching.py``), so the request path
     allocates nothing per flush.  Inter-stage activations never appear
     here at all: they stay bit-packed on device inside the jitted
     forward (the fused-epilogue contract, ``docs/kernels.md``).
@@ -940,9 +940,7 @@ class PackedInferenceServer:
 
 def latency_percentile(sorted_vals, q: float):
     """Nearest-rank percentile over a pre-sorted latency list — the one
-    definition the serving CLI (``launch/serve.py``) and the serving
-    benchmark (``benchmarks/serve_latency.py``) both report, so the two
-    cannot drift.
+    definition the serving CLI (``launch/serve.py``) reports.
 
     Raises ``ValueError`` on an empty sequence (``sorted_vals[-1]`` would
     silently report the caller's last GC'd value as a latency) and on a

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache for the repo's entry points.
 
 Called from an entry point's ``main`` only (``chip_smoke.py``,
-``repro.launch.serve``, ``benchmarks.run``): importing this module, or
+``repro.launch.serve``): importing this module, or
 running the tests, leaves the cache setting alone.
 """
 from __future__ import annotations

@@ -1,10 +1,8 @@
 """Compiled-HLO text inspection: collective ops and their wire bytes.
 
-Shared by the multi-pod dry-run (`launch/dryrun.py`) and the sharded
-packed-forward verifier (`distributed/verify_sharded.py`).  It lives
-here rather than in dryrun because importing dryrun has a side effect —
-it forces 512 fake devices via XLA_FLAGS — that the 8-device verifier
-process must not inherit.
+The parser under ``analysis.collectives``, whose rules the sharded
+packed-forward verifier (`distributed/verify_sharded.py`) and the
+analysis report's sharding cells apply.
 """
 from __future__ import annotations
 

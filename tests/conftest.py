@@ -1,6 +1,6 @@
-# Tests run on the single host CPU device (the dry-run, and ONLY the
-# dry-run, forces 512 placeholder devices via XLA_FLAGS in its own
-# process).  Keep jax state untouched here.
+# Tests run on the single host CPU device (tools that need more devices
+# force them via XLA_FLAGS in their own process).  Keep jax state
+# untouched here.
 import jax
 
 jax.config.update("jax_platform_name", "cpu")

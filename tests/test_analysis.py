@@ -2,7 +2,9 @@
 the four passes must each PASS on the repo's healthy code paths and
 CATCH a seeded instance of its target defect — an unpacked HBM escape,
 a VMEM over-budget launch, an off-plan collective, an unvalidated
-block knob."""
+block knob — and the merged report's unsharded cells must match the
+committed baseline."""
+import json
 import os
 
 import numpy as np
@@ -18,6 +20,7 @@ from repro.analysis.collectives import (check_data_parallel, check_mesh,
                                         check_model_parallel)
 from repro.analysis.lint import lint_paths, lint_source
 from repro.analysis.packedness import analyze_packedness, model_policy
+from repro.analysis import report as R
 from repro.analysis.report import report_ok
 from repro.kernels import ops as kops
 from repro.kernels.binary_matmul import (STACK_VMEM_BUDGET,
@@ -36,7 +39,7 @@ def _packed(m, n, kw=8):
 
 
 # ---------------------------------------------------------------------------
-# graph traversal (shared core; utils/jaxpr re-exports it)
+# graph traversal (shared core)
 # ---------------------------------------------------------------------------
 
 def test_pallas_launches_one_gemm():
@@ -46,12 +49,6 @@ def test_pallas_launches_one_gemm():
     assert launches[0].kernel == "_gemm_kernel"
     assert len(launches[0].grid) == 3
     assert count_pallas_calls(_gemm, a, b) == 1
-
-
-def test_utils_jaxpr_is_a_shim():
-    from repro.utils import jaxpr as UJ
-    assert UJ.pallas_launches is pallas_launches
-    assert UJ._kernel_name is UJ.kernel_name
 
 
 # ---------------------------------------------------------------------------
@@ -295,8 +292,63 @@ def test_report_ok_flags_each_cell_kind():
     assert report_ok(clean) == []
 
 
-def test_probes_reexport_diff_reports():
-    from repro.analysis.report import diff_reports as canonical
-    from repro.telemetry.probes import diff_reports
-    assert diff_reports is canonical
-    assert diff_reports({"a": 1}, {"a": 2}) == ["a: 1 -> 2"]
+def test_forward_cell_report_shape():
+    packed = R.demo_packed("bmlp")
+    cell = R.forward_cell(packed, 1)
+    assert cell["kind"] == "bmlp" and cell["batch"] == 1
+    assert cell["launch_count"] == len(cell["launches"]) > 0
+    assert cell["route"] == "gemv"
+    assert cell["max_intermediate_bytes"] > 0
+    big = R.forward_cell(packed, 32)
+    assert big["route"] == "gemm"
+    assert json.loads(json.dumps(cell)) == cell
+
+
+def test_diff_reports_drift():
+    base = {"schema": 1, "cells": {"a": {"launch_count": 3,
+                                         "launches": [1, 2, 3]}}}
+    same = json.loads(json.dumps(base))
+    assert R.diff_reports(base, same) == []
+    drifted = json.loads(json.dumps(base))
+    drifted["cells"]["a"]["launch_count"] = 4
+    drifted["cells"]["b"] = {}
+    lines = R.diff_reports(base, drifted)
+    assert any("launch_count" in l for l in lines)
+    assert any("NEW" in l for l in lines)
+
+
+def test_report_matches_committed_baseline_unsharded():
+    """Every unsharded cell of the committed baseline (forward,
+    packedness, VMEM, lint) must match a fresh report; the sharding
+    cells need 8 devices and are CI's job."""
+    with open(os.path.join(R.repo_root(), R.BASELINE_PATH)) as f:
+        baseline = json.load(f)
+    report = R.merged_report(sharded=False)
+    assert report_ok(report) == []
+    keep = {k: v for k, v in baseline["cells"].items()
+            if not k.startswith("sharding/")}
+    drift = R.diff_reports({"schema": baseline["schema"], "cells": keep},
+                           report)
+    assert not drift, "\n".join(drift)
+
+
+_FIXED_REPORT = {"schema": 1, "cells": {
+    "forward/bmlp_b1": {"launch_count": 4, "route": "gemv"},
+    "lint": {"violations": []},
+}}
+
+
+@pytest.mark.parametrize("altered, rc", [(False, 0), (True, 1)])
+def test_cli_check_exit_code(tmp_path, monkeypatch, altered, rc):
+    """``--check`` exits 0 on a matching baseline and 1 on drift."""
+    from repro.analysis import __main__ as cli
+    monkeypatch.setattr(R, "merged_report",
+                        lambda *, sharded: json.loads(
+                            json.dumps(_FIXED_REPORT)))
+    baseline = json.loads(json.dumps(_FIXED_REPORT))
+    if altered:
+        baseline["cells"]["forward/bmlp_b1"]["launch_count"] = 5
+    path = tmp_path / "baseline.json"
+    path.write_text(json.dumps(baseline))
+    assert cli.main(["--check", "--no-sharded",
+                     "--baseline", str(path)]) == rc

@@ -136,7 +136,7 @@ def test_no_score_matrix_in_hbm():
     and jaxpr never descends into kernel bodies, so intermediates are
     exactly the HBM-visible arrays."""
     from repro.kernels import binary_attention as BA
-    from repro.utils import jaxpr as J
+    from repro import analysis as J
     b, s, h, d = 1, 1024, 4, 64
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (b, s, h, d), jnp.float32)
@@ -155,3 +155,17 @@ def test_no_score_matrix_in_hbm():
     score_bytes = b * h * s * s * 4
     assert unfused_bytes >= score_bytes
     assert packed_bytes < score_bytes / 4, (packed_bytes, packed_shape)
+    # Channel packing shrinks the float32 Q/K operands 32x.
+    assert q.nbytes == 32 * qp.nbytes
+
+
+def test_dispatcher_is_three_launches():
+    """``kops.binary_attention`` on float Q/K/V: one bitpack launch each
+    for Q and K, then ONE blocked attention launch."""
+    from repro import analysis as J
+    q = jnp.zeros((1, 8, 2, 64), jnp.float32)
+    launches = J.pallas_launches(
+        lambda a, b_, c: kops.binary_attention(a, b_, c, backend="pallas"),
+        q, q, q)
+    assert len(launches) == 3, launches
+    assert sum("attention" in ln.kernel for ln in launches) == 1, launches

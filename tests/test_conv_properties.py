@@ -25,7 +25,7 @@ from repro.core import binarize as B
 from repro.kernels import binary_conv as BC
 from repro.kernels import ops, ref
 from repro.models import cnn
-from repro.utils.jaxpr import count_pallas_calls
+from repro.analysis import count_pallas_calls
 
 settings = hypothesis.settings(max_examples=8, deadline=None)
 

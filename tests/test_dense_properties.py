@@ -5,7 +5,8 @@ Invariants, sampled over the awkward-shape grid in ``strategies.py``:
 
 * fused GEMM + BN-sign-repack epilogue == separate GEMM -> ``bn_sign_pack``
   == the float oracle, every backend, including pack-seam tails (K and N
-  not multiples of 32),
+  not multiples of 32), and the fused path keeps the int32 (M, N)
+  activation out of HBM,
 * the contraction is invariant to ``words_per_step`` (plain, fused, and
   stack kernels), and invalid values raise like ``block_oh``/``block_n``,
 * the single-launch hidden stack == per-layer fused launches == the jnp
@@ -34,7 +35,7 @@ from repro.core import binary_layers as L
 from repro.kernels import binary_matmul as BMM
 from repro.kernels import ops, ref
 from repro.models import cnn
-from repro.utils.jaxpr import count_pallas_calls
+from repro.analysis import count_pallas_calls, max_intermediate_bytes
 
 settings = hypothesis.settings(max_examples=8, deadline=None)
 
@@ -92,6 +93,31 @@ def test_fused_epilogue_matches_separate_and_float(case, ws, seed):
         np.testing.assert_array_equal(
             np.asarray(got), want,
             err_msg=f"{backend} fused epilogue diverged on {case} ws={ws}")
+
+
+def test_fused_epilogue_keeps_int32_out_of_hbm():
+    """The separate path stages the int32 (M, N) GEMM output in HBM
+    between its two launches; the fused epilogue emits packed words
+    from the kernel flush, so its largest HBM intermediate is smaller.
+    K = 4096 fills whole 128-word lane tiles, so no padded weight copy
+    (``pack_bmlp`` pads at pack time) masks the activations."""
+    key = jax.random.PRNGKey(4)
+    m, k, n = 16, 4096, 256
+    _, _, ap, bp = _rand_gemm(key, m, k, n)
+    tau, flip = _rand_folded(jax.random.fold_in(key, 2), n)
+
+    def separate(a):
+        z = ops.binary_matmul_packed(a, bp, k_true=k, backend="pallas")
+        return ops.bn_sign_pack(z, tau, flip, backend="pallas")
+
+    def fused(a):
+        return ops.binary_matmul_bn_sign_packed(a, bp, tau, flip, k_true=k,
+                                                backend="pallas")
+
+    sep_bytes, _ = max_intermediate_bytes(separate, ap)
+    fused_bytes, _ = max_intermediate_bytes(fused, ap)
+    assert sep_bytes >= m * n * 4, sep_bytes
+    assert fused_bytes < sep_bytes, (fused_bytes, sep_bytes)
 
 
 @settings

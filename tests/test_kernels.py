@@ -131,7 +131,7 @@ def test_binary_matmul_pallas_packs_in_kernel():
     host-side pack_bits even on backend='pallas'.  Routed through the
     bitpack dispatcher, the traced fn now launches 3 kernels (two packs
     + the GEMM) instead of one."""
-    from repro.utils.jaxpr import count_pallas_calls
+    from repro.analysis import count_pallas_calls
     key = jax.random.PRNGKey(0)
     a = jax.random.normal(key, (16, 64))
     b = jax.random.normal(jax.random.fold_in(key, 1), (32, 64))

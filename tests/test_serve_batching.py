@@ -24,7 +24,7 @@ from repro.kernels import ops as kops
 from repro.models import cnn
 from repro.models import model as M
 from repro.train import serve as SV
-from repro.utils.jaxpr import pallas_grids
+from repro.analysis import pallas_grids
 
 
 # ---------------------------------------------------------------------------

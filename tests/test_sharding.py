@@ -1,7 +1,6 @@
 """Sharding resolver unit tests + small-mesh lower/compile integration.
 
-The production mesh is exercised by launch/dryrun.py (512 fake devices in
-its own process); here we verify the RULES on a small in-process mesh.
+Here we verify the RULES on a small in-process mesh.
 """
 import jax
 import jax.numpy as jnp
@@ -110,18 +109,3 @@ def test_lower_compile_small_mesh():
     mem = compiled.memory_analysis()
     assert mem is not None
 
-
-def test_collective_bytes_parser():
-    from repro.launch import dryrun as DR
-    hlo = """
-  %ag = bf16[16,128]{1,0} all-gather(%x), replica_groups={}
-  %ar = f32[64]{0} all-reduce-start(%y), to_apply=%sum
-  %rs = f32[8,8]{1,0} reduce-scatter(%z), dimensions={0}
-  %a2a = (f32[4,4]{1,0}, f32[4,4]{1,0}) all-to-all(%p, %q)
-"""
-    got = DR.collective_bytes(hlo)
-    assert got["all-gather"] == 16 * 128 * 2
-    assert got["all-reduce"] == 64 * 4 * 2        # 2x ring factor
-    assert got["reduce-scatter"] == 64 * 4
-    assert got["all-to-all"] == 2 * 16 * 4
-    assert got["total"] == sum(v for k, v in got.items() if k != "total")

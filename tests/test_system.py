@@ -2,7 +2,7 @@
 
 1. The paper's pipeline: train-style latent BMLP -> pack -> binary
    inference agrees; speed/memory claims structurally verified elsewhere
-   (benchmarks/).
+   (the ``repro.analysis`` report and the kernel property tests).
 2. LM pipeline: train a reduced arch on the synthetic stream, checkpoint,
    kill, restore, continue — loss continues to drop and the data cursor
    resumes deterministically.

@@ -1,6 +1,6 @@
 """Telemetry subsystem: metrics registry, span tracer, instrumented
-serving lifecycle, the shared jaxpr traversal, and the static cost
-probes (taxonomy in docs/observability.md)."""
+serving lifecycle, and the shared jaxpr traversal (taxonomy in
+docs/observability.md)."""
 import json
 
 import numpy as np
@@ -13,8 +13,8 @@ from repro.telemetry import (LATENCY_BUCKETS_S, MetricsRegistry, Telemetry,
                              Tracer, log_spaced_buckets)
 from repro.telemetry.trace import _NOOP
 from repro.train import serve as SV
-from repro.utils.jaxpr import (count_pallas_calls, max_intermediate_bytes,
-                               pallas_grids, pallas_launches)
+from repro.analysis import (count_pallas_calls, max_intermediate_bytes,
+                            pallas_grids, pallas_launches)
 
 
 # ---------------------------------------------------------------------------
@@ -404,7 +404,7 @@ def test_dispatch_batch_counts_routes():
 
 
 # ---------------------------------------------------------------------------
-# shared jaxpr traversal (utils/jaxpr.py)
+# shared jaxpr traversal (repro.analysis.graph)
 # ---------------------------------------------------------------------------
 
 def test_pallas_launches_names_and_grids():
@@ -438,47 +438,3 @@ def test_max_intermediate_ignores_kernel_internals():
     nb_unfused, _ = max_intermediate_bytes(lambda a: unfused(a), x)
     assert nb_fused <= nb_unfused
 
-
-# ---------------------------------------------------------------------------
-# static cost probes
-# ---------------------------------------------------------------------------
-
-def test_probe_forward_report_shape():
-    from repro.telemetry import probes
-    packed = probes._demo_packed("bmlp")
-    cell = probes.probe_forward(packed, 1)
-    assert cell["kind"] == "bmlp" and cell["batch"] == 1
-    assert cell["launch_count"] == len(cell["launches"]) > 0
-    assert cell["route"] == "gemv"
-    assert cell["max_intermediate_bytes"] > 0
-    big = probes.probe_forward(packed, 32)
-    assert big["route"] == "gemm"
-    assert json.loads(json.dumps(cell)) == cell
-
-
-def test_probe_diff_reports_drift():
-    from repro.telemetry import probes
-    base = {"schema": 1, "cells": {"a": {"launch_count": 3,
-                                         "launches": [1, 2, 3]}}}
-    same = json.loads(json.dumps(base))
-    assert probes.diff_reports(base, same) == []
-    drifted = json.loads(json.dumps(base))
-    drifted["cells"]["a"]["launch_count"] = 4
-    drifted["cells"]["b"] = {}
-    lines = probes.diff_reports(base, drifted)
-    assert any("launch_count" in l for l in lines)
-    assert any("NEW" in l for l in lines)
-
-
-def test_probes_match_committed_baseline_unsharded():
-    """The forward cells of the committed baseline must match a fresh
-    trace (the sharded cells need 8 devices and are CI's job)."""
-    from repro.telemetry import probes
-    baseline = json.load(open(
-        f"{probes.repo_root()}/{probes.BASELINE_PATH}"))
-    report = probes.standard_report(sharded=False)
-    keep = {k: v for k, v in baseline["cells"].items()
-            if k in report["cells"]}
-    drift = probes.diff_reports(
-        {"schema": baseline["schema"], "cells": keep}, report)
-    assert not drift, "\n".join(drift)

@@ -2,7 +2,7 @@
 
 ``transformer_forward_packed`` must (a) trace its attention to the
 blocked ``binary_attention`` Pallas launches and every projection to
-the dense megakernels (launch-shape evidence via ``utils.jaxpr``),
+the dense megakernels (launch-shape evidence via ``repro.analysis``),
 (b) be bit-exact against the pure-jnp oracle path for registry
 configs, and (c) serve through ``PackedInferenceServer`` via the
 ``packed_kind == 'transformer'`` seam.
@@ -17,7 +17,7 @@ from repro.kernels import ops as kops
 from repro.models import cnn
 from repro.models import transformer as TF
 from repro.train import serve as SV
-from repro.utils.jaxpr import pallas_launches
+from repro.analysis import pallas_launches
 
 ARCHS = ("gemma2-9b", "starcoder2-3b")
 
